@@ -22,18 +22,18 @@ tick; the (C,W) threshold sort dominates) is linear in the camera rows.
 Bit parity of the sharded vs unsharded decisions is asserted
 unconditionally.
 
-Needs >1 device to measure anything interesting; when launched with a
-single device (plain ``benchmarks.run``) it re-execs itself in a
-subprocess with ``--xla_force_host_platform_device_count=8``, matching
-the CI smoke invocation documented in ROADMAP.md.
+It measures the devices of its own process and starts no other: one
+device gives a one-shard mesh. The CPU rehearsal gets its simulated
+devices from the command line:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
+      python -m benchmarks.run --quick --only fleet
 """
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
-from pathlib import Path
 
 import numpy as np
 
@@ -109,37 +109,10 @@ def _measure(quick: bool) -> dict:
 
 
 def run(quick=True):
-    import jax
     with Timer() as t:
-        if len(jax.devices()) > 1:
-            derived = _measure(quick)
-        else:
-            # single-device process (plain benchmarks.run): re-exec with
-            # 8 simulated host devices so the mesh has something to shard
-            # over — same flags as the CI fleet smoke step
-            env = dict(os.environ)
-            env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                                " --xla_force_host_platform_device_count=8"
-                                ).strip()
-            repo = Path(__file__).resolve().parent.parent
-            env["PYTHONPATH"] = os.pathsep.join(
-                p for p in (str(repo / "src"), str(repo),
-                            env.get("PYTHONPATH", "")) if p)
-            mode = "--quick" if quick else "--full"
-            out = subprocess.run(
-                [sys.executable, "-m", "benchmarks.bench_fleet", mode],
-                capture_output=True, text=True, cwd=repo, env=env,
-                timeout=1800)
-            if out.returncode != 0:
-                raise RuntimeError(f"fleet subprocess failed: "
-                                   f"{out.stderr[-2000:]}")
-            derived = json.loads(out.stdout.strip().splitlines()[-1])
+        derived = _measure(quick)
     return {"us_per_call": t.us, "derived": derived}
 
 
 if __name__ == "__main__":
-    quick = "--full" not in sys.argv
-    if len(__import__("jax").devices()) > 1:
-        print(json.dumps(_measure(quick)))
-    else:
-        print(json.dumps(run(quick), indent=2))
+    print(json.dumps(run("--full" not in sys.argv), indent=2))

@@ -27,6 +27,7 @@ import numpy as np
 from repro.core import Query, open_session
 from repro.core.session import ADMIT, SHED_ADMISSION, SHED_QUEUE
 from repro.core.shed_queue import UtilityQueue
+from repro.core.threshold import next_above
 from benchmarks.common import Timer, median_ms
 
 BENCH_SEED = 0
@@ -127,7 +128,7 @@ class HostLoopShedder:
             idx = int(np.ceil(np.minimum(r, np.float32(1.0))
                               * np.float32(n))) - 1
             idx = max(0, min(idx, n - 1))
-            self.threshold[c] = np.nextafter(v[idx], np.float32(np.inf))
+            self.threshold[c] = next_above(v[idx])
         cap = np.maximum((self.budget / p + 1e-9).astype(np.int32) - 1, 1)
         self.queue_cap = cap.astype(np.int32)
         for c, q in enumerate(self.queues):

@@ -70,6 +70,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.full and args.quick:
         ap.error("--full and --quick are mutually exclusive")
+    from repro.launch.jax_cache import enable_compile_cache
+    enable_compile_cache()
 
     outdir = Path("results/benchmarks")
     outdir.mkdir(parents=True, exist_ok=True)

@@ -20,14 +20,21 @@ from repro.core.utility import (
     pixel_fraction_matrix,
     train_utility_model,
 )
-from repro.core.session import (
-    IngestResult,
-    Query,
-    SessionState,
-    ShedSession,
-    StepResult,
-    open_session,
-)
+
+# The session API is imported on first use: session.py pulls in the
+# ingest kernels, which themselves import the core math modules above,
+# so an eager import here would make ``import
+# repro.kernels.hsv_features.kernel`` circular.
+_SESSION_NAMES = ("IngestResult", "Query", "SessionState", "ShedSession",
+                  "StepResult", "open_session")
+
+
+def __getattr__(name):
+    if name in _SESSION_NAMES:
+        from repro.core import session
+        return getattr(session, name)
+    raise AttributeError(f"module 'repro.core' has no attribute {name!r}")
+
 
 __all__ = [
     "BLUE", "COLORS", "GREEN", "RED", "YELLOW", "Color",

@@ -46,10 +46,9 @@ from typing import Any, Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.sharding.api import resolve_axis
+from repro.sharding.api import auto_mesh, resolve_axis
 
 AxisName = Union[str, Tuple[str, ...]]
 
@@ -64,7 +63,7 @@ def fleet_mesh(num_devices: Optional[int] = None,
     """A 1-D mesh over ``num_devices`` (default: all) devices whose
     single axis carries the camera dimension."""
     n = len(jax.devices()) if num_devices is None else int(num_devices)
-    return jax.make_mesh((n,), (axis_name,))
+    return auto_mesh((n,), (axis_name,))
 
 
 def mesh_axis_size(mesh: Mesh, axis: AxisName) -> int:
@@ -218,11 +217,11 @@ def _fleet_control(state, util, present, *, mesh, axis, num_total, masked,
                else _empty_aggregates(True))
         return st, out, agg
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(st_spec, P(axis), P(axis)),
         out_specs=(st_spec, ctrl_spec, agg_spec),
-        check_rep=False)(state, util, present)
+        check_vma=False)(state, util, present)
 
 
 @functools.partial(
@@ -261,11 +260,11 @@ def _fleet_serve_step(state, frames, M_pos, norm, *, mesh, axis, num_total,
                else _empty_aggregates(True))
         return st, out, agg
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(st_spec, P(axis), P(), P()),
         out_specs=(st_spec, ctrl_spec, agg_spec),
-        check_rep=False)(state, frames, M_pos, norm)
+        check_vma=False)(state, frames, M_pos, norm)
 
 
 @functools.partial(
@@ -286,10 +285,10 @@ def _fleet_tick(state, *, mesh, axis, num_total, min_proc, budget,
                                               num_total, tick_cfg=tick_cfg)
         return st, rates, resize_ev
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(st_spec,),
         out_specs=(st_spec, P(axis), P(axis)),
-        check_rep=False)(state)
+        check_vma=False)(state)
 
 
 # ---------------------------------------------------------------------------
@@ -337,10 +336,10 @@ def _fleet_pop_candidates(q_util, q_seq, rows, *, mesh, axis, kk):
             (nu, cams, seqs, slots), num_keys=3)
         return nu_s[:kk], cam_s[:kk], seq_s[:kk], slot_s[:kk]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(axis)),
-        check_rep=False)(q_util, q_seq, rows)
+        check_vma=False)(q_util, q_seq, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis"),
@@ -362,9 +361,9 @@ def _fleet_pop_clear(state, gcam, slot, *, mesh, axis):
         q_seq = st.q_seq.at[ic, isl].set(-1, mode="drop")
         return dataclasses.replace(st, q_util=q_util, q_seq=q_seq)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(st_spec, P(), P()),
-        out_specs=st_spec, check_rep=False)(state, gcam, slot)
+        out_specs=st_spec, check_vma=False)(state, gcam, slot)
 
 
 def pop_topk(state, *, mesh, axis, k, rows=None):
@@ -407,10 +406,10 @@ def _fleet_aggregates(state, *, mesh, axis):
     from repro.core.session import SessionState
     st_spec = state_pspecs(SessionState, axis)
     agg_spec = {k: P() for k in _empty_aggregates(False)}
-    return shard_map(
+    return jax.shard_map(
         lambda st: _local_aggregates(st, axis), mesh=mesh,
         in_specs=(st_spec,), out_specs=agg_spec,
-        check_rep=False)(state)
+        check_vma=False)(state)
 
 
 # -- python-facing wrappers (keyword plumbing, mesh/axis hashability) -------

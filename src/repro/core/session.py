@@ -334,9 +334,11 @@ def _ring_push_dev(buf, pos, ln, counts, us, mask, lo: float,
     ``mask`` marks real entries (None = all). The (C, B) bucket
     ``counts`` are maintained incrementally (ring-wrap aware: slot s is
     pre-push live iff s < len, regardless of where ``pos`` wrapped), so
-    they always equal a recount of the live window."""
+    they always equal a recount of the live window. Utilities are stored
+    flushed of subnormals (``shed_queue.flush_subnormal``)."""
     C, W = buf.shape
     B = counts.shape[1]
+    us = sq.flush_subnormal(us, jnp)
     rows = jnp.arange(C)[:, None]
     if mask is None:
         if us.shape[1] >= W:                   # only the tail can survive
@@ -371,6 +373,7 @@ def _ring_push_host(buf, pos, ln, counts, us, mask, lo: float,
     ``counts`` in place, returns (pos', len')."""
     C, W = buf.shape
     B = counts.shape[1]
+    us = sq.flush_subnormal(us)
     if mask is None:
         if us.shape[1] >= W:
             us = us[:, -W:]
@@ -1423,7 +1426,7 @@ class ShedSession:
             util = self.ingest(frames, impl=impl,
                                interpret=interpret).utility
         else:
-            util = np.asarray(utilities, np.float32)
+            util = sq.flush_subnormal(utilities)
             if util.ndim == 1:
                 util = util[None]
             if util.shape[0] != self.num_cameras:
@@ -1485,7 +1488,7 @@ class ShedSession:
             util = np.asarray(util, np.float32)
             bbox = np.asarray(bbox, np.int32)
         else:
-            util = np.asarray(utilities, np.float32)
+            util = sq.flush_subnormal(utilities)
             if util.ndim == 1:
                 util = util[None]
             if util.shape[0] != self.num_cameras:
@@ -1516,6 +1519,7 @@ class ShedSession:
                     self.cascade.scorer.score(
                         np.ascontiguousarray(frames[r, t]), bbox[r, t]),
                     np.float32)
+        s2 = sq.flush_subnormal(s2)
         # phase B: stage-2 ring/gate + queue insertion + optional tick
         if self.serve == "device":
             self.state, out = _cascade_finish_dev(
@@ -1634,7 +1638,7 @@ class ShedSession:
         are mapped to lanes in first-seen order), else lane 0.
         """
         c = self.lane(getattr(item, "cam_id", 0)) if cam is None else int(cam)
-        u = np.float32(utility)
+        u = np.float32(sq.flush_subnormal(utility))
         self.stats.offered += 1
         self.per_camera_offered[c] += 1
         st = self.state
@@ -1715,6 +1719,7 @@ class ShedSession:
                 present[c, t] = True
                 batch_items[c][t] = items[i]
                 slot_of[(c, t)] = i
+        util = sq.flush_subnormal(util)
         kw = dict(update_cdf=self.update_cdf_online, do_tick=False,
                   min_proc=self.min_proc, budget=self._budget,
                   num_total=self._num_active, tick_cfg=self._tick_cfg)

@@ -36,7 +36,9 @@ Array lanes (``lanes_*`` functions)
         of a totally ordered set.
 
     Utilities are assumed finite (the model's scores are); ``-inf`` is
-    reserved for empty slots and ``+inf`` for sort sentinels.
+    reserved for empty slots and ``+inf`` for sort sentinels. Every
+    push first maps subnormal utilities (and -0.0) to +0.0 with
+    :func:`flush_subnormal`, the reference queue included.
 """
 from __future__ import annotations
 
@@ -50,6 +52,18 @@ import jax.numpy as jnp
 import numpy as np
 
 INT32_MAX = np.int32(2**31 - 1)
+TINY = np.float32(np.finfo(np.float32).tiny)   # smallest normal float32
+
+
+def flush_subnormal(u, xp=np):
+    """float32 utilities with subnormals (and -0.0) mapped to +0.0.
+
+    XLA compares floats with subnormals flushed to zero (CPU and TPU
+    alike), so on the device a subnormal ties with 0.0 where NumPy and
+    Python order the two strictly. Flushing each utility as it enters a
+    queue or a CDF ring gives every implementation the same values."""
+    u = xp.asarray(u, xp.float32)
+    return xp.where(xp.abs(u) < TINY, xp.float32(0.0), u)
 
 
 @dataclass(order=True)
@@ -88,7 +102,10 @@ class UtilityQueue:
 
     def push(self, item: Any, utility: float) -> Optional[Any]:
         """Insert; returns the evicted item (possibly ``item`` itself) or None."""
-        e = _Entry(float(utility), next(self._counter), item)
+        u = float(utility)
+        if abs(u) < TINY:           # flush_subnormal, at the input's width
+            u = 0.0
+        e = _Entry(u, next(self._counter), item)
         heapq.heappush(self._min, e)
         heapq.heappush(self._max_heap, (-e.utility, e.seq, e))
         if len(self) > self._max:
@@ -226,7 +243,7 @@ def push_batch_dev(util, seq, next_seq, u, admit, cap):
     """
     K = util.shape[1]
     cand_u, cand_s, cand_b, cap_eff, pushed_seq, new_next = _push_batch_args(
-        util, seq, next_seq, jnp.asarray(u, jnp.float32), admit, cap, jnp)
+        util, seq, next_seq, flush_subnormal(u, jnp), admit, cap, jnp)
     nu, ns, ev_s, ev_b = select_dev(cand_u, cand_s, cand_b, cap_eff, K)
     return nu, ns, new_next, pushed_seq, ev_s, ev_b
 
@@ -236,7 +253,7 @@ def push_batch_host(util, seq, next_seq, u, admit, cap):
     and returns (next_seq', pushed_seq, evicted_seq, evicted_bidx)."""
     K = util.shape[1]
     cand_u, cand_s, cand_b, cap_eff, pushed_seq, new_next = _push_batch_args(
-        util, seq, next_seq, np.asarray(u, np.float32), admit, cap, np)
+        util, seq, next_seq, flush_subnormal(u), admit, cap, np)
     nu, ns, ev_s, ev_b = select_host(cand_u, cand_s, cand_b, cap_eff, K)
     util[...], seq[...] = nu, ns
     return new_next, pushed_seq, ev_s, ev_b
@@ -260,7 +277,7 @@ def push_one_dev(util, seq, next_seq, u, do_push, cap):
     """
     C, K = util.shape
     rows = jnp.arange(C)
-    u = jnp.asarray(u, jnp.float32)
+    u = flush_subnormal(u, jnp)
     valid = seq >= 0
     count = valid.sum(axis=-1)
     cap_eff = jnp.clip(cap, 1, K)
@@ -290,7 +307,7 @@ def push_one_host(util, seq, next_seq, u, do_push, cap):
     """NumPy twin of :func:`push_one_dev`; mutates util/seq in place."""
     C, K = util.shape
     rows = np.arange(C)
-    u = np.asarray(u, np.float32)
+    u = flush_subnormal(u)
     valid = seq >= 0
     count = valid.sum(axis=-1)
     cap_eff = np.clip(cap, 1, K)
@@ -499,7 +516,7 @@ def pop_topk_host(util, seq, k: int, rows=None):
 
 
 __all__ = [
-    "UtilityQueue", "make_lanes",
+    "UtilityQueue", "make_lanes", "flush_subnormal",
     "select_dev", "select_host",
     "push_batch_dev", "push_batch_host",
     "push_one_dev", "push_one_host",

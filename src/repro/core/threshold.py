@@ -64,6 +64,23 @@ def _scratch(shape, dtype) -> np.ndarray:
     return buf
 
 
+def next_above(v, xp=np):
+    """The Eq. 17 threshold just above quantile value ``v``:
+    ``nextafter(v, +inf)``, except that above a zero float32 ``v`` it is
+    the smallest *normal* float32. XLA flushes subnormals to zero when
+    it compares floats (CPU and TPU alike), so a subnormal threshold
+    would admit a 0.0 utility on the device and shed it on the host.
+    The session's float32 lanes take utilities flushed of subnormals
+    (``shed_queue.flush_subnormal``), so with this threshold both twins
+    shed exactly the utilities ``<= v``. Float64 values (the NumPy-only
+    :class:`UtilityCDF`) keep plain ``nextafter``."""
+    v = xp.asarray(v)
+    up = xp.nextafter(v, xp.asarray(np.inf, v.dtype))
+    if v.dtype != np.float32:
+        return up
+    return xp.where(v == 0, xp.float32(np.finfo(np.float32).tiny), up)
+
+
 def threshold_from_sorted(v: np.ndarray, r: float) -> float:
     """Eq. 17 on a sorted utility array: min u_th with CDF(u_th) >= r.
 
@@ -71,14 +88,14 @@ def threshold_from_sorted(v: np.ndarray, r: float) -> float:
     ``UtilityCDF`` (scalar, float64) and the session's per-camera lanes
     (float32 rows) both follow it, so they cannot drift apart. The
     threshold is the next representable value *in the array's dtype*
-    above the r-quantile, dropping everything <= it; r <= 0 maps to
-    -inf (shed nothing).
+    above the r-quantile (:func:`next_above`), dropping everything <= it;
+    r <= 0 maps to -inf (shed nothing).
     """
     if len(v) == 0 or r <= 0.0:
         return float(-np.inf)
     idx = int(np.ceil(min(r, 1.0) * len(v))) - 1
     idx = max(0, min(idx, len(v) - 1))
-    return float(np.nextafter(v[idx], np.asarray(np.inf, v.dtype)))
+    return float(next_above(v[idx]))
 
 
 def thresholds_from_lanes_dev(cdf_buf, cdf_len, rates):
@@ -98,8 +115,8 @@ def thresholds_from_lanes_dev(cdf_buf, cdf_len, rates):
     idx = (jnp.ceil(jnp.minimum(r, 1.0) * n.astype(jnp.float32))
            .astype(jnp.int32) - 1)
     idx = jnp.clip(idx, 0, jnp.maximum(n - 1, 0))
-    th = jnp.nextafter(
-        jnp.take_along_axis(v, idx[:, None], axis=-1)[:, 0], jnp.inf)
+    th = next_above(jnp.take_along_axis(v, idx[:, None], axis=-1)[:, 0],
+                    jnp)
     return jnp.where((n == 0) | (r <= 0.0), -jnp.inf, th).astype(jnp.float32)
 
 
@@ -119,8 +136,7 @@ def thresholds_from_lanes_host(cdf_buf: np.ndarray, cdf_len: np.ndarray,
     th = np.full((C,), -np.inf, np.float32)
     for c in np.flatnonzero((n > 0) & (r > 0.0)):
         k = int(idx[c])
-        th[c] = np.nextafter(
-            np.partition(cdf_buf[c, :n[c]], k)[k], np.float32(np.inf))
+        th[c] = next_above(np.partition(cdf_buf[c, :n[c]], k)[k])
     return th
 
 
@@ -244,7 +260,7 @@ class UtilityCDF:
         return float(np.searchsorted(v, u_th, side="left")) / len(v)
 
 
-__all__ = ["UtilityCDF", "threshold_from_sorted",
+__all__ = ["UtilityCDF", "threshold_from_sorted", "next_above",
            "thresholds_from_lanes_dev", "thresholds_from_lanes_host",
            "thresholds_from_counts_dev", "thresholds_from_counts_host",
            "bucket_index_dev", "bucket_index_host", "counts_from_ring_host"]

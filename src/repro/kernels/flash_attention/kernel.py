@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
@@ -93,11 +95,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: (B, Hq, Sq, d); k/v: (B, Hkv, Sk, d); Hq % Hkv == 0.
 
     Sq and Sk must be multiples of the block sizes (pad outside).
+    interpret=None resolves backend-aware (compiled only on TPU).
     """
+    interpret = resolve_interpret(interpret)
     B, Hq, Sq, d = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     assert Hq % Hkv == 0 and Sq % block_q == 0 and Sk % block_k == 0, \

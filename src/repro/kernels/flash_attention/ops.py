@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.kernel import (
 
 def flash_attention_bsnh(q, k, v, *, causal: bool = True,
                          window: Optional[int] = None,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """Model-layout entry point. q: (B, Sq, Hq, hd); k/v: (B, Sk, Hkv, hd).
 
     Pads sequences to block multiples; padded K positions are masked by

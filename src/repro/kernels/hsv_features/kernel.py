@@ -14,15 +14,15 @@ Two entry points:
     lanes — and runs, per pixel tile,
 
       HBM -> VMEM tile -> RGB->HSV -> EMA background subtraction
-          -> joint (sat, val) bin one-hot (computed ONCE per tile)
-          -> per-color hue masks applied via one matmul
+          -> per-color hue masks x joint (sat, val) bin one-hot (MXU)
           -> per-frame PF counts + totals + in-kernel utility score
 
     over a 3D grid ``(camera, frame, pixel-tile)``. TPU grid execution
-    is sequential per core; accumulator / state blocks are indexed by
-    the camera dimension only, so within one camera's grid span they
-    stay VMEM-resident and read-modify-write across grid steps is
-    race-free, while each camera gets its own state lane.
+    is sequential per core; the background/gain state blocks are indexed
+    by the camera dimension only and the per-frame output blocks by
+    (camera, frame), so within their grid span they stay VMEM-resident
+    and read-modify-write across grid steps is race-free, while each
+    camera gets its own state lane.
 
     Background-model state is *explicit kernel state carried across
     batches*: the caller passes ``(bg, gain)`` in and receives the
@@ -35,19 +35,31 @@ Two entry points:
     divided by it before differencing, and the background absorbs the
     compensated frame with learning rate ``alpha``.
 
-    The histogram uses a broadcast-compare one-hot followed by a
-    ``(n_colors, BLOCK) @ (BLOCK, bins)`` matmul — MXU/VPU-friendly,
-    no scatter (TPU has no fast scatter), and the one-hot is built once
-    per tile regardless of how many query colors there are.
+Tile layout (Mosaic's (8, 128) rule): the wrappers hand the kernels
+*planar* pixels, each ``BLOCK``-pixel tile a ``(SUB, LANES)`` slab per
+channel, so every elementwise stage runs on full vregs. Per-frame
+results leave through (camera, frame)-indexed blocks whose last two dims
+are whole arrays — counts ``(NCP, bins)`` and an ``(NCP, 128)`` aux slab
+(lane 0 totals, 1 foreground total, 2 utility) — and the wrappers slice
+them back to the public shapes. Scalar state (gain, the gain sums) lives
+in broadcast ``(SUB, 128)`` vector slabs; nothing stores scalars to
+VMEM and no store has a dynamic lane offset.
+
+The histogram is, for each of a tile's ``SUB`` pixel rows, one
+``(NCP, LANES) x (bins, LANES)^T`` matmul of the stacked hue x
+foreground masks against the bin one-hot — MXU work, no scatter (TPU
+has no fast scatter).
 
 Hue ranges, bin counts, EMA constants and the composition op are all
 *static* (baked into the kernel at trace time), matching the deployment
 model: one compiled shedder per query.
 
-VMEM contract: the resident state is ``T*nc*bins + N`` floats per
-camera (counts plus background — only the current camera's lane is
-resident at a time); with the default 64-frame batches and edge-scale
-frames this is a few hundred KiB, far below the ~16 MiB VMEM budget.
+VMEM contract: each camera's whole background lane (``4 * N`` bytes) is
+resident, twice in and twice out under Pallas' double buffering — about
+14 MiB at 1280x720, 32 MiB at 1920x1080 — plus about 1 MiB of per-step
+blocks and tile temporaries. ``ingest_batch`` asks for that estimate
+(``_ingest_vmem_bytes``, at least ``VMEM_FLOOR``) as the kernel's VMEM
+limit and refuses frames whose estimate exceeds ``VMEM_CAP``.
 """
 from __future__ import annotations
 
@@ -56,22 +68,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.utility import B_S, B_V, joint_bin_index
 from repro.data.background import GAIN_MAX, GAIN_MIN
-from repro.kernels.hsv_features.ref import color_masks
+from repro.kernels import resolve_interpret
 
-BLOCK = 4096  # pixels per VMEM tile (BLOCK*3*4B = 48 KiB in, well inside VMEM)
+BLOCK = 4096          # pixels per VMEM tile
+SUB = 8               # sublane rows of a tile
+LANES = BLOCK // SUB  # lanes of a tile row (a multiple of 128)
+AUX = 128             # lanes of the per-frame aux slab
+# VMEM a kernel may ask for. The chips this repo targets have at least
+# 64 MiB per core (v5e and v6e 128 MiB, v7x 64 MiB); older generations
+# have less, and frames there need the tiled background (ROADMAP R2).
+VMEM_CAP = 64 * 2 ** 20
+# Floor of the requested limit. The estimate below counts the buffers
+# and temporaries the kernel names, not Mosaic's internal scratch, so
+# small frames get headroom; 720p (an estimate of about 15 MiB) compiled
+# and ran on a v5e with this limit.
+VMEM_FLOOR = 32 * 2 ** 20
 
 
-def default_interpret() -> bool:
-    """Backend-aware interpret default: compiled on TPU, interpreted
-    elsewhere (CPU has no Mosaic lowering)."""
-    return jax.default_backend() != "tpu"
-
-
-def _resolve_interpret(interpret):
-    return default_interpret() if interpret is None else interpret
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def _rgb_to_hsv_block(r, g, b):
@@ -80,49 +99,104 @@ def _rgb_to_hsv_block(r, g, b):
     c = v - mn
     s = jnp.where(v > 0, c / jnp.maximum(v, 1e-9) * 255.0, 0.0)
     safe_c = jnp.where(c > 0, c, 1.0)
+    # where v == r, |g - b| <= c, so the hue sector x lies in [-1, 1]
+    # and ``x % 6`` is exactly ``x + 6`` for negative x
+    x = (g - b) / safe_c
     h = jnp.where(
-        v == r, ((g - b) / safe_c) % 6.0,
+        v == r, jnp.where(x < 0, x + 6.0, x),
         jnp.where(v == g, (b - r) / safe_c + 2.0, (r - g) / safe_c + 4.0))
     h = jnp.where(c > 0, h * 30.0, 0.0)
     return h, s, v
 
 
-def _joint_onehot(s, v, bs, bv):
-    """Joint (sat, val) bin one-hot — built ONCE per tile. (n, bins)."""
+def _planar(rgb, npad: int):
+    """(..., n, 3) interleaved pixels -> (..., 3, npad/BLOCK, SUB, LANES)
+    zero-padded planar tiles."""
+    pad = npad - rgb.shape[-2]
+    if pad:
+        rgb = jnp.pad(rgb, [(0, 0)] * (rgb.ndim - 2) + [(0, pad), (0, 0)])
+    planes = jnp.moveaxis(rgb.astype(jnp.float32), -1, -2)
+    return planes.reshape(*planes.shape[:-1], npad // BLOCK, SUB, LANES)
+
+
+def _tiles(x, npad: int):
+    """(..., n) per-pixel lane -> (..., npad/BLOCK, SUB, LANES)."""
+    pad = npad - x.shape[-1]
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(*x.shape[:-1], npad // BLOCK, SUB, LANES)
+
+
+def _pixel_index(j):
+    """Flat pixel index of every element of tile ``j``. (SUB, LANES)."""
+    return (j * BLOCK
+            + jax.lax.broadcasted_iota(jnp.int32, (SUB, LANES), 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (SUB, LANES), 1))
+
+
+def _tile_hist(h, s, v, fgf, *, hue_ranges, ncp, bs, bv):
+    """One tile's per-color histograms. h/s/v/fgf: (SUB, LANES).
+
+    Returns (counts (ncp, bs*bv), totals (ncp, 1)); rows >= nc are 0.
+    """
+    nb = bs * bv
     joint = joint_bin_index(s, v, bs, bv)
-    bins = jax.lax.broadcasted_iota(jnp.int32, (joint.shape[0], bs * bv), 1)
-    return (joint[:, None] == bins).astype(jnp.float32)
+    masks = []
+    for ranges in hue_ranges:
+        m = jnp.zeros(h.shape, bool)
+        for lo, hi in ranges:
+            m |= (h >= lo) & (h < hi)
+        masks.append(m.astype(jnp.float32) * fgf)
+    color = jax.lax.broadcasted_iota(jnp.int32, (ncp, LANES), 0)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (nb, LANES), 0)
+    counts = jnp.zeros((ncp, nb), jnp.float32)
+    totals = jnp.zeros((ncp, 1), jnp.float32)
+    for i in range(SUB):
+        rows = jnp.zeros((ncp, LANES), jnp.float32)
+        for c, m in enumerate(masks):
+            rows = jnp.where(color == c, m[i:i + 1, :], rows)
+        onehot = (bins == joint[i:i + 1, :]).astype(jnp.float32)
+        counts += jax.lax.dot_general(
+            rows, onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        totals += jnp.sum(rows, axis=1, keepdims=True)
+    return counts, totals
 
 
-def _hue_mask_rows(h, fgf, hue_ranges):
-    """Stacked per-color hue masks * foreground weight. (nc, n)."""
-    return color_masks(h, hue_ranges).astype(jnp.float32) * fgf[None]
+def _whole(fn, x, rows: int = SUB):
+    """Whole-slab reduction ``fn`` (jnp.sum/min/max) as a (rows, 1)
+    column; Mosaic broadcasts along one of sublanes or lanes at a
+    time, so results stay columns until they meet a full slab."""
+    r = fn(fn(x, axis=1, keepdims=True), axis=0, keepdims=True)
+    return jnp.broadcast_to(r, (rows, 1))
+
+
+def _lanes(shape=(SUB, AUX)):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1)
 
 
 # ---------------------------------------------------------------------------
 # Per-frame histogram kernel (precomputed foreground mask)
 # ---------------------------------------------------------------------------
 
-def _hsv_hist_kernel(rgb_ref, fg_ref, counts_ref, totals_ref, fgtot_ref,
-                     *, hue_ranges, bs, bv):
+def _hsv_hist_kernel(rgb_ref, fg_ref, counts_ref, aux_ref,
+                     *, hue_ranges, ncp, bs, bv):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
-        totals_ref[...] = jnp.zeros_like(totals_ref)
-        fgtot_ref[...] = jnp.zeros_like(fgtot_ref)
+        aux_ref[...] = jnp.zeros_like(aux_ref)
 
-    rgb = rgb_ref[...]                                  # (BLOCK, 3)
-    fgf = fg_ref[...].astype(jnp.float32)               # (BLOCK,)
-    h, s, v = _rgb_to_hsv_block(rgb[:, 0], rgb[:, 1], rgb[:, 2])
-    onehot = _joint_onehot(s, v, bs, bv)                # (BLOCK, bins), once
-    rows = _hue_mask_rows(h, fgf, hue_ranges)           # (nc, BLOCK)
-
-    fgtot_ref[0, 0] += jnp.sum(fgf)
-    counts_ref[...] += jnp.dot(rows, onehot,
-                               preferred_element_type=jnp.float32)
-    totals_ref[0, :] += jnp.sum(rows, axis=1)
+    fgf = fg_ref[...]
+    h, s, v = _rgb_to_hsv_block(rgb_ref[0], rgb_ref[1], rgb_ref[2])
+    counts, totals = _tile_hist(h, s, v, fgf, hue_ranges=hue_ranges,
+                                ncp=ncp, bs=bs, bv=bv)
+    lane = _lanes((ncp, AUX))
+    counts_ref[...] += counts
+    aux_ref[...] += jnp.where(lane == 0, totals,
+                              jnp.where(lane == 1, _whole(jnp.sum, fgf, ncp),
+                                        0.0))
 
 
 @functools.partial(jax.jit, static_argnames=("hue_ranges", "bs", "bv",
@@ -134,36 +208,30 @@ def hsv_hist(rgb, fg, hue_ranges, bs: int = B_S, bv: int = B_V,
     Returns (counts (nc, bs*bv), totals (nc,), fg_total ()).
     interpret=None resolves backend-aware (compiled only on TPU).
     """
-    interpret = _resolve_interpret(interpret)
-    n = rgb.shape[0]
-    pad = (-n) % BLOCK
-    if pad:
-        rgb = jnp.pad(rgb, ((0, pad), (0, 0)))
-        fg = jnp.pad(fg.astype(jnp.float32), ((0, pad),))
-    fg = fg.astype(jnp.float32)
+    interpret = resolve_interpret(interpret)
+    npad = _round_up(rgb.shape[0], BLOCK)
     nc = len(hue_ranges)
-    grid = (rgb.shape[0] // BLOCK,)
-    counts, totals, fgtot = pl.pallas_call(
-        functools.partial(_hsv_hist_kernel, hue_ranges=hue_ranges,
+    ncp = _round_up(nc, SUB)
+    nb = bs * bv
+    counts, aux = pl.pallas_call(
+        functools.partial(_hsv_hist_kernel, hue_ranges=hue_ranges, ncp=ncp,
                           bs=bs, bv=bv),
-        grid=grid,
+        grid=(npad // BLOCK,),
         in_specs=[
-            pl.BlockSpec((BLOCK, 3), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
+            pl.BlockSpec((3, None, SUB, LANES), lambda i: (0, i, 0, 0)),
+            pl.BlockSpec((None, SUB, LANES), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((nc, bs * bv), lambda i: (0, 0)),
-            pl.BlockSpec((1, nc), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((ncp, nb), lambda i: (0, 0)),
+            pl.BlockSpec((ncp, AUX), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nc, bs * bv), jnp.float32),
-            jax.ShapeDtypeStruct((1, nc), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((ncp, nb), jnp.float32),
+            jax.ShapeDtypeStruct((ncp, AUX), jnp.float32),
         ],
         interpret=interpret,
-    )(rgb, fg)
-    return counts, totals[0], fgtot[0, 0]
+    )(_planar(rgb, npad), _tiles(fg.astype(jnp.float32), npad))
+    return counts[:nc], aux[:nc, 0], aux[0, 1]
 
 
 # ---------------------------------------------------------------------------
@@ -171,123 +239,146 @@ def hsv_hist(rgb, fg, hue_ranges, bs: int = B_S, bv: int = B_V,
 # ---------------------------------------------------------------------------
 
 def _ingest_kernel(rgb_ref, bg0_ref, gain0_ref, m_ref, norm_ref,
-                   counts_ref, totals_ref, fgtot_ref, util_ref,
-                   bg_ref, gain_ref, sums_ref, bbox_ref=None,
-                   *, hue_ranges, bs, bv, alpha, threshold, npix,
-                   use_fg, bg_valid, op, num_frames, num_tiles,
-                   width=0):
-    # grid (camera, frame, tile): all state/accumulator blocks are
-    # indexed by camera only, so each camera's span reuses its own lane
+                   counts_ref, aux_ref, bg_ref, gain_ref, *rest,
+                   hue_ranges, ncp, bs, bv, alpha, threshold, npix,
+                   use_fg, bg_valid, op, num_tiles, width=0):
+    # grid (camera, frame, tile): bg/gain blocks are indexed by camera
+    # only, so each camera's span reuses its own lane; counts/aux/bbox
+    # blocks by (camera, frame), so they accumulate over the tile loop
+    bbox_ref = rest[0] if width else None
+    sums_ref = rest[-1]         # (SUB, AUX) scratch: lane 0 sum v, 1 sum bg
     t = pl.program_id(1)        # frame (background recurrence is sequential)
     j = pl.program_id(2)        # pixel tile (inner)
     nc = len(hue_ranges)
+    lane = _lanes()
 
     @pl.when((t == 0) & (j == 0))
     def _init_state():
-        gain_ref[0, 0] = gain0_ref[0, 0]
+        gain_ref[...] = gain0_ref[...]
         sums_ref[...] = jnp.zeros_like(sums_ref)
 
-    rgb = rgb_ref[0, 0]                                 # (BLOCK, 3)
-    h, s, v = _rgb_to_hsv_block(rgb[:, 0], rgb[:, 1], rgb[:, 2])
-    validf = (j * BLOCK
-              + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)[:, 0]
-              < npix).astype(jnp.float32)
+    h, s, v = _rgb_to_hsv_block(rgb_ref[0], rgb_ref[1], rgb_ref[2])
+    pidx = _pixel_index(j)
+    validf = (pidx < npix).astype(jnp.float32)
 
     # --- EMA background subtraction (state carried across frames/batches)
-    sl = pl.dslice(j * BLOCK, BLOCK)
     if bg_valid:
-        base = jnp.where(t == 0, bg0_ref[0, sl], bg_ref[0, sl])
+        base = jnp.where(t == 0, bg0_ref[j], bg_ref[j])
     else:
         # no prior state: frame 0 seeds the background with itself, so its
         # |comp - base| is 0 -> all-background, matching the host model
-        base = jnp.where(t == 0, v, bg_ref[0, sl])
-    gain = jnp.clip(gain_ref[0, 0], GAIN_MIN, GAIN_MAX)
+        base = jnp.where(t == 0, v, bg_ref[j])
+    gain = jnp.clip(gain_ref[:, 0:1], GAIN_MIN, GAIN_MAX)
     comp = v / gain
     fgf = ((jnp.abs(comp - base) > threshold).astype(jnp.float32)
            if use_fg else jnp.ones_like(v)) * validf
-    bg_ref[0, sl] = (1.0 - alpha) * base + alpha * comp
+    bg_ref[j] = (1.0 - alpha) * base + alpha * comp
 
     # one-frame-lagged global gain estimate: mean(v) / mean(bg)
-    sums_ref[0, 0] += jnp.sum(v * validf)
-    sums_ref[0, 1] += jnp.sum(base * validf)
+    sums_ref[...] += jnp.where(
+        lane == 0, _whole(jnp.sum, v * validf),
+        jnp.where(lane == 1, _whole(jnp.sum, base * validf), 0.0))
 
     @pl.when(j == num_tiles - 1)
     def _advance_gain():
-        gain_ref[0, 0] = jnp.clip(
-            sums_ref[0, 0] / jnp.maximum(sums_ref[0, 1], 1e-6),
-            GAIN_MIN, GAIN_MAX)
+        sums = sums_ref[...]
+        ratio = sums[:, 0:1] / jnp.maximum(sums[:, 1:2], 1e-6)
+        gain_ref[...] = jnp.broadcast_to(
+            jnp.clip(ratio, GAIN_MIN, GAIN_MAX), gain_ref.shape)
         sums_ref[...] = jnp.zeros_like(sums_ref)
 
-    # --- joint-bin one-hot once per tile; colors applied via one matmul
-    onehot = _joint_onehot(s, v, bs, bv)                # (BLOCK, bins)
-    rows = _hue_mask_rows(h, fgf, hue_ranges)           # (nc, BLOCK)
-    counts_t = jnp.dot(rows, onehot,
-                       preferred_element_type=jnp.float32)   # (nc, bins)
-    totals_t = jnp.sum(rows, axis=1)                    # (nc,)
-    fgtot_t = jnp.sum(fgf)
-
-    ts = pl.dslice(t, 1)
+    counts_t, totals_t = _tile_hist(h, s, v, fgf, hue_ranges=hue_ranges,
+                                    ncp=ncp, bs=bs, bv=bv)
+    lane_c = _lanes((ncp, AUX))
+    aux_t = jnp.where(lane_c == 0, totals_t,
+                      jnp.where(lane_c == 1, _whole(jnp.sum, fgf, ncp),
+                                0.0))
 
     # --- foreground bounding box (the cascade's free ROI): per-tile
     # masked min/max over (row, col) of the flattened pixel index,
     # min-combined across tiles; empty frames finalize to all -1
     if width:
-        pidx = (j * BLOCK
-                + jax.lax.broadcasted_iota(jnp.int32, (BLOCK, 1), 0)[:, 0])
-        rows_px = pidx // width
-        cols_px = pidx % width
+        # exact pixel row: float estimate, then one integer correction
+        row = (pidx.astype(jnp.float32) * (1.0 / width)).astype(jnp.int32)
+        row = jnp.where(row * width > pidx, row - 1, row)
+        row = jnp.where((row + 1) * width <= pidx, row + 1, row)
+        col = pidx - row * width
         on = fgf > 0
-        big = jnp.int32(npix)
-        vals = jnp.stack([
-            jnp.min(jnp.where(on, rows_px, big)),
-            jnp.max(jnp.where(on, rows_px, -1)),
-            jnp.min(jnp.where(on, cols_px, big)),
-            jnp.max(jnp.where(on, cols_px, -1))]).astype(jnp.int32)
+        big = jnp.float32(npix)
+        rowf, colf = row.astype(jnp.float32), col.astype(jnp.float32)
+
+        def red(fn, x, empty):
+            return _whole(fn, jnp.where(on, x, empty))
+
+        vals = jnp.where(
+            lane == 0, red(jnp.min, rowf, big),
+            jnp.where(lane == 1, red(jnp.max, rowf, -1.0),
+                      jnp.where(lane == 2, red(jnp.min, colf, big),
+                                red(jnp.max, colf, -1.0))))
+        is_min = (lane == 0) | (lane == 2)
 
         @pl.when(j == 0)
         def _bbox_first():
-            bbox_ref[0, ts, :] = vals[None]
+            bbox_ref[...] = vals
 
         @pl.when(j > 0)
         def _bbox_accum():
-            prev = bbox_ref[0, ts, :][0]
-            mn = jnp.minimum(prev, vals)
-            mx = jnp.maximum(prev, vals)
-            # lanes 0/2 are mins, lanes 1/3 are maxes
-            is_min = (jax.lax.broadcasted_iota(jnp.int32, (4, 1), 0)[:, 0]
-                      % 2) == 0
-            bbox_ref[0, ts, :] = jnp.where(is_min, mn, mx)[None]
+            prev = bbox_ref[...]
+            bbox_ref[...] = jnp.where(is_min, jnp.minimum(prev, vals),
+                                      jnp.maximum(prev, vals))
 
         @pl.when(j == num_tiles - 1)
         def _bbox_final():
-            cur = bbox_ref[0, ts, :][0]
-            bbox_ref[0, ts, :] = jnp.where(cur[1] < 0, jnp.int32(-1),
-                                           cur)[None]
+            cur = bbox_ref[...]
+            rmax = jnp.max(jnp.where(lane == 1, cur, -1.0), axis=1,
+                           keepdims=True)
+            bbox_ref[...] = jnp.where(rmax < 0, -1.0, cur)
 
     @pl.when(j == 0)
     def _first_tile():
-        counts_ref[0, ts, :, :] = counts_t[None]
-        totals_ref[0, ts, :] = totals_t[None]
-        fgtot_ref[0, ts] = fgtot_t[None]
+        counts_ref[...] = counts_t
+        aux_ref[...] = aux_t
 
     @pl.when(j > 0)
     def _accumulate():
-        counts_ref[0, ts, :, :] += counts_t[None]
-        totals_ref[0, ts, :] += totals_t[None]
-        fgtot_ref[0, ts] += fgtot_t[None]
+        counts_ref[...] += counts_t
+        aux_ref[...] += aux_t
 
-    # --- in-kernel utility (Eq. 14-15) once this camera's counts are final
-    @pl.when((t == num_frames - 1) & (j == num_tiles - 1))
+    # --- in-kernel utility (Eq. 14-15) once this frame's counts are final
+    @pl.when(j == num_tiles - 1)
     def _finalize_utility():
-        counts = counts_ref[0]                          # (T, nc, bins)
-        totals = totals_ref[0]                          # (T, nc)
-        pf = counts / jnp.maximum(totals, 1.0)[..., None]
-        u = jnp.sum(pf * m_ref[...][None], axis=-1)     # (T, nc)
-        u = u / jnp.maximum(norm_ref[0, :], 1e-9)[None]
+        aux = aux_ref[...]
+        pf = counts_ref[...] / jnp.maximum(aux[:, 0:1], 1.0)
+        u = jnp.sum(pf * m_ref[...], axis=1, keepdims=True)     # (ncp, 1)
+        u = u / jnp.maximum(norm_ref[:, 0:1], 1e-9)
+        real = jax.lax.broadcasted_iota(jnp.int32, (ncp, 1), 0) < nc
         if op == "and":
-            util_ref[...] = jnp.min(u, axis=-1)[None]
+            util = jnp.min(jnp.where(real, u, jnp.inf), axis=0,
+                           keepdims=True)
         else:                                           # single / or
-            util_ref[...] = jnp.max(u, axis=-1)[None]
+            util = jnp.max(jnp.where(real, u, -jnp.inf), axis=0,
+                           keepdims=True)
+        aux_ref[...] = jnp.where(lane_c == 2,
+                                 jnp.broadcast_to(util, (ncp, 1)), aux)
+
+
+# (SUB, LANES) values the kernel body names, counted as if all were live
+# at once: HSV conversion 12 (r, g, b and 9 intermediates), pixel index
+# and validity 4, background/foreground update 6, gain sums 2, joint bin
+# 4, bbox row/column math 12, and one mask per colour row (up to SUB)
+TILE_TEMPS = 48
+
+
+def _ingest_vmem_bytes(npad: int, ncp: int, nb: int) -> int:
+    """VMEM the ingest kernel needs: the double-buffered background lane
+    in and out, the double-buffered per-step blocks (RGB tile, counts
+    and aux slabs, gain/bbox slabs), one row's bin one-hot and the tile
+    temporaries, all float32."""
+    lane = 4 * npad
+    tile = 4 * BLOCK
+    blocks = 2 * (3 * tile + 4 * ncp * (nb + AUX) + 3 * 4 * SUB * AUX)
+    work = 4 * nb * LANES + TILE_TEMPS * tile
+    return 4 * lane + blocks + work
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -317,69 +408,79 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
     ``-1`` for empty masks — the in-kernel ROI for the semantic
     cascade, accumulated tile-by-tile at zero extra passes.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     has_cams = rgb.ndim == 4
     if not has_cams:
         rgb = rgb[None]
     C, T, n = rgb.shape[0], rgb.shape[1], rgb.shape[2]
-    bg0 = jnp.asarray(bg0, jnp.float32).reshape(C, n)
-    # a scalar gain broadcasts to every camera lane, same as the oracle
-    gain0 = jnp.broadcast_to(
-        jnp.asarray(gain0, jnp.float32).reshape(-1, 1), (C, 1))
-    pad = (-n) % BLOCK
-    if pad:
-        rgb = jnp.pad(rgb, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        bg0 = jnp.pad(bg0, ((0, 0), (0, pad)))
-    npad = n + pad
+    npad = _round_up(n, BLOCK)
     num_tiles = npad // BLOCK
     nc = len(hue_ranges)
+    ncp = _round_up(nc, SUB)
     nb = bs * bv
+    vmem = _ingest_vmem_bytes(npad, ncp, nb)
+    if vmem > VMEM_CAP:
+        raise ValueError(
+            f"{n}-pixel frames need {vmem} B of VMEM for the resident "
+            f"background lane, over the {VMEM_CAP} B cap")
+    bg0 = _tiles(jnp.asarray(bg0, jnp.float32).reshape(C, n), npad)
+    # a scalar gain broadcasts to every camera lane, same as the oracle
+    gain0 = jnp.broadcast_to(
+        jnp.asarray(gain0, jnp.float32).reshape(-1, 1, 1), (C, SUB, AUX))
+    M_pos = jnp.pad(M_pos.astype(jnp.float32), ((0, ncp - nc), (0, 0)))
+    norm = jnp.broadcast_to(
+        jnp.pad(norm.astype(jnp.float32), (0, ncp - nc),
+                constant_values=1.0)[:, None], (ncp, AUX))
 
+    frame_block = lambda c, t, j: (c, t, 0, 0)    # noqa: E731
+    lane_block = lambda c, t, j: (c, 0, 0, 0)     # noqa: E731
+    cam_block = lambda c, t, j: (c, 0, 0)         # noqa: E731
+    const_block = lambda c, t, j: (0, 0)          # noqa: E731
     out_specs = [
-        pl.BlockSpec((1, T, nc, nb), lambda c, t, j: (c, 0, 0, 0)),
-        pl.BlockSpec((1, T, nc), lambda c, t, j: (c, 0, 0)),
-        pl.BlockSpec((1, T), lambda c, t, j: (c, 0)),
-        pl.BlockSpec((1, T), lambda c, t, j: (c, 0)),
-        pl.BlockSpec((1, npad), lambda c, t, j: (c, 0)),
-        pl.BlockSpec((1, 1), lambda c, t, j: (c, 0)),
-        pl.BlockSpec((1, 2), lambda c, t, j: (c, 0)),
+        pl.BlockSpec((None, None, ncp, nb), frame_block),
+        pl.BlockSpec((None, None, ncp, AUX), frame_block),
+        pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
+        pl.BlockSpec((None, SUB, AUX), cam_block),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((C, T, nc, nb), jnp.float32),
-        jax.ShapeDtypeStruct((C, T, nc), jnp.float32),
-        jax.ShapeDtypeStruct((C, T), jnp.float32),
-        jax.ShapeDtypeStruct((C, T), jnp.float32),
-        jax.ShapeDtypeStruct((C, npad), jnp.float32),
-        jax.ShapeDtypeStruct((C, 1), jnp.float32),
-        jax.ShapeDtypeStruct((C, 2), jnp.float32),
+        jax.ShapeDtypeStruct((C, T, ncp, nb), jnp.float32),
+        jax.ShapeDtypeStruct((C, T, ncp, AUX), jnp.float32),
+        jax.ShapeDtypeStruct((C, num_tiles, SUB, LANES), jnp.float32),
+        jax.ShapeDtypeStruct((C, SUB, AUX), jnp.float32),
     ]
     if width:
-        out_specs.append(pl.BlockSpec((1, T, 4), lambda c, t, j: (c, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((C, T, 4), jnp.int32))
+        out_specs.append(pl.BlockSpec((None, None, SUB, AUX), frame_block))
+        out_shape.append(jax.ShapeDtypeStruct((C, T, SUB, AUX),
+                                              jnp.float32))
 
     results = pl.pallas_call(
         functools.partial(
-            _ingest_kernel, hue_ranges=hue_ranges, bs=bs, bv=bv,
+            _ingest_kernel, hue_ranges=hue_ranges, ncp=ncp, bs=bs, bv=bv,
             alpha=alpha, threshold=threshold, npix=n, use_fg=use_fg,
-            bg_valid=bg_valid, op=op, num_frames=T, num_tiles=num_tiles,
+            bg_valid=bg_valid, op=op, num_tiles=num_tiles,
             width=int(width)),
         grid=(C, T, num_tiles),
         in_specs=[
-            pl.BlockSpec((1, 1, BLOCK, 3), lambda c, t, j: (c, t, j, 0)),
-            pl.BlockSpec((1, npad), lambda c, t, j: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c, t, j: (c, 0)),
-            pl.BlockSpec((nc, nb), lambda c, t, j: (0, 0)),
-            pl.BlockSpec((1, nc), lambda c, t, j: (0, 0)),
+            pl.BlockSpec((None, None, 3, None, SUB, LANES),
+                         lambda c, t, j: (c, t, 0, j, 0, 0)),
+            pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
+            pl.BlockSpec((None, SUB, AUX), cam_block),
+            pl.BlockSpec((ncp, nb), const_block),
+            pl.BlockSpec((ncp, AUX), const_block),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((SUB, AUX), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(vmem, VMEM_FLOOR)),
         interpret=interpret,
-    )(rgb.astype(jnp.float32), bg0, gain0,
-      M_pos.astype(jnp.float32), norm.astype(jnp.float32)[None])
-    counts, totals, fgtot, util, bg, gain = results[:6]
-    out = [counts, totals, fgtot, util, bg[:, :n], gain[:, 0]]
+    )(_planar(rgb, npad), bg0, gain0, M_pos, norm)
+    counts, aux, bg, gain = results[:4]
+    out = [counts[:, :, :nc], aux[:, :, :nc, 0], aux[:, :, 0, 1],
+           aux[:, :, 0, 2], bg.reshape(C, npad)[:, :n], gain[:, 0, 0]]
     if width:
-        out.append(results[7])
+        out.append(results[4][:, :, 0, :4].astype(jnp.int32))
     if has_cams:
         return tuple(out)
     return tuple(o[0] for o in out)

@@ -24,11 +24,8 @@ import jax.numpy as jnp
 
 from repro.core.colors import Color
 from repro.core.utility import B_S, B_V, UtilityModel
-from repro.kernels.hsv_features.kernel import (
-    default_interpret,
-    hsv_hist,
-    ingest_batch,
-)
+from repro.kernels import default_interpret
+from repro.kernels.hsv_features.kernel import hsv_hist, ingest_batch
 from repro.kernels.hsv_features.ref import ingest_batch_ref, pf_from_counts
 
 

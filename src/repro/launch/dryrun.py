@@ -35,7 +35,6 @@ from repro.sharding.api import (
     num_params,
     spec_partition_specs,
     spec_shapes,
-    use_mesh,
 )
 from repro.sharding.caches import cache_partition_specs
 from repro.train.optimizer import AdamW, constant_lr
@@ -98,7 +97,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, fsdp: bool = True,
             lambda s: NamedSharding(mesh, s), tree_pspecs,
             is_leaf=lambda x: isinstance(x, P))
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = AdamW(lr=constant_lr(3e-4))
             opt_shapes = jax.eval_shape(opt.init, param_shapes)

@@ -4,12 +4,14 @@ from __future__ import annotations
 
 import jax
 
+from repro.sharding.api import auto_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 (512 chips, 2 pods)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -17,4 +19,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(1, n // data))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

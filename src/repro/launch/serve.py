@@ -16,6 +16,9 @@ The replay is paced by a virtual clock by default (deterministic given
 in real time, which is the service's production default.
 
   PYTHONPATH=src python -m repro.launch.serve --cams 8 --frames 300
+
+``--height/--width`` set the rendered camera resolution (48x80 by
+default; ``chip_smoke.py`` serves 1280x720).
 """
 from __future__ import annotations
 
@@ -31,6 +34,7 @@ from repro.configs import get_smoke_config
 from repro.core import RED, Query, open_session, overall_qor
 from repro.data.pipeline import camera_array_records, scenario_records
 from repro.data.synthetic import generate_dataset
+from repro.launch.jax_cache import enable_compile_cache
 from repro.models import lm_specs, lm_forward
 from repro.serve import (
     Arrival,
@@ -64,11 +68,16 @@ def make_lm_backend(arch: str = "smollm-135m", seq: int = 64,
     return backend
 
 
-def main():
+def main(argv=None):
+    """Run the service once; returns its ``ServiceResult``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--cams", type=int, default=8)
     ap.add_argument("--frames", type=int, default=300)
     ap.add_argument("--fps", type=float, default=30.0)
+    ap.add_argument("--height", type=int, default=48,
+                    help="rendered frame height (pixels)")
+    ap.add_argument("--width", type=int, default=80,
+                    help="rendered frame width (pixels)")
     ap.add_argument("--latency-bound", type=float, default=0.5)
     ap.add_argument("--seed", type=int, default=0,
                     help="seed for scenario generation and backend jitter")
@@ -94,9 +103,10 @@ def main():
                          "instead of raw frames via the fused step")
     ap.add_argument("--metrics-out", default="results/serve/metrics.json",
                     help="metrics JSON path (a .csv lands next to it)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    h, w = 48, 80
+    h, w = args.height, args.width
     query = Query.single(RED, latency_bound=args.latency_bound, fps=args.fps)
 
     print("generating scenarios...")
@@ -156,6 +166,7 @@ def main():
     print(f"metrics -> {out} / {out.with_suffix('.csv')}")
     print()
     print(service.metrics.report("service metrics"))
+    return res
 
 
 if __name__ == "__main__":

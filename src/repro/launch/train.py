@@ -25,7 +25,6 @@ from repro.sharding.api import (
     num_params,
     spec_partition_specs,
     spec_shardings,
-    use_mesh,
 )
 from repro.train.fault import FaultConfig, FaultInjector, run_training
 from repro.train.optimizer import AdamW, warmup_cosine
@@ -41,7 +40,7 @@ def build(arch: str, smoke: bool, batch: int, seq: int, steps: int,
     pspecs = spec_partition_specs(specs, mesh)
     opt = AdamW(lr=warmup_cosine(lr, max(10, steps // 20), steps))
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.jit(lambda k: materialize(specs, k),
                          out_shardings=shardings)(jax.random.key(0))
         opt_state = jax.jit(opt.init, out_shardings={
@@ -86,7 +85,7 @@ def main():
     state = {"params": params, "opt_state": opt_state}
 
     def step_fn(state, batch):
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             p, o, m = jstep(state["params"], state["opt_state"], batch)
         return {"params": p, "opt_state": o}, m
 

@@ -16,7 +16,7 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis name -> ordered physical candidates. "data" expands to all
 # pure-DP axes present in the mesh (pod + data).
@@ -61,13 +61,12 @@ class ParamSpec:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
-def use_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists (>= 0.6), else the classic ``with mesh:`` global-mesh
-    context (0.4.x)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+def auto_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes, so shardings propagate through
+    eager ops and ``jit`` without per-op ``out_sharding`` annotations
+    (``make_mesh`` alone makes Explicit axes)."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def is_spec(x) -> bool:
@@ -194,17 +193,5 @@ def constrain(x, *axes, rules=None):
 
 
 def _current_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m

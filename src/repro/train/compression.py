@@ -92,8 +92,6 @@ def make_dp_compressed_train_step(loss_fn, opt, mesh, axis: str = "pod",
     """loss_fn(params, batch) -> (loss, metrics). Model replicated;
     batch sharded on its leading dim over ``axis``. EF state carries a
     leading per-pod dimension (size = mesh.shape[axis])."""
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.shape[axis]
 
     def init_ef(params):
@@ -115,11 +113,11 @@ def make_dp_compressed_train_step(loss_fn, opt, mesh, axis: str = "pod",
         pspec = jax.tree_util.tree_map(lambda _: P(), params)
         ef_spec = jax.tree_util.tree_map(lambda _: P(axis), params)
         bspec = jax.tree_util.tree_map(lambda _: P(axis), batch)
-        grads, ef, metrics = shard_map(
+        grads, ef, metrics = jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(pspec, ef_spec, bspec),
             out_specs=(pspec, ef_spec, jax.tree_util.tree_map(lambda _: P(), metrics_shape(loss_fn))),
-            check_rep=False)(params, ef, batch)
+            check_vma=False)(params, ef, batch)
         params, opt_state, om = opt.update(grads, opt_state, params)
         return params, opt_state, ef, {**metrics, **om}
 

@@ -19,7 +19,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import lm_specs
@@ -103,9 +102,9 @@ def make_pp_loss(cfg, mesh, num_microbatches: int, axis: str = "stage"):
             jax.tree_util.tree_map(lambda _: P(axis), blocks),
             P(), P(), (P() if head_p is not None else None),
             P(), P())
-        fn = shard_map(pp_fn, mesh=mesh,
-                       in_specs=in_specs, out_specs=P(),
-                       check_rep=False)
+        fn = jax.shard_map(pp_fn, mesh=mesh,
+                           in_specs=in_specs, out_specs=P(),
+                           check_vma=False)
         return fn(blocks, params["embed"], params["final_norm"], head_p,
                   toks, labs)
 

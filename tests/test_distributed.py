@@ -16,7 +16,7 @@ def run_py(code: str, ndev: int = 8, timeout: int = 600) -> str:
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={ndev}").strip()
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -30,7 +30,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models import lm_specs, lm_loss
-from repro.sharding.api import materialize, spec_shardings, use_mesh
+from repro.sharding.api import materialize, spec_shardings, auto_mesh
 cfg = get_smoke_config('smollm-135m')
 specs = lm_specs(cfg)
 params = materialize(specs, jax.random.key(0))
@@ -38,9 +38,9 @@ toks = jax.random.randint(jax.random.key(1), (4, 33), 0, cfg.vocab_size)
 batch = {'tokens': toks[:, :-1], 'labels': toks[:, 1:]}
 l1, _ = jax.jit(lambda p, b: lm_loss(cfg, p, b))(params, batch)
 
-mesh = jax.make_mesh((2, 2), ('data', 'model'))
+mesh = auto_mesh((2, 2), ('data', 'model'))
 sh = spec_shardings(specs, mesh)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     ps = jax.device_put(params, sh)
     bs = {k: jax.device_put(v, NamedSharding(mesh, P('data', None)))
           for k, v in batch.items()}
@@ -56,7 +56,7 @@ def test_pipeline_parallel_matches_unpipelined():
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.configs import get_smoke_config, scaled
 from repro.models import lm_specs, lm_loss
-from repro.sharding.api import materialize, use_mesh
+from repro.sharding.api import materialize, auto_mesh
 from repro.train.pipeline_parallel import make_pp_loss
 cfg = scaled(get_smoke_config('smollm-135m'), num_layers=4, remat='none')
 specs = lm_specs(cfg)
@@ -65,15 +65,15 @@ toks = jax.random.randint(jax.random.key(1), (8, 17), 0, cfg.vocab_size)
 batch = {'tokens': toks[:, :-1], 'labels': toks[:, 1:]}
 ref, _ = jax.jit(lambda p, b: lm_loss(cfg, p, b))(params, batch)
 
-mesh = jax.make_mesh((4,), ('stage',))
+mesh = auto_mesh((4,), ('stage',))
 pp_loss = make_pp_loss(cfg, mesh, num_microbatches=4)
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     lp = jax.jit(pp_loss)(params, batch)
 print('PP', float(ref), float(lp))
 assert abs(float(ref) - float(lp)) < 5e-3, (float(ref), float(lp))
 
 # gradients flow through all stages
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(pp_loss))(params, batch)
 gn = [float(jnp.sum(jnp.abs(x))) for x in jax.tree_util.tree_leaves(g['blocks'])]
 assert all(v > 0 for v in gn), gn
@@ -87,7 +87,7 @@ def test_dp_compressed_training_converges():
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_smoke_config, scaled
 from repro.models import lm_specs, lm_loss
-from repro.sharding.api import materialize, use_mesh
+from repro.sharding.api import materialize, auto_mesh
 from repro.train.compression import make_dp_compressed_train_step
 from repro.train.optimizer import AdamW, constant_lr
 from repro.data.pipeline import BigramStream
@@ -95,7 +95,7 @@ from repro.data.pipeline import BigramStream
 cfg = scaled(get_smoke_config('smollm-135m'), num_layers=2)
 params = materialize(lm_specs(cfg), jax.random.key(0))
 opt = AdamW(lr=constant_lr(1e-2), weight_decay=0.0)
-mesh = jax.make_mesh((4,), ('pod',))
+mesh = auto_mesh((4,), ('pod',))
 loss_fn = lambda p, b: lm_loss(cfg, p, b)
 step, init_ef = make_dp_compressed_train_step(loss_fn, opt, mesh, axis='pod',
                                               method='int8')
@@ -104,7 +104,7 @@ opt_state = opt.init(params)
 stream = BigramStream(cfg.vocab_size, seed=0)
 rng = np.random.default_rng(0)
 losses = []
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     jstep = jax.jit(step)
     for i in range(60):
         toks = stream.sample(rng, 8, 32)
@@ -124,19 +124,19 @@ import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_smoke_config
 from repro.models import lm_specs
-from repro.sharding.api import materialize, spec_shardings, spec_shapes, use_mesh
+from repro.sharding.api import materialize, spec_shardings, spec_shapes, auto_mesh
 from repro.train import checkpoint as ckpt
 import tempfile, numpy as np
 
 cfg = get_smoke_config('qwen2.5-32b')
 specs = lm_specs(cfg)
-mesh4 = jax.make_mesh((2, 2), ('data', 'model'))
+mesh4 = auto_mesh((2, 2), ('data', 'model'))
 sh4 = spec_shardings(specs, mesh4)
 params = jax.device_put(materialize(specs, jax.random.key(0)), sh4)
 d = tempfile.mkdtemp()
 ckpt.save(d, 11, params)
 
-mesh2 = jax.make_mesh((1, 2), ('data', 'model'))
+mesh2 = auto_mesh((1, 2), ('data', 'model'))
 sh2 = spec_shardings(specs, mesh2)
 out2, step, _ = ckpt.restore(d, spec_shapes(specs), shardings=sh2)
 assert step == 11

@@ -33,7 +33,7 @@ def run_py(code: str, ndev: int = 8, timeout: int = 600) -> str:
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={ndev}").strip()
     env["PYTHONPATH"] = str(REPO / "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-3000:]

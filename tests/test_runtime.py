@@ -151,9 +151,9 @@ def test_ef_accumulates_to_exact_sum(rng):
     gradients (within quantization of the final residual)."""
     if len(jax.devices()) < 1:
         pytest.skip("needs a device")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("pod",))
+    from repro.sharding.api import auto_mesh
+    mesh = auto_mesh((1,), ("pod",))
     g_seq = [jnp.asarray(rng.standard_normal(64), jnp.float32) * 0.01
              for _ in range(20)]
     ef = {"g": jnp.zeros(64)}
@@ -163,8 +163,9 @@ def test_ef_accumulates_to_exact_sum(rng):
     def step(g, e):
         return ef_compressed_psum({"g": g}, e, "pod", "int8")
 
-    smapped = shard_map(step, mesh=mesh, in_specs=(P(), {"g": P()}),
-                        out_specs=({"g": P()}, {"g": P()}))
+    smapped = jax.shard_map(step, mesh=mesh, in_specs=(P(), {"g": P()}),
+                            out_specs=({"g": P()}, {"g": P()}),
+                            check_vma=False)
     jstep = jax.jit(smapped)
     for g in g_seq:
         red, ef = jstep(g, ef)

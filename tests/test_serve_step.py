@@ -119,6 +119,38 @@ def test_queue_lanes_property_parity(pool, seed):
                    utilities=[np.float32(x) for x in pool])
 
 
+SUBNORMAL_POOL = [0.0, 5.6e-45, -0.0, 2.0**-127, 2.0**-126, 0.5]
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_queue_lanes_subnormal_utilities(seed):
+    """Subnormal utilities enter every queue as 0.0: the device lanes
+    (whose float compares flush subnormals to zero) stay bitwise equal
+    to the host lanes and in step with the heapq reference."""
+    _run_mixed_ops(np.random.default_rng(seed),
+                   utilities=[np.float32(x) for x in SUBNORMAL_POOL])
+
+
+def test_subnormal_utilities_device_host_parity(rng):
+    """``step(utilities=...)`` with subnormals: both serve twins hold
+    the same CDF rings, thresholds and queues, bit for bit."""
+    C, T, W = 3, 8, 16
+    sessions = [open_session(Query.single("red", fps=10.0), num_cameras=C,
+                             cdf_window=W, serve=serve, exact_tick=True)
+                for serve in ("host", "device")]
+    for s in sessions:
+        s.report_backend_latency(0.2)
+    for _ in range(5):
+        u = rng.choice(np.array(SUBNORMAL_POOL, np.float32), (C, T))
+        res = [s.step(utilities=u, tick=True) for s in sessions]
+        np.testing.assert_array_equal(res[0].decisions, res[1].decisions)
+        for leaf in ("cdf_buf", "threshold", "q_util", "q_seq"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(sessions[0].state, leaf)),
+                np.asarray(getattr(sessions[1].state, leaf)), err_msg=leaf)
+    assert not np.any(np.signbit(np.asarray(sessions[1].state.cdf_buf)))
+
+
 def test_queue_fifo_tiebreaks():
     """Equal utilities: eviction removes the OLDEST (min seq); pop_best
     returns the oldest of the best; any-camera pop prefers the lowest

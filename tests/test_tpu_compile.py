@@ -1,0 +1,134 @@
+"""Compile-only checks against a described TPU v5e topology.
+
+The TPU compiler is installed even where no chip is attached: these
+tests lower the fused ingest kernel, the one-chip device serve step and
+the four-chip fleet serve step at camera resolution (8 cameras x 8
+frames x 1280x720) and compile them for the chip. Nothing runs, so they
+say nothing about results or speed; they catch what interpret mode
+cannot — tiling violations, VMEM overruns, programs that do not fit.
+
+The topology is described inside module-scoped fixtures only: one
+process at a time may load the TPU library, so describing it at import
+would make pytest-xdist workers collect different tests.
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+C, T, H, W = 8, 8, 720, 1280
+NPIX = H * W
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # compiles for a described chip cannot be read back without one
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def query():
+    from repro.core import Query
+    return Query.any_of("red", "yellow", latency_bound=0.5, fps=30.0)
+
+
+def _ingest_kw(query):
+    return dict(hue_ranges=query.hue_ranges, bs=query.bs, bv=query.bv,
+                alpha=query.alpha, fg_threshold=query.threshold,
+                use_fg=query.use_foreground, bg_valid=True, op="or",
+                impl="pallas", interpret=False)
+
+
+def _control_kw(num_total):
+    from repro.core.session import DEFAULT_TICK_CONFIG
+    return dict(update_cdf=True, do_tick=True, min_proc=1e-6, budget=0.4,
+                num_total=num_total, tick_cfg=DEFAULT_TICK_CONFIG)
+
+
+def _state_shapes(num_cameras, sharding_of):
+    """SessionState of ShapeDtypeStructs; ``sharding_of(name)`` places
+    each leaf."""
+    from repro.core.session import SessionState
+    st = SessionState.fresh(num_cameras, NPIX)
+    return SessionState(**{
+        f.name: jax.ShapeDtypeStruct(np.shape(getattr(st, f.name)),
+                                     np.asarray(getattr(st, f.name)).dtype,
+                                     sharding=sharding_of(f.name))
+        for f in dataclasses.fields(st)})
+
+
+@pytest.mark.parametrize("with_bbox", [False, True])
+def test_ingest_batch_compiles_at_720p(one_chip, query, with_bbox):
+    from repro.kernels.hsv_features.kernel import ingest_batch
+    nc = query.num_colors
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa: E731
+                                           sharding=one_chip)
+
+    def f(rgb, bg, gain, m, norm):
+        return ingest_batch(rgb, bg, gain, m, norm, query.hue_ranges,
+                            interpret=False, width=W if with_bbox else 0)
+
+    compiled = jax.jit(f).lower(
+        S((C, T, NPIX, 3)), S((C, NPIX)), S((C,)),
+        S((nc, query.bs * query.bv)), S((nc,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_device_serve_step_compiles_at_720p(one_chip, query):
+    from repro.core.session import _serve_step_dev
+    nc = query.num_colors
+    S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa: E731
+                                           sharding=one_chip)
+    state = _state_shapes(C, lambda _: one_chip)
+    compiled = _serve_step_dev.lower(
+        state, S((C, T, NPIX, 3)), S((nc, query.bs * query.bv)), S((nc,)),
+        **_ingest_kw(query), **_control_kw(C)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_fleet_serve_step_compiles_on_four_chips(topo, query):
+    from jax.sharding import Mesh
+    from repro.core import fleet
+    from repro.core.session import SessionState
+    mesh = Mesh(np.array(topo.devices[:4]), (fleet.CAMERA_AXIS,))
+    axis = fleet.CAMERA_AXIS
+    specs = fleet.state_pspecs(SessionState, axis)
+    cams = 4 * C
+    state = _state_shapes(
+        cams, lambda name: NamedSharding(mesh, getattr(specs, name)))
+    nc = query.num_colors
+    rep = NamedSharding(mesh, P())
+    frames = jax.ShapeDtypeStruct((cams, T, NPIX, 3), jnp.float32,
+                                  sharding=NamedSharding(mesh, P(axis)))
+    compiled = fleet._fleet_serve_step.lower(
+        state, frames,
+        jax.ShapeDtypeStruct((nc, query.bs * query.bv), jnp.float32,
+                             sharding=rep),
+        jax.ShapeDtypeStruct((nc,), jnp.float32, sharding=rep),
+        mesh=mesh, axis=axis, aggregate=True,
+        **_ingest_kw(query), **_control_kw(cams)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the psum aggregate tree is the step's only collective
+    assert "all-reduce" in text
