@@ -57,12 +57,13 @@ layer's backend-aware dispatch:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import heapq
 from dataclasses import dataclass
-from typing import (Any, Dict, List, NamedTuple, Optional, Sequence, Tuple,
-                    Union)
+from typing import (TYPE_CHECKING, Any, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple, Union)
 
 import jax
 import jax.numpy as jnp
@@ -95,6 +96,9 @@ from repro.kernels.hsv_features.ops import (
     query_constants,
 )
 
+if TYPE_CHECKING:
+    from repro.serve.metrics import MetricsRegistry
+
 # admit() decision codes — (C, T) int8 arrays, vectorized per camera
 # (offer_batch marks padding slots that carried no frame with -1)
 ADMIT = 0
@@ -104,6 +108,15 @@ SHED_CASCADE = 3     # passed the color gate, shed by the stage-2 scorer
 
 _DECISION_NAMES = {ADMIT: "queued", SHED_ADMISSION: "shed_admission",
                    SHED_QUEUE: "shed_queue", SHED_CASCADE: "shed_cascade"}
+
+# the span of a session opened without a metrics registry: one shared
+# context for every site, so an unmetered step allocates nothing and
+# reads no clock
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_span(name: str):
+    return _NO_SPAN
 
 
 class TickConfig(NamedTuple):
@@ -887,16 +900,28 @@ def _serve_step_dev(state, frames, M_pos, norm, *, hue_ranges, bs, bv,
     transfer."""
     bg0 = state.bg if bg_valid else jnp.zeros_like(state.bg)
     gain0 = state.gain if bg_valid else jnp.ones_like(state.gain)
+    # the Pallas ingest names its own device work: shed.stage (the
+    # planar relayout of the frames) and shed.score (the kernel)
     _, _, _, util, bg, gain = ingest_core(
         frames, bg0, gain0, M_pos, norm, hue_ranges=hue_ranges, bs=bs,
         bv=bv, alpha=alpha, threshold=fg_threshold, use_fg=use_fg,
         bg_valid=bg_valid, op=op, impl=impl, interpret=interpret)
     state = dataclasses.replace(state, bg=bg, gain=gain,
                                 bg_valid=jnp.asarray(True))
-    return _control_core_dev(state, util, None, update_cdf=update_cdf,
-                             do_tick=do_tick, min_proc=min_proc,
-                             budget=budget, num_total=num_total,
-                             tick_cfg=tick_cfg)
+    with jax.named_scope("shed.control"):
+        return _control_core_dev(state, util, None, update_cdf=update_cdf,
+                                 do_tick=do_tick, min_proc=min_proc,
+                                 budget=budget, num_total=num_total,
+                                 tick_cfg=tick_cfg)
+
+
+@jax.jit
+def _flatten_frames(frames):
+    """(C, T, H, W, 3) -> (C, T, H*W, 3) on the device: the frames'
+    first relayout, a program of its own under ``shed.stage``."""
+    C, T, H, W, _ = frames.shape
+    with jax.named_scope("shed.stage"):
+        return frames.reshape(C, T, H * W, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("update_cdf", "tick_cfg"),
@@ -1002,10 +1027,15 @@ class ShedSession:
                  quantile_bins: int = 256,
                  quantile_range: Tuple[float, float] = (0.0, 1.0),
                  s2_quantile_range: Tuple[float, float] = (-1.0, 1.0),
+                 metrics: Optional["MetricsRegistry"] = None,
                  ) -> None:
         if num_cameras < 1:
             raise ValueError("num_cameras must be >= 1")
         self.query = query
+        # host spans and counters of the serve path (session.*); None
+        # runs every span site through one shared no-op context
+        self.metrics = metrics
+        self._span = metrics.span if metrics is not None else _no_span
         self.num_cameras = int(num_cameras)
         self.model = model
         # semantic cascade (repro.cascade.Cascade, duck-typed: .scorer /
@@ -1373,7 +1403,26 @@ class ShedSession:
         queued frames are identified by their ``(cam, t)`` index pair.
         Only compact decision/eviction arrays return to the host — see
         :class:`StepResult`.
+
+        With a session ``metrics`` registry each call is a span
+        ``session.step`` and counts ``session.steps`` and its offered
+        frames in ``session.frames``. On the ``serve="device"`` frames
+        path its phases are the spans ``session.stage`` (float32 frames
+        on the host), ``session.put`` (the hand-off to the device and
+        the first relayout), ``session.dispatch``, ``session.readback``
+        and ``session.absorb`` (host bookkeeping).
         """
+        with self._span("session.step"):
+            res = self._step(frames, utilities, s2_utilities, items, tick,
+                             impl, interpret)
+        if self.metrics is not None:
+            self.metrics.counter("session.steps").inc()
+            self.metrics.counter("session.frames").inc(
+                int((res.decisions >= 0).sum()))
+        return res
+
+    def _step(self, frames, utilities, s2_utilities, items, tick, impl,
+              interpret) -> StepResult:
         if (frames is None) == (utilities is None):
             raise ValueError("pass exactly one of frames= or utilities=")
         if s2_utilities is not None and self.cascade is None:
@@ -1392,37 +1441,12 @@ class ShedSession:
             if self.model is None:
                 raise ValueError("step(frames=...) needs a trained model "
                                  "(call fit() or pass model=)")
+            if self.serve == "device":
+                return self._serve_frames(frames, items, tick, impl,
+                                          interpret, kw)
             frames = self._check_frames(frames)
             if frames.shape[1] == 0:
                 raise ValueError("empty frame batch")
-            q = self.query
-            if self.serve == "device":
-                n = frames.shape[2] * frames.shape[3]
-                flat = jnp.asarray(frames).reshape(
-                    self.num_cameras, frames.shape[1], n, 3)
-                M_pos, norm, op = self._model_constants()
-                use_impl = impl if impl is not None else self.impl
-                if use_impl is None:
-                    use_impl = default_impl()
-                ingest_kw = dict(
-                    hue_ranges=q.hue_ranges, bs=q.bs, bv=q.bv,
-                    alpha=q.alpha, fg_threshold=q.threshold,
-                    use_fg=q.use_foreground,
-                    bg_valid=bool(self.state.bg_valid), op=op,
-                    impl=use_impl,
-                    interpret=(interpret if interpret is not None
-                               else self.interpret))
-                if self.mesh is not None:
-                    from repro.core import fleet as _fleet
-                    self.state, out, agg = _fleet.serve_step(
-                        self.state, flat, M_pos, norm, mesh=self.mesh,
-                        axis=self._cam_axis,
-                        aggregate=self.fleet_aggregate, **ingest_kw, **kw)
-                    self._absorb_fleet(agg)
-                else:
-                    self.state, out = _serve_step_dev(
-                        self.state, flat, M_pos, norm, **ingest_kw, **kw)
-                return self._absorb_control(out, items, tick)
             util = self.ingest(frames, impl=impl,
                                interpret=interpret).utility
         else:
@@ -1442,14 +1466,54 @@ class ShedSession:
                     self.state, jnp.asarray(util, jnp.float32),
                     mesh=self.mesh, axis=self._cam_axis,
                     aggregate=self.fleet_aggregate, **kw)
-                self._absorb_fleet(agg)
-            else:
-                self.state, out = _control_step_dev(
-                    self.state, jnp.asarray(util, jnp.float32), **kw)
+                return self._absorb_control(out, items, tick, agg=agg)
+            self.state, out = _control_step_dev(
+                self.state, jnp.asarray(util, jnp.float32), **kw)
         else:
             self.state, out = _control_core_host(
                 self.state, util, None, **kw)
         return self._absorb_control(out, items, tick)
+
+    def _serve_frames(self, frames, items, tick, impl, interpret,
+                      kw) -> StepResult:
+        """The ``serve="device"`` frames step: float32 frames to the
+        device, then ONE fused serve-step dispatch (see ``step`` for
+        its spans)."""
+        span = self._span
+        with span("session.stage"):
+            frames = self._check_frames(frames)
+        if frames.shape[1] == 0:
+            raise ValueError("empty frame batch")
+        if self.metrics is not None:
+            self.metrics.counter("session.staged_bytes").inc(frames.nbytes)
+        with span("session.put"):
+            flat = _flatten_frames(jnp.asarray(frames))
+            del frames      # the float32 host copy goes once handed off
+        with span("session.dispatch"):
+            q = self.query
+            M_pos, norm, op = self._model_constants()
+            use_impl = impl if impl is not None else self.impl
+            if use_impl is None:
+                use_impl = default_impl()
+            ingest_kw = dict(
+                hue_ranges=q.hue_ranges, bs=q.bs, bv=q.bv,
+                alpha=q.alpha, fg_threshold=q.threshold,
+                use_fg=q.use_foreground,
+                bg_valid=bool(self.state.bg_valid), op=op,
+                impl=use_impl,
+                interpret=(interpret if interpret is not None
+                           else self.interpret))
+            agg = None
+            if self.mesh is not None:
+                from repro.core import fleet as _fleet
+                self.state, out, agg = _fleet.serve_step(
+                    self.state, flat, M_pos, norm, mesh=self.mesh,
+                    axis=self._cam_axis,
+                    aggregate=self.fleet_aggregate, **ingest_kw, **kw)
+            else:
+                self.state, out = _serve_step_dev(
+                    self.state, flat, M_pos, norm, **ingest_kw, **kw)
+        return self._absorb_control(out, items, tick, agg=agg, span=span)
 
     def _cascade_step(self, frames, utilities, s2_utilities, items, tick,
                       impl, interpret) -> StepResult:
@@ -1533,14 +1597,32 @@ class ShedSession:
     def _absorb_control(self, out: Dict[str, Any],
                         items: Optional[Sequence[Sequence[Any]]],
                         ticked: bool,
-                        s2_scores: Optional[np.ndarray] = None
-                        ) -> StepResult:
+                        s2_scores: Optional[np.ndarray] = None,
+                        agg: Optional[Dict[str, Any]] = None,
+                        span=_no_span) -> StepResult:
         """Fold a control step's compact outputs into host bookkeeping:
-        stats, payload registry, per-camera counters."""
-        decisions = np.asarray(out["decisions"])
-        pushed_seq = np.asarray(out["pushed_seq"])
-        ev_res = np.asarray(out["evicted_resident"])
-        push_ev = np.asarray(out["push_evictions"])
+        stats, payload registry, per-camera counters. Every read of the
+        outputs (the span ``session.readback`` where ``span`` times
+        them, waiting for the device) comes before the bookkeeping
+        (``session.absorb``). ``agg``: a sharded step's psum aggregate
+        tree, if it made one."""
+        with span("session.readback"):
+            decisions = np.asarray(out["decisions"])
+            pushed_seq = np.asarray(out["pushed_seq"])
+            ev_res = np.asarray(out["evicted_resident"])
+            push_ev = np.asarray(out["push_evictions"])
+            rates = rz = None
+            if ticked:
+                rates = np.asarray(out["rates"])
+                rz = np.asarray(out["resize_evicted"])
+            if agg is not None:
+                self._absorb_fleet(agg)
+        with span("session.absorb"):
+            return self._absorb_host(decisions, pushed_seq, ev_res, push_ev,
+                                     rates, rz, items, s2_scores)
+
+    def _absorb_host(self, decisions, pushed_seq, ev_res, push_ev, rates,
+                     rz, items, s2_scores) -> StepResult:
         C = decisions.shape[0]
         offered = decisions >= 0
         self.stats.offered += int(offered.sum())
@@ -1563,10 +1645,7 @@ class ShedSession:
             for s in evs:
                 pl.pop(int(s), None)
             evicted.append(evs.astype(np.int64))
-        rates = None
-        if ticked:
-            rates = np.asarray(out["rates"])
-            rz = np.asarray(out["resize_evicted"])
+        if rz is not None:
             cnt = (rz >= 0).sum(axis=1)
             self.stats.dropped_queue += int(cnt.sum())
             self.per_camera_dropped += cnt
@@ -1723,6 +1802,7 @@ class ShedSession:
         kw = dict(update_cdf=self.update_cdf_online, do_tick=False,
                   min_proc=self.min_proc, budget=self._budget,
                   num_total=self._num_active, tick_cfg=self._tick_cfg)
+        agg = None
         if self.serve == "device":
             if self.mesh is not None:
                 from repro.core import fleet as _fleet
@@ -1730,14 +1810,13 @@ class ShedSession:
                     self.state, jnp.asarray(util), jnp.asarray(present),
                     mesh=self.mesh, axis=self._cam_axis,
                     aggregate=self.fleet_aggregate, **kw)
-                self._absorb_fleet(agg)
             else:
                 self.state, out = _control_masked_dev(
                     self.state, jnp.asarray(util), jnp.asarray(present), **kw)
         else:
             self.state, out = _control_core_host(
                 self.state, util, present, **kw)
-        res = self._absorb_control(out, batch_items, ticked=False)
+        res = self._absorb_control(out, batch_items, ticked=False, agg=agg)
         codes = [""] * len(items)
         for (c, t), i in slot_of.items():
             codes[i] = _DECISION_NAMES[int(res.decisions[c, t])]
@@ -1769,37 +1848,39 @@ class ShedSession:
         loop of ``next_frame()`` calls would send, without a host sync
         per frame. ``cams`` restricts the pool to those camera lanes
         (default: the whole array). Returns up to ``k`` payloads; fewer
-        when the eligible queues drain first."""
+        when the eligible queues drain first. With a session
+        ``metrics`` registry the call is a span ``session.pop``."""
         if k <= 0:
             return []
-        rows = None
-        if cams is not None:
-            rows = np.zeros((self.num_cameras,), bool)
-            rows[[int(c) for c in cams]] = True
-        st = self.state
-        if self.serve == "device":
-            if self.mesh is not None:
-                from repro.core import fleet as _fleet
-                self.state, pc, ps = _fleet.pop_topk(
-                    st, mesh=self.mesh, axis=self._cam_axis, k=int(k),
-                    rows=None if rows is None else jnp.asarray(rows))
-            elif rows is None:
-                self.state, pc, ps = _pop_topk_dev(st, k=int(k))
+        with self._span("session.pop"):
+            rows = None
+            if cams is not None:
+                rows = np.zeros((self.num_cameras,), bool)
+                rows[[int(c) for c in cams]] = True
+            st = self.state
+            if self.serve == "device":
+                if self.mesh is not None:
+                    from repro.core import fleet as _fleet
+                    self.state, pc, ps = _fleet.pop_topk(
+                        st, mesh=self.mesh, axis=self._cam_axis, k=int(k),
+                        rows=None if rows is None else jnp.asarray(rows))
+                elif rows is None:
+                    self.state, pc, ps = _pop_topk_dev(st, k=int(k))
+                else:
+                    self.state, pc, ps = _pop_topk_masked_dev(
+                        st, jnp.asarray(rows), k=int(k))
+                pc, ps = np.asarray(pc), np.asarray(ps)
             else:
-                self.state, pc, ps = _pop_topk_masked_dev(
-                    st, jnp.asarray(rows), k=int(k))
-            pc, ps = np.asarray(pc), np.asarray(ps)
-        else:
-            pc, ps = sq.pop_topk_host(st.q_util, st.q_seq, int(k),
-                                      rows=rows)
-        items: List[Any] = []
-        for c, s in zip(pc.tolist(), ps.tolist()):
-            if s < 0:               # -1 padding: pool drained
-                break
-            self._depths[c] -= 1
-            items.append(self._payloads[c].pop(s, (c, s)))
-        self.stats.sent += len(items)
-        return items
+                pc, ps = sq.pop_topk_host(st.q_util, st.q_seq, int(k),
+                                          rows=rows)
+            items: List[Any] = []
+            for c, s in zip(pc.tolist(), ps.tolist()):
+                if s < 0:               # -1 padding: pool drained
+                    break
+                self._depths[c] -= 1
+                items.append(self._payloads[c].pop(s, (c, s)))
+            self.stats.sent += len(items)
+            return items
 
     def __len__(self) -> int:
         return int(self._depths.sum())
@@ -1842,19 +1923,22 @@ class ShedSession:
         A scalar call (``cam=None``) broadcasts to every lane — the
         shared-backend form, bit-identical to the pre-lane behavior.
         Pass ``cam`` to update one camera's lane, so heterogeneous
-        backends and sharded fleets estimate latency per camera."""
-        st, xp = self.state, self._xp
-        x = max(float(proc_latency), self.min_proc)
-        a = xp.where(x > st.proc_q, self.ewma_alpha_up, self.ewma_alpha)
-        new = xp.where(st.proc_seen, st.proc_q + a * (x - st.proc_q),
-                       x).astype(xp.float32)
-        if cam is None:
-            st.proc_q = new
-            st.proc_seen = xp.ones_like(st.proc_seen)
-        else:
-            upd = xp.arange(self.num_cameras) == int(cam)
-            st.proc_q = xp.where(upd, new, st.proc_q).astype(xp.float32)
-            st.proc_seen = st.proc_seen | upd
+        backends and sharded fleets estimate latency per camera. With a
+        session ``metrics`` registry the call is a span
+        ``session.report_latency``."""
+        with self._span("session.report_latency"):
+            st, xp = self.state, self._xp
+            x = max(float(proc_latency), self.min_proc)
+            a = xp.where(x > st.proc_q, self.ewma_alpha_up, self.ewma_alpha)
+            new = xp.where(st.proc_seen, st.proc_q + a * (x - st.proc_q),
+                           x).astype(xp.float32)
+            if cam is None:
+                st.proc_q = new
+                st.proc_seen = xp.ones_like(st.proc_seen)
+            else:
+                upd = xp.arange(self.num_cameras) == int(cam)
+                st.proc_q = xp.where(upd, new, st.proc_q).astype(xp.float32)
+                st.proc_seen = st.proc_seen | upd
 
     def report_ingress_fps(self, fps: float, cam: Optional[int] = None) -> None:
         """Observed ingress rate: per camera, or an aggregate rate split
@@ -2062,6 +2146,16 @@ def open_session(query: Query, num_cameras: int = 1, **kw: Any) -> ShedSession:
     of global shed/queue/backend stats per step (``last_fleet_stats``,
     ``fleet_stats()``). ``num_cameras`` must divide evenly over the
     mesh's camera axis.
+
+    Observability: ``metrics=MetricsRegistry()``
+    (``repro.serve.metrics``) times the serve path in host spans
+    (``session.step`` and its phases, ``session.pop``,
+    ``session.report_latency``; their ``span.*`` histograms reach
+    ``metrics.report()``) and counts ``session.steps``,
+    ``session.frames`` and ``session.staged_bytes``. A ``ServeService``
+    over the session reports into the same registry. Spans only read
+    the clock around calls that block anyway; without a registry
+    (the default) none is taken.
     """
     return ShedSession(query, num_cameras, **kw)
 

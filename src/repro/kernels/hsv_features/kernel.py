@@ -423,14 +423,17 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
         raise ValueError(
             f"{n}-pixel frames need {vmem} B of VMEM for the resident "
             f"background lane, over the {VMEM_CAP} B cap")
-    bg0 = _tiles(jnp.asarray(bg0, jnp.float32).reshape(C, n), npad)
-    # a scalar gain broadcasts to every camera lane, same as the oracle
-    gain0 = jnp.broadcast_to(
-        jnp.asarray(gain0, jnp.float32).reshape(-1, 1, 1), (C, SUB, AUX))
-    M_pos = jnp.pad(M_pos.astype(jnp.float32), ((0, ncp - nc), (0, 0)))
-    norm = jnp.broadcast_to(
-        jnp.pad(norm.astype(jnp.float32), (0, ncp - nc),
-                constant_values=1.0)[:, None], (ncp, AUX))
+    # device scopes a profile reads: shed.stage is the planar relayout
+    # of the frames, shed.score the kernel and its small operands
+    with jax.named_scope("shed.score"):
+        bg0 = _tiles(jnp.asarray(bg0, jnp.float32).reshape(C, n), npad)
+        # a scalar gain broadcasts to every camera lane, as the oracle's
+        gain0 = jnp.broadcast_to(
+            jnp.asarray(gain0, jnp.float32).reshape(-1, 1, 1), (C, SUB, AUX))
+        M_pos = jnp.pad(M_pos.astype(jnp.float32), ((0, ncp - nc), (0, 0)))
+        norm = jnp.broadcast_to(
+            jnp.pad(norm.astype(jnp.float32), (0, ncp - nc),
+                    constant_values=1.0)[:, None], (ncp, AUX))
 
     frame_block = lambda c, t, j: (c, t, 0, 0)    # noqa: E731
     lane_block = lambda c, t, j: (c, 0, 0, 0)     # noqa: E731
@@ -453,34 +456,38 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
         out_shape.append(jax.ShapeDtypeStruct((C, T, SUB, AUX),
                                               jnp.float32))
 
-    results = pl.pallas_call(
-        functools.partial(
-            _ingest_kernel, hue_ranges=hue_ranges, ncp=ncp, bs=bs, bv=bv,
-            alpha=alpha, threshold=threshold, npix=n, use_fg=use_fg,
-            bg_valid=bg_valid, op=op, num_tiles=num_tiles,
-            width=int(width)),
-        grid=(C, T, num_tiles),
-        in_specs=[
-            pl.BlockSpec((None, None, 3, None, SUB, LANES),
-                         lambda c, t, j: (c, t, 0, j, 0, 0)),
-            pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
-            pl.BlockSpec((None, SUB, AUX), cam_block),
-            pl.BlockSpec((ncp, nb), const_block),
-            pl.BlockSpec((ncp, AUX), const_block),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((SUB, AUX), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=max(vmem, VMEM_FLOOR)),
-        interpret=interpret,
-    )(_planar(rgb, npad), bg0, gain0, M_pos, norm)
-    counts, aux, bg, gain = results[:4]
-    out = [counts[:, :, :nc], aux[:, :, :nc, 0], aux[:, :, 0, 1],
-           aux[:, :, 0, 2], bg.reshape(C, npad)[:, :n], gain[:, 0, 0]]
-    if width:
-        out.append(results[4][:, :, 0, :4].astype(jnp.int32))
+    with jax.named_scope("shed.stage"):
+        planes = _planar(rgb, npad)
+    with jax.named_scope("shed.score"):
+        results = pl.pallas_call(
+            functools.partial(
+                _ingest_kernel, hue_ranges=hue_ranges, ncp=ncp, bs=bs, bv=bv,
+                alpha=alpha, threshold=threshold, npix=n, use_fg=use_fg,
+                bg_valid=bg_valid, op=op, num_tiles=num_tiles,
+                width=int(width)),
+            grid=(C, T, num_tiles),
+            in_specs=[
+                pl.BlockSpec((None, None, 3, None, SUB, LANES),
+                             lambda c, t, j: (c, t, 0, j, 0, 0)),
+                pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
+                pl.BlockSpec((None, SUB, AUX), cam_block),
+                pl.BlockSpec((ncp, nb), const_block),
+                pl.BlockSpec((ncp, AUX), const_block),
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((SUB, AUX), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=max(vmem, VMEM_FLOOR)),
+            interpret=interpret,
+            name="ingest_batch",
+        )(planes, bg0, gain0, M_pos, norm)
+        counts, aux, bg, gain = results[:4]
+        out = [counts[:, :, :nc], aux[:, :, :nc, 0], aux[:, :, 0, 1],
+               aux[:, :, 0, 2], bg.reshape(C, npad)[:, :n], gain[:, 0, 0]]
+        if width:
+            out.append(results[4][:, :, 0, :4].astype(jnp.int32))
     if has_cams:
         return tuple(out)
     return tuple(o[0] for o in out)

@@ -9,7 +9,8 @@ of the paper's filter/DNN split by default, or a real jitted LM forward
 with ``--real-backend``. Every completion feeds the frame's *measured*
 latency into the Eq. 17–20 control loop, and per-stage metrics (ingest
 fps, shed rate, coalescer wait, queue depth, backend utilization,
-p50/p95/p99 E2E latency, deadline violations) are exported as JSON/CSV.
+p50/p95/p99 E2E latency, deadline violations, and the session's
+wall-clock host spans ``span.session.*``) are exported as JSON/CSV.
 
 The replay is paced by a virtual clock by default (deterministic given
 ``--seed``, runs as fast as the host allows); ``--wall-clock`` paces it
@@ -38,6 +39,7 @@ from repro.launch.jax_cache import enable_compile_cache
 from repro.models import lm_specs, lm_forward
 from repro.serve import (
     Arrival,
+    MetricsRegistry,
     MockBackend,
     ServeService,
     VirtualClock,
@@ -115,8 +117,11 @@ def main(argv=None):
     train, test = scs[:3], scs[3:]
 
     # one session fronts the whole camera array; fit() trains the query's
-    # utility function and seeds the per-camera admission CDFs
-    session = open_session(query, num_cameras=args.cams, frame_shape=(h, w))
+    # utility function and seeds the per-camera admission CDFs; its spans
+    # and counters (session.*) land in the registry the service adopts,
+    # so the report below carries them
+    session = open_session(query, num_cameras=args.cams, frame_shape=(h, w),
+                           metrics=MetricsRegistry())
     train_recs = [r for i, s in enumerate(train)
                   for r in scenario_records(s, i, list(query.colors),
                                             fps=args.fps)]

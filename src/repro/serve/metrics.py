@@ -18,12 +18,21 @@ Exports:
 Histogram summaries carry count/mean/min/max and p50/p95/p99 — the
 end-to-end latency percentiles the paper's Eq. 16 latency bound is
 judged against.
+
+Spans: ``with registry.span(name):`` times a block on
+``time.perf_counter`` and adds its seconds to the histogram
+``span.<name>``. A span reads the clock and nothing else: it never
+waits for a device. Its readings are wall-clock times, so they are the
+one part of a snapshot that differs between repeats of a seeded
+virtual-clock run.
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import time
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, Iterator, List, Optional, Union
 
 import numpy as np
 
@@ -175,6 +184,16 @@ class MetricsRegistry:
         if s is None:
             s = self._states[name] = StateGauge(name)
         return s
+
+    @contextlib.contextmanager
+    def span(self, name: str) -> Iterator[None]:
+        """Time a ``with`` block into the histogram ``span.<name>``
+        (seconds, on ``time.perf_counter``)."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.histogram("span." + name).observe(time.perf_counter() - t0)
 
     # -- export --------------------------------------------------------------
 

@@ -217,6 +217,10 @@ class ServeService:
         # session whose report_backend_latency accepts ``cam=``
         self.per_camera_latency = bool(per_camera_latency)
         self.clock: Clock = clock if clock is not None else WallClock()
+        # one registry for the service and its session: a session opened
+        # with ``metrics=`` lends it, so its spans reach this report
+        if metrics is None:
+            metrics = getattr(session, "metrics", None)
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.num_cameras = int(getattr(session, "num_cameras", 1))
         self.control_period = float(control_period)

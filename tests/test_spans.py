@@ -1,0 +1,169 @@
+"""Host spans of the serve path: ``MetricsRegistry.span`` and the
+``session.*`` spans and counters a ``ShedSession`` with a registry
+records, which must not change a single decision and reach the report
+of a ``ServeService`` over the session."""
+import contextlib
+
+import numpy as np
+import pytest
+
+from repro.core import Query, RED, open_session, train_utility_model
+from repro.serve import Arrival, MockBackend, ServeService, VirtualClock
+from repro.serve.metrics import MetricsRegistry
+
+STEP_PHASES = ["session.stage", "session.put", "session.dispatch",
+               "session.readback", "session.absorb"]
+
+
+def _span_counts(reg):
+    return {k[len("span."):]: v["count"]
+            for k, v in reg.snapshot()["histograms"].items()
+            if k.startswith("span.")}
+
+
+def test_span_times_a_block_into_its_histogram():
+    reg = MetricsRegistry()
+    with reg.span("outer"):
+        with reg.span("inner"):
+            pass
+        with reg.span("inner"):
+            pass
+    hists = reg.snapshot()["histograms"]
+    assert hists["span.outer"]["count"] == 1
+    assert hists["span.inner"]["count"] == 2
+    h_out, h_in = reg.histogram("span.outer"), reg.histogram("span.inner")
+    assert 0.0 <= h_in.total <= h_out.total
+    assert "span.inner" in reg.report()
+
+
+def test_span_records_a_block_that_raises():
+    reg = MetricsRegistry()
+    with pytest.raises(KeyError):
+        with reg.span("failing"):
+            raise KeyError("x")
+    assert reg.histogram("span.failing").count == 1
+
+
+# ---------------------------------------------------------------------------
+# the session's spans and counters
+# ---------------------------------------------------------------------------
+
+C, T, H, W, STEPS = 3, 4, 48, 80, 3
+
+
+def _model():
+    rng = np.random.default_rng(0)
+    pfs = rng.random((40, 1, 8, 8)).astype(np.float32)
+    return train_utility_model(pfs, rng.random(40) < 0.5, [RED])
+
+
+def _session(metrics=None, **kw):
+    rng = np.random.default_rng(0)
+    kw.setdefault("model", _model())
+    return open_session(Query.single("red", latency_bound=1.0, fps=10.0),
+                        num_cameras=C, frame_shape=(H, W),
+                        train_utilities=rng.uniform(0, 1, 64)
+                        .astype(np.float32),
+                        queue_size=3, cdf_window=64, serve="device",
+                        metrics=metrics, **kw)
+
+
+def _drive(session):
+    """Steps of uint8 windows with a tick, each followed by a pop of C
+    frames and one latency report per popped frame."""
+    rng = np.random.default_rng(1)
+    out = []
+    for k in range(STEPS):
+        frames = rng.integers(0, 256, (C, T, H, W, 3), dtype=np.uint8)
+        res = session.step(frames=frames, tick=True)
+        popped = session.next_frames(C)
+        for i, _ in enumerate(popped):
+            session.report_backend_latency(0.05 + 0.01 * i)
+        out.append((res.decisions.copy(), res.target_drop_rate.copy(),
+                    popped))
+    return out
+
+
+def test_unmetered_session_shares_one_noop_span():
+    s = open_session(Query.single("red"), num_cameras=1)
+    assert s.metrics is None
+    first = s._span("session.step")
+    assert first is s._span("session.pop")
+    assert first is s._span("session.report_latency")
+    assert isinstance(first, contextlib.nullcontext)
+
+
+def test_session_spans_and_counters_cover_every_call():
+    reg = MetricsRegistry()
+    runs = _drive(_session(reg))
+    counts = _span_counts(reg)
+    reports = sum(len(p) for _, _, p in runs)
+    assert counts == {"session.step": STEPS, "session.pop": STEPS,
+                      "session.report_latency": reports,
+                      **{name: STEPS for name in STEP_PHASES}}
+    # the phases run inside their step
+    step_s = reg.histogram("span.session.step").total
+    phases_s = sum(reg.histogram("span." + n).total for n in STEP_PHASES)
+    assert 0.0 < phases_s <= step_s
+    counters = reg.snapshot()["counters"]
+    assert counters["session.steps"] == STEPS
+    assert counters["session.frames"] == STEPS * C * T
+    assert counters["session.staged_bytes"] == STEPS * C * T * H * W * 3 * 4
+    report = reg.report()
+    for name in counts:
+        assert f"span.{name}" in report
+
+
+def test_session_frames_count_every_step_and_offer_batch_has_no_phases():
+    """A utilities step counts its frames; neither it nor offer_batch
+    (which is no step) opens the frames path's phase spans."""
+    reg = MetricsRegistry()
+    s = _session(reg)
+    util = np.random.default_rng(2).uniform(0, 1, (C, T)).astype(np.float32)
+    s.step(utilities=util)
+    s.offer_batch([("x", i) for i in range(4)], [0.9, 0.8, 0.7, 0.6],
+                  cams=[0, 0, 1, 2])
+    assert _span_counts(reg) == {"session.step": 1}
+    counters = reg.snapshot()["counters"]
+    assert counters["session.steps"] == 1
+    assert counters["session.frames"] == C * T
+    assert "session.staged_bytes" not in counters
+
+
+def test_session_results_identical_with_and_without_metrics():
+    plain, metered = _session(), _session(MetricsRegistry())
+    a, b = _drive(plain), _drive(metered)
+    for (da, ra, pa), (db, rb, pb) in zip(a, b):
+        np.testing.assert_array_equal(da, db)
+        np.testing.assert_array_equal(ra, rb)
+        assert pa == pb
+    sa, sb = plain.state.as_dict(), metered.state.as_dict()
+    for k in sa:
+        np.testing.assert_array_equal(sa[k], sb[k], err_msg=k)
+    assert plain.stats.__dict__ == metered.stats.__dict__
+    np.testing.assert_array_equal(plain.queue_depths(),
+                                  metered.queue_depths())
+
+
+def test_service_reports_into_the_sessions_registry():
+    """A service over a metered session adopts its registry: the
+    operator's one report carries the session's spans beside the
+    service's own metrics."""
+    reg = MetricsRegistry()
+    session = _session(reg)
+    rng = np.random.default_rng(3)
+    arrivals = []
+    for i in range(2 * T):
+        for c in range(C):
+            arrivals.append(Arrival(
+                t=i / 10.0, cam=c, record=(c, i),
+                frame=rng.integers(0, 256, (H, W, 3), dtype=np.uint8)))
+    service = ServeService(session, MockBackend(seed=0),
+                           clock=VirtualClock(), max_batch=8, max_wait=0.05)
+    assert service.metrics is reg
+    res = service.run(arrivals)
+    assert res.metrics["counters"]["dispatch.fused"] > 0
+    report = service.metrics.report()
+    assert "span.session.step" in report
+    assert "span.session.report_latency" in report
+    assert "ingest.offered" in report
