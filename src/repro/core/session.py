@@ -1301,7 +1301,13 @@ class ShedSession:
     # -- fused ingest --------------------------------------------------------
 
     def _check_frames(self, frames: np.ndarray) -> np.ndarray:
-        frames = np.asarray(frames, np.float32)
+        """Validated float32 frames for the host-scored paths."""
+        return self._validate_frames(np.asarray(frames, np.float32))
+
+    def _validate_frames(self, frames: np.ndarray) -> np.ndarray:
+        """Check a (C, T, H, W, 3) batch (or a single camera's (T, H, W,
+        3)) against the session, sizing a fresh background lane to it;
+        the dtype is left as it came."""
         if frames.ndim == 4:
             frames = frames[None]
         if frames.ndim != 5 or frames.shape[0] != self.num_cameras:
@@ -1407,10 +1413,14 @@ class ShedSession:
         With a session ``metrics`` registry each call is a span
         ``session.step`` and counts ``session.steps`` and its offered
         frames in ``session.frames``. On the ``serve="device"`` frames
-        path its phases are the spans ``session.stage`` (float32 frames
-        on the host), ``session.put`` (the hand-off to the device and
-        the first relayout), ``session.dispatch``, ``session.readback``
-        and ``session.absorb`` (host bookkeeping).
+        path its phases are the spans ``session.stage`` (the frames'
+        checks on the host), ``session.put`` (the hand-off to the device
+        and the first relayout), ``session.dispatch``,
+        ``session.readback`` and ``session.absorb`` (host bookkeeping).
+        That path hands the frames to the device in the camera's dtype
+        (uint8 as it comes) and converts them to float32 there.
+        ``session.staged_bytes`` counts the float32 frames staged for
+        the kernel, whatever dtype arrives.
         """
         with self._span("session.step"):
             res = self._step(frames, utilities, s2_utilities, items, tick,
@@ -1476,19 +1486,18 @@ class ShedSession:
 
     def _serve_frames(self, frames, items, tick, impl, interpret,
                       kw) -> StepResult:
-        """The ``serve="device"`` frames step: float32 frames to the
-        device, then ONE fused serve-step dispatch (see ``step`` for
-        its spans)."""
+        """The ``serve="device"`` frames step: the frames to the device
+        in the camera's dtype, converted to float32 there, then ONE
+        fused serve-step dispatch (see ``step`` for its spans)."""
         span = self._span
         with span("session.stage"):
-            frames = self._check_frames(frames)
+            frames = self._validate_frames(np.asarray(frames))
         if frames.shape[1] == 0:
             raise ValueError("empty frame batch")
         if self.metrics is not None:
-            self.metrics.counter("session.staged_bytes").inc(frames.nbytes)
+            self.metrics.counter("session.staged_bytes").inc(4 * frames.size)
         with span("session.put"):
             flat = _flatten_frames(jnp.asarray(frames))
-            del frames      # the float32 host copy goes once handed off
         with span("session.dispatch"):
             q = self.query
             M_pos, norm, op = self._model_constants()
@@ -2152,8 +2161,11 @@ def open_session(query: Query, num_cameras: int = 1, **kw: Any) -> ShedSession:
     (``session.step`` and its phases, ``session.pop``,
     ``session.report_latency``; their ``span.*`` histograms reach
     ``metrics.report()``) and counts ``session.steps``,
-    ``session.frames`` and ``session.staged_bytes``. A ``ServeService``
-    over the session reports into the same registry. Spans only read
+    ``session.frames`` and ``session.staged_bytes`` (the float32
+    frames a device frames step stages for the kernel; the frames
+    cross in the camera's dtype and are converted on the chip). A
+    ``ServeService`` over the session reports into the same registry.
+    Spans only read
     the clock around calls that block anyway; without a registry
     (the default) none is taken.
     """

@@ -392,7 +392,9 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
     """Fused batched ingest: one pallas_call for a whole camera array.
 
     rgb:   (T, N, 3) float32 RGB in [0, 255] (frames flattened to
-           pixels), or (C, T, N, 3) for a C-camera array
+           pixels), or (C, T, N, 3) for a C-camera array; the
+           camera's uint8 is padded as it is and converted to float32
+           in the planar relayout
     bg0:   (N,) / (C, N) float32 — per-camera background Value-channel
            state (ignored when ``bg_valid=False``: frame 0 then seeds it
            and yields no fg)

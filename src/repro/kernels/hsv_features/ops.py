@@ -80,10 +80,13 @@ def ingest_core(rgb, bg0, gain0, M_pos, norm, *, hue_ranges, bs, bv,
     device programs (e.g. the session's fused serve step) can trace it
     inline and keep everything in ONE dispatch.
 
-    rgb: (T, N, 3) or (C, T, N, 3) float32 (frames flattened to
-    pixels). Returns the kernel tuple (counts, totals, fg_total,
-    utility, bg, gain); ``width > 0`` appends the per-frame foreground
-    bounding box (the cascade's ROI — see ``foreground_bbox``).
+    rgb: (T, N, 3) or (C, T, N, 3) frames flattened to pixels, RGB in
+    [0, 255], in float32 or the camera's dtype: both implementations
+    convert to float32 on the device before any arithmetic (exact for
+    uint8, so both score alike). Returns
+    the kernel tuple (counts, totals, fg_total, utility, bg, gain);
+    ``width > 0`` appends the per-frame foreground bounding box (the
+    cascade's ROI — see ``foreground_bbox``).
     """
     if impl == "pallas":
         return ingest_batch(

@@ -3,7 +3,9 @@
 The TPU compiler is installed even where no chip is attached: these
 tests lower the fused ingest kernel, the one-chip device serve step and
 the four-chip fleet serve step at camera resolution (8 cameras x 8
-frames x 1280x720) and compile them for the chip. Nothing runs, so they
+frames x 1280x720; the serve steps also with the camera's uint8
+frames, the one-chip step at 24 x 8 x 960x540) and compile them for
+the chip. Nothing runs, so they
 say nothing about results or speed; they catch what interpret mode
 cannot — tiling violations, VMEM overruns, programs that do not fit.
 
@@ -64,11 +66,11 @@ def _control_kw(num_total):
                 num_total=num_total, tick_cfg=DEFAULT_TICK_CONFIG)
 
 
-def _state_shapes(num_cameras, sharding_of):
+def _state_shapes(num_cameras, sharding_of, npix=NPIX):
     """SessionState of ShapeDtypeStructs; ``sharding_of(name)`` places
     each leaf."""
     from repro.core.session import SessionState
-    st = SessionState.fresh(num_cameras, NPIX)
+    st = SessionState.fresh(num_cameras, npix)
     return SessionState(**{
         f.name: jax.ShapeDtypeStruct(np.shape(getattr(st, f.name)),
                                      np.asarray(getattr(st, f.name)).dtype,
@@ -107,7 +109,33 @@ def test_device_serve_step_compiles_at_720p(one_chip, query):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-def test_fleet_serve_step_compiles_on_four_chips(topo, query):
+def test_device_serve_step_compiles_with_uint8_frames_at_540p(one_chip,
+                                                               query):
+    """The camera's uint8 frames as the benchmark's 24 cameras x 8
+    frames x 960x540 window hands them over: the first relayout, then
+    the serve step that converts them to float32 on the chip."""
+    from repro.core.session import _flatten_frames, _serve_step_dev
+    cams, frames, h, w = 24, 8, 540, 960
+    nc = query.num_colors
+    S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    flat = _flatten_frames.lower(
+        S((cams, frames, h, w, 3), jnp.uint8)).compile()
+    assert flat.memory_analysis().output_size_in_bytes == (
+        cams * frames * h * w * 3)
+    state = _state_shapes(cams, lambda _: one_chip, npix=h * w)
+    compiled = _serve_step_dev.lower(
+        state, S((cams, frames, h * w, 3), jnp.uint8),
+        S((nc, query.bs * query.bv)), S((nc,)),
+        **_ingest_kw(query), **_control_kw(cams)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.uint8],
+                         ids=["float32", "uint8"])
+def test_fleet_serve_step_compiles_on_four_chips(topo, query, dtype):
     from jax.sharding import Mesh
     from repro.core import fleet
     from repro.core.session import SessionState
@@ -119,7 +147,7 @@ def test_fleet_serve_step_compiles_on_four_chips(topo, query):
         cams, lambda name: NamedSharding(mesh, getattr(specs, name)))
     nc = query.num_colors
     rep = NamedSharding(mesh, P())
-    frames = jax.ShapeDtypeStruct((cams, T, NPIX, 3), jnp.float32,
+    frames = jax.ShapeDtypeStruct((cams, T, NPIX, 3), dtype,
                                   sharding=NamedSharding(mesh, P(axis)))
     compiled = fleet._fleet_serve_step.lower(
         state, frames,
