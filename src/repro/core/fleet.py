@@ -115,6 +115,12 @@ def shard_state(state, mesh: Mesh, axis: AxisName = CAMERA_AXIS):
         for name, s in sh.items()})
 
 
+def frame_sharding(mesh: Mesh, axis: AxisName = CAMERA_AXIS) -> NamedSharding:
+    """Where a ``(C, T, ...)`` frame window goes: each camera's frames on
+    the device that holds its lanes."""
+    return NamedSharding(mesh, P(axis))
+
+
 def gather_state(state):
     """Pull every lane back to host as global NumPy arrays (the
     checkpoint form; mesh-independent)."""
@@ -237,13 +243,20 @@ def _fleet_serve_step(state, frames, M_pos, norm, *, mesh, axis, num_total,
                       min_proc, budget, aggregate, tick_cfg=None):
     """The sharded tentpole program: fused ingest -> control, each
     camera shard one self-contained device program (the ingest kernel's
-    per-camera background/gain lanes are row-local too)."""
+    per-camera background/gain lanes are row-local too). ``frames``:
+    ``(C, T, H, W, 3)`` laid out as ``frame_sharding`` places them, or
+    already flattened to ``(C, T, H*W, 3)``; any dtype (the ingest's
+    first relayout converts to float32)."""
     from repro.core.session import SessionState, _control_core_dev
     from repro.kernels.hsv_features.ops import ingest_core
     st_spec = state_pspecs(SessionState, axis)
     ctrl_spec, agg_spec = _out_pspecs(axis, True)
 
     def local(st, fr, mp, nm):
+        # (c, T, H, W, 3) -> (c, T, H*W, 3) on this shard's own frames
+        # (a no-op for frames that come flattened)
+        with jax.named_scope("shed.stage"):
+            fr = fr.reshape(fr.shape[0], fr.shape[1], -1, 3)
         bg0 = st.bg if bg_valid else jnp.zeros_like(st.bg)
         gain0 = st.gain if bg_valid else jnp.ones_like(st.gain)
         _, _, _, util, bg, gain = ingest_core(
@@ -252,10 +265,11 @@ def _fleet_serve_step(state, frames, M_pos, norm, *, mesh, axis, num_total,
             bg_valid=bg_valid, op=op, impl=impl, interpret=interpret)
         st = dataclasses.replace(st, bg=bg, gain=gain,
                                  bg_valid=jnp.asarray(True))
-        st, out = _control_core_dev(
-            st, util, None, update_cdf=update_cdf, do_tick=do_tick,
-            min_proc=min_proc, budget=budget, num_total=num_total,
-            tick_cfg=tick_cfg)
+        with jax.named_scope("shed.control"):
+            st, out = _control_core_dev(
+                st, util, None, update_cdf=update_cdf, do_tick=do_tick,
+                min_proc=min_proc, budget=budget, num_total=num_total,
+                tick_cfg=tick_cfg)
         agg = (_local_aggregates(st, axis, out["decisions"]) if aggregate
                else _empty_aggregates(True))
         return st, out, agg
@@ -336,10 +350,11 @@ def _fleet_pop_candidates(q_util, q_seq, rows, *, mesh, axis, kk):
             (nu, cams, seqs, slots), num_keys=3)
         return nu_s[:kk], cam_s[:kk], seq_s[:kk], slot_s[:kk]
 
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=(P(axis), P(axis), P(axis), P(axis)),
-        check_vma=False)(q_util, q_seq, rows)
+    with jax.named_scope("shed.pop"):
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
+            out_specs=(P(axis), P(axis), P(axis), P(axis)),
+            check_vma=False)(q_util, q_seq, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis"),
@@ -361,43 +376,55 @@ def _fleet_pop_clear(state, gcam, slot, *, mesh, axis):
         q_seq = st.q_seq.at[ic, isl].set(-1, mode="drop")
         return dataclasses.replace(st, q_util=q_util, q_seq=q_seq)
 
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(st_spec, P(), P()),
-        out_specs=st_spec, check_vma=False)(state, gcam, slot)
+    with jax.named_scope("shed.pop"):
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(st_spec, P(), P()),
+            out_specs=st_spec, check_vma=False)(state, gcam, slot)
 
 
-def pop_topk(state, *, mesh, axis, k, rows=None):
+def pop_topk(state, *, mesh, axis, k, rows=None, metrics=None):
     """Pop the global best ``k`` queued frames from a camera-sharded
     session — the exact frames (and order) ``pop_best`` would produce
     sequentially. Returns ``(new_state, cams, seqs)`` with ``(k,)``
     int32 outputs, -1 padded when the eligible queues drain.
 
-    ``rows``: optional global ``(C,)`` bool lane mask."""
+    ``rows``: optional global ``(C,)`` bool lane mask. ``metrics``: the
+    session's ``MetricsRegistry``; with one, the pop's halves are the
+    spans ``session.pop_gather`` (the per-shard candidates program and
+    their read-back) and ``session.pop_merge`` (the host merge and the
+    clear's dispatch), and ``fleet.pop_candidates`` counts the
+    ``S * kk`` candidates read back."""
+    from repro.core.session import _no_span
+    span = metrics.span if metrics is not None else _no_span
     C, K = state.q_util.shape
     S = mesh_axis_size(mesh, axis)
     k = int(k)
     kk = min(k, (C // S) * K)
     if rows is None:
         rows = jnp.ones((C,), bool)
-    nu, gcam, seq, slot = _fleet_pop_candidates(
-        state.q_util, state.q_seq, rows, mesh=mesh, axis=axis, kk=kk)
-    nu, gcam = np.asarray(nu), np.asarray(gcam)
-    seq, slot = np.asarray(seq), np.asarray(slot)
-    fin = np.flatnonzero(nu < np.inf)
-    # exact global pop order: utility desc (nu asc; ±0 canonicalized on
-    # device), then camera asc, then seq asc — lexsort's IEEE compare
-    # agrees with the device total order on this key set
-    order = fin[np.lexsort((seq[fin], gcam[fin], nu[fin]))]
-    m = min(k, order.size)
-    sel = order[:m]
-    cams_out = np.full((k,), -1, np.int32)
-    seqs_out = np.full((k,), -1, np.int32)
-    cams_out[:m], seqs_out[:m] = gcam[sel], seq[sel]
-    gc = np.full((k,), C, np.int32)       # OOB pad -> dropped scatter
-    sl = np.full((k,), K, np.int32)
-    gc[:m], sl[:m] = gcam[sel], slot[sel]
-    new_state = _fleet_pop_clear(state, jnp.asarray(gc), jnp.asarray(sl),
-                                 mesh=mesh, axis=axis)
+    with span("session.pop_gather"):
+        nu, gcam, seq, slot = _fleet_pop_candidates(
+            state.q_util, state.q_seq, rows, mesh=mesh, axis=axis, kk=kk)
+        nu, gcam = np.asarray(nu), np.asarray(gcam)
+        seq, slot = np.asarray(seq), np.asarray(slot)
+    with span("session.pop_merge"):
+        fin = np.flatnonzero(nu < np.inf)
+        # exact global pop order: utility desc (nu asc; ±0 canonicalized
+        # on device), then camera asc, then seq asc — lexsort's IEEE
+        # compare agrees with the device total order on this key set
+        order = fin[np.lexsort((seq[fin], gcam[fin], nu[fin]))]
+        m = min(k, order.size)
+        sel = order[:m]
+        cams_out = np.full((k,), -1, np.int32)
+        seqs_out = np.full((k,), -1, np.int32)
+        cams_out[:m], seqs_out[:m] = gcam[sel], seq[sel]
+        gc = np.full((k,), C, np.int32)       # OOB pad -> dropped scatter
+        sl = np.full((k,), K, np.int32)
+        gc[:m], sl[:m] = gcam[sel], slot[sel]
+        new_state = _fleet_pop_clear(state, jnp.asarray(gc),
+                                     jnp.asarray(sl), mesh=mesh, axis=axis)
+    if metrics is not None:
+        metrics.counter("fleet.pop_candidates").inc(S * kk)
     return new_state, cams_out, seqs_out
 
 
@@ -444,7 +471,7 @@ def aggregates(state, *, mesh, axis, num_cameras: int) -> Dict[str, float]:
 
 __all__ = [
     "CAMERA_AXIS", "aggregates", "camera_axis", "control_step",
-    "derive_fleet_stats", "fleet_mesh", "gather_state", "mesh_axis_size",
-    "pop_topk", "serve_step", "shard_state", "state_pspecs",
+    "derive_fleet_stats", "fleet_mesh", "frame_sharding", "gather_state",
+    "mesh_axis_size", "pop_topk", "serve_step", "shard_state", "state_pspecs",
     "state_shardings", "tick",
 ]

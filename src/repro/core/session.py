@@ -1066,6 +1066,7 @@ class ShedSession:
         self.mesh = None
         self._cam_axis: Optional[Any] = None
         self._shardings: Optional[Dict[str, Any]] = None
+        self._frame_sharding: Optional[Any] = None
         self.fleet_aggregate = bool(fleet_aggregate)
         self.last_fleet_stats: Optional[Dict[str, float]] = None
         if shard_cameras:
@@ -1077,6 +1078,8 @@ class ShedSession:
             serve = "device"
             self.mesh = mesh if mesh is not None else _fleet.fleet_mesh()
             self._cam_axis = _fleet.camera_axis(self.mesh, self.num_cameras)
+            self._frame_sharding = _fleet.frame_sharding(self.mesh,
+                                                         self._cam_axis)
         if serve is None:
             serve = "device" if jax.default_backend() == "tpu" else "host"
         if serve not in ("host", "device"):
@@ -1415,10 +1418,12 @@ class ShedSession:
         frames in ``session.frames``. On the ``serve="device"`` frames
         path its phases are the spans ``session.stage`` (the frames'
         checks on the host), ``session.put`` (the hand-off to the device
-        and the first relayout), ``session.dispatch``,
+        and, on one device, the first relayout), ``session.dispatch``,
         ``session.readback`` and ``session.absorb`` (host bookkeeping).
         That path hands the frames to the device in the camera's dtype
-        (uint8 as it comes) and converts them to float32 there.
+        (uint8 as it comes) and converts them to float32 there. On a
+        camera mesh each camera's frames go straight to the device that
+        holds its lanes, and the sharded step flattens them there.
         ``session.staged_bytes`` counts the float32 frames staged for
         the kernel, whatever dtype arrives.
         """
@@ -1497,7 +1502,12 @@ class ShedSession:
         if self.metrics is not None:
             self.metrics.counter("session.staged_bytes").inc(4 * frames.size)
         with span("session.put"):
-            flat = _flatten_frames(jnp.asarray(frames))
+            if self.mesh is None:
+                frames = _flatten_frames(jnp.asarray(frames))
+            else:
+                # each camera's frames straight to the device that holds
+                # its lanes; the sharded step flattens them there
+                frames = jax.device_put(frames, self._frame_sharding)
         with span("session.dispatch"):
             q = self.query
             M_pos, norm, op = self._model_constants()
@@ -1516,12 +1526,12 @@ class ShedSession:
             if self.mesh is not None:
                 from repro.core import fleet as _fleet
                 self.state, out, agg = _fleet.serve_step(
-                    self.state, flat, M_pos, norm, mesh=self.mesh,
+                    self.state, frames, M_pos, norm, mesh=self.mesh,
                     axis=self._cam_axis,
                     aggregate=self.fleet_aggregate, **ingest_kw, **kw)
             else:
                 self.state, out = _serve_step_dev(
-                    self.state, flat, M_pos, norm, **ingest_kw, **kw)
+                    self.state, frames, M_pos, norm, **ingest_kw, **kw)
         return self._absorb_control(out, items, tick, agg=agg, span=span)
 
     def _cascade_step(self, frames, utilities, s2_utilities, items, tick,
@@ -1858,7 +1868,10 @@ class ShedSession:
         per frame. ``cams`` restricts the pool to those camera lanes
         (default: the whole array). Returns up to ``k`` payloads; fewer
         when the eligible queues drain first. With a session
-        ``metrics`` registry the call is a span ``session.pop``."""
+        ``metrics`` registry the call is a span ``session.pop``; on a
+        camera mesh its children are ``session.pop_gather`` and
+        ``session.pop_merge``, and ``fleet.pop_candidates`` counts the
+        candidates read back (see ``fleet.pop_topk``)."""
         if k <= 0:
             return []
         with self._span("session.pop"):
@@ -1870,9 +1883,14 @@ class ShedSession:
             if self.serve == "device":
                 if self.mesh is not None:
                     from repro.core import fleet as _fleet
+                    # the registry goes along only when there is one: an
+                    # unmetered pop keeps pop_topk's plain keywords
+                    metered = ({} if self.metrics is None
+                               else {"metrics": self.metrics})
                     self.state, pc, ps = _fleet.pop_topk(
                         st, mesh=self.mesh, axis=self._cam_axis, k=int(k),
-                        rows=None if rows is None else jnp.asarray(rows))
+                        rows=None if rows is None else jnp.asarray(rows),
+                        **metered)
                 elif rows is None:
                     self.state, pc, ps = _pop_topk_dev(st, k=int(k))
                 else:

@@ -8,21 +8,20 @@ Two entry points:
     building block for callers that bring their own background model.
 
 ``ingest_batch``
-    The batched end-to-end ingest pipeline (this repo's hot path). One
-    ``pallas_call`` takes a ``(T, N, 3)`` frame batch — or a whole
-    camera array ``(C, T, N, 3)`` with per-camera ``(bg, gain)`` state
-    lanes — and runs, per pixel tile,
+    The batched end-to-end ingest pipeline (this repo's hot path). It
+    takes a ``(T, N, 3)`` frame batch — or a whole camera array
+    ``(C, T, N, 3)`` with per-camera ``(bg, gain)`` state lanes — and
+    runs one ``pallas_call`` a frame over every camera, per pixel tile,
 
       HBM -> VMEM tile -> RGB->HSV -> EMA background subtraction
           -> per-color hue masks x joint (sat, val) bin one-hot (MXU)
           -> per-frame PF counts + totals + in-kernel utility score
 
-    over a 3D grid ``(camera, frame, pixel-tile)``. TPU grid execution
-    is sequential per core; the background/gain state blocks are indexed
-    by the camera dimension only and the per-frame output blocks by
-    (camera, frame), so within their grid span they stay VMEM-resident
-    and read-modify-write across grid steps is race-free, while each
-    camera gets its own state lane.
+    over a 2D grid ``(camera, pixel-tile)``. TPU grid execution is
+    sequential per core; the background and output blocks are indexed
+    by the camera dimension only, so within their grid span they stay
+    VMEM-resident and read-modify-write across grid steps is race-free,
+    while each camera gets its own state lane.
 
     Background-model state is *explicit kernel state carried across
     batches*: the caller passes ``(bg, gain)`` in and receives the
@@ -30,19 +29,21 @@ Two entry points:
     over a video stream behave exactly like one long call. The model is
     a per-pixel EMA on the Value channel with global-gain compensation:
     ``gain`` is the mean-ratio illumination estimate of the *previous*
-    frame (one-frame lag makes it computable in a single pass; the
-    paper's drift is slow, so the lag is negligible), the frame is
-    divided by it before differencing, and the background absorbs the
-    compensated frame with learning rate ``alpha``.
+    frame (the paper's drift is slow, so the lag is negligible), the
+    frame is divided by it before differencing, and the background
+    absorbs the compensated frame with learning rate ``alpha``. The
+    wrapper sums each frame's values and background for that estimate
+    with XLA between the kernel calls, in the order a plain jnp ingest
+    sums them.
 
 Tile layout (Mosaic's (8, 128) rule): the wrappers hand the kernels
 *planar* pixels, each ``BLOCK``-pixel tile a ``(SUB, LANES)`` slab per
 channel, so every elementwise stage runs on full vregs. Per-frame
-results leave through (camera, frame)-indexed blocks whose last two dims
+results leave through camera-indexed blocks whose last two dims
 are whole arrays — counts ``(NCP, bins)`` and an ``(NCP, 128)`` aux slab
 (lane 0 totals, 1 foreground total, 2 utility) — and the wrappers slice
-them back to the public shapes. Scalar state (gain, the gain sums) lives
-in broadcast ``(SUB, 128)`` vector slabs; nothing stores scalars to
+them back to the public shapes. The gain comes in a broadcast
+``(SUB, 128)`` vector slab; nothing stores scalars to
 VMEM and no store has a dynamic lane offset.
 
 The histogram is, for each of a tile's ``SUB`` pixel rows, one
@@ -238,54 +239,31 @@ def hsv_hist(rgb, fg, hue_ranges, bs: int = B_S, bv: int = B_V,
 # Batched end-to-end ingest kernel
 # ---------------------------------------------------------------------------
 
-def _ingest_kernel(rgb_ref, bg0_ref, gain0_ref, m_ref, norm_ref,
-                   counts_ref, aux_ref, bg_ref, gain_ref, *rest,
+def _ingest_kernel(rgb_ref, bg0_ref, gain_ref, m_ref, norm_ref,
+                   counts_ref, aux_ref, bg_ref, *rest,
                    hue_ranges, ncp, bs, bv, alpha, threshold, npix,
                    use_fg, bg_valid, op, num_tiles, width=0):
-    # grid (camera, frame, tile): bg/gain blocks are indexed by camera
-    # only, so each camera's span reuses its own lane; counts/aux/bbox
-    # blocks by (camera, frame), so they accumulate over the tile loop
+    # one frame over the grid (camera, tile): the bg blocks are indexed
+    # by camera only, so each camera's span reuses its own lane, and the
+    # counts/aux/bbox blocks accumulate over the tile loop
     bbox_ref = rest[0] if width else None
-    sums_ref = rest[-1]         # (SUB, AUX) scratch: lane 0 sum v, 1 sum bg
-    t = pl.program_id(1)        # frame (background recurrence is sequential)
-    j = pl.program_id(2)        # pixel tile (inner)
+    j = pl.program_id(1)        # pixel tile (inner)
     nc = len(hue_ranges)
     lane = _lanes()
-
-    @pl.when((t == 0) & (j == 0))
-    def _init_state():
-        gain_ref[...] = gain0_ref[...]
-        sums_ref[...] = jnp.zeros_like(sums_ref)
 
     h, s, v = _rgb_to_hsv_block(rgb_ref[0], rgb_ref[1], rgb_ref[2])
     pidx = _pixel_index(j)
     validf = (pidx < npix).astype(jnp.float32)
 
     # --- EMA background subtraction (state carried across frames/batches)
-    if bg_valid:
-        base = jnp.where(t == 0, bg0_ref[j], bg_ref[j])
-    else:
-        # no prior state: frame 0 seeds the background with itself, so its
-        # |comp - base| is 0 -> all-background, matching the host model
-        base = jnp.where(t == 0, v, bg_ref[j])
+    # no prior state: the frame seeds the background with itself, so its
+    # |comp - base| is 0 -> all-background, matching the host model
+    base = bg0_ref[j] if bg_valid else v
     gain = jnp.clip(gain_ref[:, 0:1], GAIN_MIN, GAIN_MAX)
     comp = v / gain
     fgf = ((jnp.abs(comp - base) > threshold).astype(jnp.float32)
            if use_fg else jnp.ones_like(v)) * validf
     bg_ref[j] = (1.0 - alpha) * base + alpha * comp
-
-    # one-frame-lagged global gain estimate: mean(v) / mean(bg)
-    sums_ref[...] += jnp.where(
-        lane == 0, _whole(jnp.sum, v * validf),
-        jnp.where(lane == 1, _whole(jnp.sum, base * validf), 0.0))
-
-    @pl.when(j == num_tiles - 1)
-    def _advance_gain():
-        sums = sums_ref[...]
-        ratio = sums[:, 0:1] / jnp.maximum(sums[:, 1:2], 1e-6)
-        gain_ref[...] = jnp.broadcast_to(
-            jnp.clip(ratio, GAIN_MIN, GAIN_MAX), gain_ref.shape)
-        sums_ref[...] = jnp.zeros_like(sums_ref)
 
     counts_t, totals_t = _tile_hist(h, s, v, fgf, hue_ranges=hue_ranges,
                                     ncp=ncp, bs=bs, bv=bv)
@@ -364,8 +342,8 @@ def _ingest_kernel(rgb_ref, bg0_ref, gain0_ref, m_ref, norm_ref,
 
 # (SUB, LANES) values the kernel body names, counted as if all were live
 # at once: HSV conversion 12 (r, g, b and 9 intermediates), pixel index
-# and validity 4, background/foreground update 6, gain sums 2, joint bin
-# 4, bbox row/column math 12, and one mask per colour row (up to SUB)
+# and validity 4, background/foreground update 6, joint bin 4, bbox
+# row/column math 12, one mask per colour row (up to SUB), and 2 spare
 TILE_TEMPS = 48
 
 
@@ -389,7 +367,8 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
                  threshold: float = 18.0, use_fg: bool = True,
                  bg_valid: bool = True, op: str = "or",
                  interpret: bool | None = None, width: int = 0):
-    """Fused batched ingest: one pallas_call for a whole camera array.
+    """Fused batched ingest: one pallas_call a frame for a whole camera
+    array, the gain between frames from XLA row sums.
 
     rgb:   (T, N, 3) float32 RGB in [0, 255] (frames flattened to
            pixels), or (C, T, N, 3) for a C-camera array; the
@@ -428,6 +407,7 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
     # device scopes a profile reads: shed.stage is the planar relayout
     # of the frames, shed.score the kernel and its small operands
     with jax.named_scope("shed.score"):
+        bg_in_plain = bg0
         bg0 = _tiles(jnp.asarray(bg0, jnp.float32).reshape(C, n), npad)
         # a scalar gain broadcasts to every camera lane, as the oracle's
         gain0 = jnp.broadcast_to(
@@ -437,40 +417,38 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
             jnp.pad(norm.astype(jnp.float32), (0, ncp - nc),
                     constant_values=1.0)[:, None], (ncp, AUX))
 
-    frame_block = lambda c, t, j: (c, t, 0, 0)    # noqa: E731
-    lane_block = lambda c, t, j: (c, 0, 0, 0)     # noqa: E731
-    cam_block = lambda c, t, j: (c, 0, 0)         # noqa: E731
-    const_block = lambda c, t, j: (0, 0)          # noqa: E731
+    cam_block = lambda c, j: (c, 0, 0)            # noqa: E731
+    lane_block = lambda c, j: (c, 0, 0, 0)        # noqa: E731
+    const_block = lambda c, j: (0, 0)             # noqa: E731
     out_specs = [
-        pl.BlockSpec((None, None, ncp, nb), frame_block),
-        pl.BlockSpec((None, None, ncp, AUX), frame_block),
+        pl.BlockSpec((None, ncp, nb), cam_block),
+        pl.BlockSpec((None, ncp, AUX), cam_block),
         pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
-        pl.BlockSpec((None, SUB, AUX), cam_block),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((C, T, ncp, nb), jnp.float32),
-        jax.ShapeDtypeStruct((C, T, ncp, AUX), jnp.float32),
+        jax.ShapeDtypeStruct((C, ncp, nb), jnp.float32),
+        jax.ShapeDtypeStruct((C, ncp, AUX), jnp.float32),
         jax.ShapeDtypeStruct((C, num_tiles, SUB, LANES), jnp.float32),
-        jax.ShapeDtypeStruct((C, SUB, AUX), jnp.float32),
     ]
     if width:
-        out_specs.append(pl.BlockSpec((None, None, SUB, AUX), frame_block))
-        out_shape.append(jax.ShapeDtypeStruct((C, T, SUB, AUX),
-                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((None, SUB, AUX), cam_block))
+        out_shape.append(jax.ShapeDtypeStruct((C, SUB, AUX), jnp.float32))
 
     with jax.named_scope("shed.stage"):
         planes = _planar(rgb, npad)
-    with jax.named_scope("shed.score"):
-        results = pl.pallas_call(
+
+    def frame(t, bg_in, gain_in, valid):
+        """The kernel over frame ``t`` of every camera."""
+        return pl.pallas_call(
             functools.partial(
                 _ingest_kernel, hue_ranges=hue_ranges, ncp=ncp, bs=bs, bv=bv,
                 alpha=alpha, threshold=threshold, npix=n, use_fg=use_fg,
-                bg_valid=bg_valid, op=op, num_tiles=num_tiles,
+                bg_valid=valid, op=op, num_tiles=num_tiles,
                 width=int(width)),
-            grid=(C, T, num_tiles),
+            grid=(C, num_tiles),
             in_specs=[
                 pl.BlockSpec((None, None, 3, None, SUB, LANES),
-                             lambda c, t, j: (c, t, 0, j, 0, 0)),
+                             lambda c, j: (c, t, 0, j, 0, 0)),
                 pl.BlockSpec((None, num_tiles, SUB, LANES), lane_block),
                 pl.BlockSpec((None, SUB, AUX), cam_block),
                 pl.BlockSpec((ncp, nb), const_block),
@@ -478,18 +456,44 @@ def ingest_batch(rgb, bg0, gain0, M_pos, norm, hue_ranges,
             ],
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((SUB, AUX), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=max(vmem, VMEM_FLOOR)),
             interpret=interpret,
             name="ingest_batch",
-        )(planes, bg0, gain0, M_pos, norm)
-        counts, aux, bg, gain = results[:4]
+        )(planes, bg_in, gain_in, M_pos, norm)
+
+    # one kernel call a frame. Between calls the next frame's gain is
+    # sum(v) / sum(background), both summed by XLA over (C, N) rows as
+    # a plain jnp ingest sums them, on a copy of the background that
+    # XLA advances by the kernel's own update: the kernel's estimate,
+    # summed tile by tile, differs in the last bits, and at 1080p a
+    # pixel on the foreground threshold then flips
+    with jax.named_scope("shed.score"):
+        bg, gain, parts = bg0, gain0, []
+        plain = jnp.asarray(bg_in_plain, jnp.float32).reshape(C, n)
+        for t in range(T):
+            results = frame(t, bg, gain, bg_valid or t > 0)
+            parts.append(results)
+            x = rgb[:, t].astype(jnp.float32)
+            v = jnp.maximum(jnp.maximum(x[..., 0], x[..., 1]), x[..., 2])
+            if t == 0 and not bg_valid:
+                plain = v
+            g_now = jnp.clip(gain[:, 0, 0], GAIN_MIN, GAIN_MAX)[:, None]
+            g = jnp.clip(v.sum(axis=1) / jnp.maximum(plain.sum(axis=1), 1e-6),
+                         GAIN_MIN, GAIN_MAX)
+            plain = (1.0 - alpha) * plain + alpha * (v / g_now)
+            bg = results[2]
+            gain = jnp.broadcast_to(g[:, None, None], (C, SUB, AUX))
+        counts, aux = (jnp.stack([r[i] for r in parts], axis=1)
+                       for i in (0, 1))
+        # the state carries XLA's copy of the background, so that the
+        # next batch's first sum starts from the plain ingest's values
         out = [counts[:, :, :nc], aux[:, :, :nc, 0], aux[:, :, 0, 1],
-               aux[:, :, 0, 2], bg.reshape(C, npad)[:, :n], gain[:, 0, 0]]
+               aux[:, :, 0, 2], plain, gain[:, 0, 0]]
         if width:
-            out.append(results[4][:, :, 0, :4].astype(jnp.int32))
+            out.append(jnp.stack([r[3] for r in parts], axis=1)
+                       [:, :, 0, :4].astype(jnp.int32))
     if has_cams:
         return tuple(out)
     return tuple(o[0] for o in out)

@@ -287,3 +287,109 @@ np.testing.assert_allclose(standalone["proc_q_mean"],
 print("AGG-OK")
 """)
     assert "AGG-OK" in out
+
+
+# ---------------------------------------------------------------------------
+# Sharded staging of the frames window (4 fake devices, one subprocess for
+# every case below)
+# ---------------------------------------------------------------------------
+
+STAGING_CASES = [(dtype, bg) for dtype in ("uint8", "float32")
+                 for bg in ("fresh", "carried")]
+
+
+@pytest.fixture(scope="module")
+def staged_runs():
+    """Step a fleet session over 4 devices and a one-device session
+    through the same windows, once for each staging case; the child
+    prints one line per case that passed."""
+    return run_py(r"""
+import numpy as np, jax
+from repro.core import Query, RED, fleet, open_session, train_utility_model
+from repro.kernels.hsv_features.ops import IngestState
+
+assert len(jax.devices()) == 4
+C, T, H, W, STEPS = 8, 4, 24, 40, 3
+rng = np.random.default_rng(0)
+pfs = rng.random((40, 1, 8, 8)).astype(np.float32)
+model = train_utility_model(pfs, rng.random(40) < 0.5, [RED])
+hist = rng.uniform(0, 1, 64).astype(np.float32)
+wins = [rng.integers(0, 256, (C, T, H, W, 3), dtype=np.uint8)
+        for _ in range(STEPS)]
+carried = IngestState(
+    bg=rng.uniform(0, 255, (C, H * W)).astype(np.float32),
+    gain=rng.uniform(0.8, 1.2, C).astype(np.float32))
+
+staged = []
+serve_step = fleet.serve_step
+
+def spy(state, frames, *a, **k):
+    # no array holding every camera's frames may live on one device
+    for x in jax.live_arrays():
+        if (x.shape[:2] == (C, T) and x.ndim >= 4
+                and len(x.sharding.device_set) == 1):
+            raise AssertionError(f"whole window on one device: {x.shape}")
+    staged.append(frames)
+    return serve_step(state, frames, *a, **k)
+
+fleet.serve_step = spy
+
+def session(bg, **kw):
+    s = open_session(Query.single("red", latency_bound=1.0, fps=10.0),
+                     num_cameras=C, model=model, train_utilities=hist,
+                     queue_size=3, cdf_window=64, impl="jnp",
+                     frame_shape=(H, W) if bg == "carried" else None, **kw)
+    if bg == "carried":
+        s.set_ingest_state(carried)
+    return s
+
+def same_state(a, b, where):
+    for name, x in a.state.as_dict().items():
+        assert np.array_equal(x, b.state.as_dict()[name]), (where, name)
+
+for dtype in ("uint8", "float32"):
+    for bg in ("fresh", "carried"):
+        mesh = fleet.fleet_mesh(4)
+        fl, one = session(bg, mesh=mesh), session(bg, serve="device")
+        want = fleet.frame_sharding(mesh)
+        staged.clear()
+        shed = 0
+        for s, win in enumerate(wins):
+            win = win.astype(dtype)
+            for sess in (fl, one):
+                sess.report_backend_latency(2.0 / (C * 10.0))
+            r1 = fl.step(frames=win, tick=True)
+            r2 = one.step(frames=win, tick=True)
+            assert np.array_equal(r1.decisions, r2.decisions), s
+            assert np.array_equal(r1.target_drop_rate, r2.target_drop_rate)
+            shed += int((r1.decisions != 0).sum())
+            same_state(fl, one, f"step {s}")
+            x = staged[-1]
+            assert x.dtype == win.dtype and x.shape == win.shape
+            assert x.sharding.is_equivalent_to(want, x.ndim), x.sharding
+            devs = []
+            for sh in x.addressable_shards:
+                i = list(mesh.devices.flat).index(sh.device)
+                assert sh.index[0] == slice(2 * i, 2 * i + 2), sh.index
+                assert np.array_equal(np.asarray(sh.data),
+                                      win[2 * i:2 * i + 2])
+                devs.append(i)
+            assert sorted(devs) == [0, 1, 2, 3]
+            p1, p2 = fl.next_frames(C), one.next_frames(C)
+            assert p1 == p2 and p1, s
+            same_state(fl, one, f"pop {s}")
+        assert shed > 0 and len(staged) == STEPS
+        print("STAGED-OK", dtype, bg)
+""", ndev=4)
+
+
+@pytest.mark.parametrize("dtype,background", STAGING_CASES,
+                         ids=[f"{d}-{b}" for d, b in STAGING_CASES])
+def test_fleet_stages_each_camera_on_its_own_device(staged_runs, dtype,
+                                                    background):
+    """A fleet ``step(frames=...)`` puts each camera's frames on the
+    device that holds its lanes, in the camera's dtype, with no window
+    gathered on one device; decisions, pops and the whole state after
+    every step equal the one-device session's, bit for bit, from a
+    fresh and from a carried background."""
+    assert f"STAGED-OK {dtype} {background}" in staged_runs

@@ -1,5 +1,6 @@
 """Fused batched ingest kernel vs. jnp oracle, plus the state-carry and
 memory-lean-oracle contracts (ISSUE 3 acceptance)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -42,6 +43,37 @@ def test_ingest_kernel_matches_oracle(T, n, rng):
                            "gain"), k, r):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-5, err_msg=name)
+
+
+def test_ingest_kernel_gain_is_the_jnp_row_sum_ratio(rng):
+    """Each frame's gain is sum(v) / sum(background) summed as a plain
+    jnp row sum, bit for bit, over frames with a carried background:
+    frames of many tiles, whose sums' last bits move with the order of
+    the additions."""
+    C, T, n = 2, 3, 24 * BLOCK
+    rgb = jnp.asarray(rng.integers(0, 256, (C, T, n, 3)), jnp.uint8)
+    bg0 = jnp.asarray(rng.uniform(0, 255, (C, n)) * 2.0 ** rng.integers(
+        -3, 2, (C, n)), jnp.float32)
+    M = jnp.zeros((1, 64), jnp.float32)
+    norm = jnp.ones((1,), jnp.float32)
+    *_, bg, gain = ingest_batch(rgb, bg0, 1.0, M, norm,
+                                (tuple(RED.hue_ranges),), interpret=True)
+
+    @jax.jit
+    def plain(rgb, bg):
+        g = jnp.ones((C,), jnp.float32)
+        for t in range(T):
+            v = jnp.max(rgb[:, t].astype(jnp.float32), axis=-1)
+            comp = v / g[:, None]
+            g = jnp.clip(v.sum(axis=1) / jnp.maximum(bg.sum(axis=1), 1e-6),
+                         0.25, 4.0)
+            bg = 0.95 * bg + 0.05 * comp
+        return bg, g
+
+    want_bg, want_gain = plain(rgb, bg0)
+    assert np.all((want_gain > 0.25) & (want_gain < 4.0))
+    np.testing.assert_array_equal(np.asarray(gain), np.asarray(want_gain))
+    np.testing.assert_array_equal(np.asarray(bg), np.asarray(want_bg))
 
 
 @pytest.mark.parametrize("bg_valid", [False, True])

@@ -167,3 +167,139 @@ def test_service_reports_into_the_sessions_registry():
     assert "span.session.step" in report
     assert "span.session.report_latency" in report
     assert "ingest.offered" in report
+
+
+# ---------------------------------------------------------------------------
+# the camera-sharded fleet: the pop's spans and counter, the device scopes
+# ---------------------------------------------------------------------------
+
+FLEET_POP_SPANS = ["session.pop_gather", "session.pop_merge"]
+
+
+def _fleet_session(metrics=None, **kw):
+    from repro.core import fleet
+    return _session(metrics, mesh=fleet.fleet_mesh(1), impl="jnp", **kw)
+
+
+def test_fleet_pop_records_its_halves_and_counts_candidates():
+    """One camera shard here: each pop reads back ``S * kk`` candidates,
+    ``kk = min(k, cameras_per_shard * queue_capacity)``."""
+    reg = MetricsRegistry()
+    s = _fleet_session(reg)
+    runs = _drive(s)
+    counts = _span_counts(reg)
+    assert {n: counts[n] for n in FLEET_POP_SPANS} == {
+        n: STEPS for n in FLEET_POP_SPANS}
+    pop_s = reg.histogram("span.session.pop").total
+    halves_s = sum(reg.histogram("span." + n).total for n in FLEET_POP_SPANS)
+    assert 0.0 < halves_s <= pop_s
+    kk = min(C, C * s.queue_capacity)
+    assert reg.snapshot()["counters"]["fleet.pop_candidates"] == STEPS * kk
+    assert sum(len(p) for _, _, p in runs) > 0
+
+
+def test_fleet_pop_counts_every_shards_candidates_on_four_devices():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    repo = Path(__file__).resolve().parent.parent
+    code = r"""
+import numpy as np, jax
+from repro.core import fleet
+from repro.serve.metrics import MetricsRegistry
+import test_spans as t
+assert len(jax.devices()) == 4
+reg = MetricsRegistry()
+s = t.open_session(t.Query.single("red", latency_bound=1.0, fps=10.0),
+                   num_cameras=8, train_utilities=np.linspace(0, 1, 64),
+                   queue_size=3, queue_capacity=16, cdf_window=64,
+                   mesh=fleet.fleet_mesh(4), metrics=reg)
+u = np.random.default_rng(0).uniform(0, 1, (8, 4)).astype(np.float32)
+s.step(utilities=u)
+assert len(s.next_frames(5)) == 5
+assert len(s.next_frames(40)) > 0
+snap = reg.snapshot()
+# S * kk: 4 * min(5, 2 * 16), then 4 * min(40, 2 * 16)
+assert snap["counters"]["fleet.pop_candidates"] == 4 * 5 + 4 * 32, snap
+assert snap["histograms"]["span.session.pop_gather"]["count"] == 2
+assert snap["histograms"]["span.session.pop_merge"]["count"] == 2
+print("FLEET-POP-OK")
+"""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(repo / "src"),
+                                           str(repo / "tests")]))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=300, env=env, cwd=repo)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "FLEET-POP-OK" in out.stdout
+
+
+def test_unmetered_fleet_pop_records_nothing_and_pops_the_same(monkeypatch):
+    """Without a registry the session calls ``pop_topk`` with its plain
+    keywords (no span, no counter), and a metered fleet session pops,
+    decides and ends in the same state as an unmetered one."""
+    from repro.core import fleet
+    calls = []
+    pop_topk = fleet.pop_topk
+
+    def plain(state, *, mesh, axis, k, rows=None):
+        calls.append(k)
+        return pop_topk(state, mesh=mesh, axis=axis, k=k, rows=rows)
+
+    monkeypatch.setattr(fleet, "pop_topk", plain)
+    plain_run = _drive(_fleet_session())
+    assert calls == [C] * STEPS
+    monkeypatch.setattr(fleet, "pop_topk", pop_topk)
+    reg = MetricsRegistry()
+    metered, unmetered = _fleet_session(reg), _fleet_session()
+    a, b = _drive(metered), _drive(unmetered)
+    for (da, ra, pa), (db, rb, pb), (dc, _, pc) in zip(a, b, plain_run):
+        np.testing.assert_array_equal(da, db)
+        np.testing.assert_array_equal(ra, rb)
+        np.testing.assert_array_equal(db, dc)
+        assert pa == pb == pc
+    sa, sb = metered.state.as_dict(), unmetered.state.as_dict()
+    for k in sa:
+        np.testing.assert_array_equal(sa[k], sb[k], err_msg=k)
+    assert unmetered.metrics is None
+
+
+def _op_names(compiled) -> set:
+    import re
+    return set(re.findall(r'op_name="([^"]*)"', compiled.as_text()))
+
+
+def test_fleet_programs_name_their_device_scopes():
+    """The sharded step's control under ``shed.control`` (its flatten
+    under ``shed.stage``), both pop programs under ``shed.pop``."""
+    import jax.numpy as jnp
+    from repro.core import fleet
+    s = _fleet_session()
+    captured = {}
+    serve = fleet._fleet_serve_step
+
+    def spy(*a, **k):
+        captured.update(args=a, kw=k)
+        return serve(*a, **k)
+
+    fleet._fleet_serve_step = spy
+    try:
+        s.step(frames=np.zeros((C, T, H, W, 3), np.uint8), tick=True)
+    finally:
+        fleet._fleet_serve_step = serve
+    lowered = serve.lower(*captured["args"], **captured["kw"])
+    assert any("/shed.control/" in n for n in _op_names(lowered.compile()))
+    # the CPU compiler folds the flatten into a bitcast: read the scope
+    # off the traced program
+    assert "shed.stage/reshape" in lowered.as_text(debug_info=True)
+    st, mesh, axis = s.state, s.mesh, s._cam_axis
+    cand = _op_names(fleet._fleet_pop_candidates.lower(
+        st.q_util, st.q_seq, jnp.ones((C,), bool), mesh=mesh, axis=axis,
+        kk=C).compile())
+    clear = _op_names(fleet._fleet_pop_clear.lower(
+        st, jnp.zeros((C,), jnp.int32), jnp.zeros((C,), jnp.int32),
+        mesh=mesh, axis=axis).compile())
+    for names in (cand, clear):
+        assert any("/shed.pop/" in n for n in names), sorted(names)[:5]

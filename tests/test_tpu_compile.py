@@ -4,8 +4,8 @@ The TPU compiler is installed even where no chip is attached: these
 tests lower the fused ingest kernel, the one-chip device serve step and
 the four-chip fleet serve step at camera resolution (8 cameras x 8
 frames x 1280x720; the serve steps also with the camera's uint8
-frames, the one-chip step at 24 x 8 x 960x540) and compile them for
-the chip. Nothing runs, so they
+frames, the one-chip step at 24 x 8 x 960x540, the fleet step at 40 x
+8 x 1920x1080) and compile them for the chip. Nothing runs, so they
 say nothing about results or speed; they catch what interpret mode
 cannot — tiling violations, VMEM overruns, programs that do not fit.
 
@@ -133,22 +133,35 @@ def test_device_serve_step_compiles_with_uint8_frames_at_540p(one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.uint8],
-                         ids=["float32", "uint8"])
-def test_fleet_serve_step_compiles_on_four_chips(topo, query, dtype):
+# (cameras, frames per step, height, width, dtype, as the window comes):
+# 32 x 8 x 720p pre-flattened, and the cityflow40_1080p deployment's window,
+# 40 cameras x 8 frames x 1920x1080 uint8, as the session stages it
+FLEET_WINDOWS = {
+    "float32": (4 * C, T, H, W, jnp.float32, False),
+    "uint8": (4 * C, T, H, W, jnp.uint8, False),
+    "cityflow40_1080p": (40, 8, 1080, 1920, jnp.uint8, True),
+}
+
+
+@pytest.mark.parametrize("window", list(FLEET_WINDOWS.values()),
+                         ids=list(FLEET_WINDOWS))
+def test_fleet_serve_step_compiles_on_four_chips(topo, query, window):
     from jax.sharding import Mesh
     from repro.core import fleet
     from repro.core.session import SessionState
+    cams, frames_per_step, h, w, dtype, whole_frames = window
     mesh = Mesh(np.array(topo.devices[:4]), (fleet.CAMERA_AXIS,))
     axis = fleet.CAMERA_AXIS
     specs = fleet.state_pspecs(SessionState, axis)
-    cams = 4 * C
     state = _state_shapes(
-        cams, lambda name: NamedSharding(mesh, getattr(specs, name)))
+        cams, lambda name: NamedSharding(mesh, getattr(specs, name)),
+        npix=h * w)
     nc = query.num_colors
     rep = NamedSharding(mesh, P())
-    frames = jax.ShapeDtypeStruct((cams, T, NPIX, 3), dtype,
-                                  sharding=NamedSharding(mesh, P(axis)))
+    shape = ((cams, frames_per_step, h, w, 3) if whole_frames
+             else (cams, frames_per_step, h * w, 3))
+    frames = jax.ShapeDtypeStruct(shape, dtype,
+                                  sharding=fleet.frame_sharding(mesh, axis))
     compiled = fleet._fleet_serve_step.lower(
         state, frames,
         jax.ShapeDtypeStruct((nc, query.bs * query.bv), jnp.float32,
@@ -158,5 +171,21 @@ def test_fleet_serve_step_compiles_on_four_chips(topo, query, dtype):
         **_ingest_kw(query), **_control_kw(cams)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    # the psum aggregate tree is the step's only collective
+    # the psum aggregate tree is the step's only collective: no chip
+    # gathers another's frames
     assert "all-reduce" in text
+    assert "all-gather" not in text and "all-to-all" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_ingest_vmem_estimate_at_1080p_fits_the_cap():
+    """1920x1080 is the first frame whose resident background lane asks
+    for more than ``VMEM_FLOOR``; it stays under ``VMEM_CAP``."""
+    from repro.kernels.hsv_features.kernel import (
+        BLOCK, SUB, VMEM_CAP, VMEM_FLOOR, _ingest_vmem_bytes, _round_up)
+    from repro.core import Query
+    q = Query.any_of("red", "yellow")
+    vmem = _ingest_vmem_bytes(_round_up(1920 * 1080, BLOCK),
+                              _round_up(q.num_colors, SUB), q.bs * q.bv)
+    assert VMEM_FLOOR < vmem < VMEM_CAP
