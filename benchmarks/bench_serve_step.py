@@ -68,11 +68,11 @@ class HostLoopShedder:
     # -- metric feeds (identical EWMA math to ShedSession) -------------------
 
     def report_backend_latency(self, lat: float) -> None:
-        x = max(float(lat), self.min_proc)
-        a = np.where(x > self.proc_q, self.ewma_alpha_up, self.ewma_alpha)
+        x = np.float32(max(float(lat), self.min_proc))
+        a = np.where(x > self.proc_q, np.float32(self.ewma_alpha_up),
+                     np.float32(self.ewma_alpha))
         self.proc_q = np.where(self.proc_seen,
-                               self.proc_q + a * (x - self.proc_q),
-                               x).astype(np.float32)
+                               self.proc_q + a * (x - self.proc_q), x)
         self.proc_seen = np.ones_like(self.proc_seen)
 
     def seed_cdf(self, us: np.ndarray) -> None:

@@ -222,8 +222,10 @@ class SessionState:
         control tick is O(B) instead of a (C, W) sort (``TickConfig``).
       * ``threshold (C,)`` — current admission thresholds (Eq. 17).
       * ``proc_q (C,)`` (+ ``proc_seen``) — asymmetric-EWMA backend
-        latency estimates; ``fps_obs (C,)`` (+ ``fps_seen``) — observed
-        per-camera ingress rates (Eq. 18–19 inputs).
+        latency estimates, folded on the host and written here by the
+        session (no program writes them); ``fps_obs (C,)`` (+
+        ``fps_seen``) — observed per-camera ingress rates (Eq. 18–19
+        inputs).
       * ``queue_cap (C,)`` — dynamic queue sizes (Eq. 20).
       * ``q_util`` / ``q_seq (C, K)`` + ``q_next_seq (C,)`` — the
         utility-ordered queues as array lanes (``repro.core.shed_queue``
@@ -1117,6 +1119,11 @@ class ShedSession:
                 self.mesh, self.state, self._cam_axis)
             self.state = _fleet.shard_state(self.state, self.mesh,
                                             self._cam_axis)
+        # host copy of the latency lanes, equal to the fresh state's
+        # zeros (see report_backend_latency and _put_latency_lanes)
+        self._proc_q = np.zeros((self.num_cameras,), np.float32)
+        self._proc_seen = np.zeros((self.num_cameras,), bool)
+        self._proc_dirty = False
         self.queue_capacity = int(self.state.q_util.shape[1])
         self._payloads: List[Dict[int, Any]] = [
             {} for _ in range(self.num_cameras)]
@@ -1218,7 +1225,6 @@ class ShedSession:
                 ("gain", 1.0), ("cdf_len", 0), ("cdf_pos", 0),
                 ("cdf_counts", np.zeros((B,), np.int32)),
                 ("threshold", np.float32(-np.inf if active else np.inf)),
-                ("proc_q", 0.0), ("proc_seen", False),
                 ("fps_obs", float(q.fps)), ("fps_seen", False),
                 ("queue_cap", self._queue_size), ("q_next_seq", 0),
                 ("q_util", np.full((K,), -np.inf, np.float32)),
@@ -1230,6 +1236,10 @@ class ShedSession:
                 ("s2_counts", np.zeros((B,), np.int32)),
                 ("active", bool(active))):
             self._write_lane(name, lane, v)
+        fresh = np.arange(self.num_cameras) == lane
+        self._set_latency_lanes(
+            np.where(fresh, np.float32(0.0), self._proc_q),
+            self._proc_seen & ~fresh)
         self._depths[lane] = 0
         if self.state.bg.shape[1]:
             self._write_lane(
@@ -1445,6 +1455,7 @@ class ShedSession:
         if s2_utilities is not None and frames is not None:
             raise ValueError("s2_utilities= goes with utilities=, not "
                              "frames= (frames are scored by the cascade)")
+        self._put_latency_lanes()
         if self.cascade is not None and (frames is not None
                                          or s2_utilities is not None):
             return self._cascade_step(frames, utilities, s2_utilities,
@@ -1699,6 +1710,7 @@ class ShedSession:
                              "session (open_session(..., shard_cameras"
                              "=True))")
         from repro.core import fleet as _fleet
+        self._put_latency_lanes()
         return _fleet.aggregates(self.state, mesh=self.mesh,
                                  axis=self._cam_axis,
                                  num_cameras=self.num_cameras)
@@ -1818,6 +1830,7 @@ class ShedSession:
                 batch_items[c][t] = items[i]
                 slot_of[(c, t)] = i
         util = sq.flush_subnormal(util)
+        self._put_latency_lanes()
         kw = dict(update_cdf=self.update_cdf_online, do_tick=False,
                   min_proc=self.min_proc, budget=self._budget,
                   num_total=self._num_active, tick_cfg=self._tick_cfg)
@@ -1939,8 +1952,8 @@ class ShedSession:
         lane, or (default) the worst lane — the conservative shared
         value every lane carries under scalar reporting."""
         if cam is not None:
-            return float(np.asarray(self.state.proc_q)[int(cam)])
-        return float(np.asarray(self.state.proc_q).max(initial=0.0))
+            return float(self._proc_q[int(cam)])
+        return float(self._proc_q.max(initial=0.0))
 
     def report_backend_latency(self, proc_latency: float,
                                cam: Optional[int] = None) -> None:
@@ -1948,24 +1961,67 @@ class ShedSession:
         be detected fast, recovery can be smoothed) on ``(C,)`` lanes.
 
         A scalar call (``cam=None``) broadcasts to every lane — the
-        shared-backend form, bit-identical to the pre-lane behavior.
-        Pass ``cam`` to update one camera's lane, so heterogeneous
-        backends and sharded fleets estimate latency per camera. With a
-        session ``metrics`` registry the call is a span
-        ``session.report_latency``."""
+        shared-backend form. Pass ``cam`` to update one camera's lane,
+        so heterogeneous backends and sharded fleets estimate latency
+        per camera. A lane's first report sets it; later ones move it
+        by ``ewma_alpha_up`` (up) or ``ewma_alpha`` (down), every
+        operation in float32.
+
+        The fold runs in NumPy on the session's host copy of the lanes
+        and makes no device call; the next program that reads the lanes
+        (a step, ``offer_batch``, ``tick``, ``fleet_stats``,
+        ``checkpoint``) uploads them once, however many reports came
+        between. With a session ``metrics`` registry the call is a span
+        ``session.report_latency``, and the gauges
+        ``session.latency_reports`` and ``session.latency_flushes`` hold
+        the running totals of reports and of uploads."""
         with self._span("session.report_latency"):
-            st, xp = self.state, self._xp
-            x = max(float(proc_latency), self.min_proc)
-            a = xp.where(x > st.proc_q, self.ewma_alpha_up, self.ewma_alpha)
-            new = xp.where(st.proc_seen, st.proc_q + a * (x - st.proc_q),
-                           x).astype(xp.float32)
+            x = np.float32(max(float(proc_latency), self.min_proc))
+            q, seen = self._proc_q, self._proc_seen
+            a = np.where(x > q, np.float32(self.ewma_alpha_up),
+                         np.float32(self.ewma_alpha))
+            new = np.where(seen, q + a * (x - q), x)
             if cam is None:
-                st.proc_q = new
-                st.proc_seen = xp.ones_like(st.proc_seen)
+                self._set_latency_lanes(new, np.ones_like(seen))
             else:
-                upd = xp.arange(self.num_cameras) == int(cam)
-                st.proc_q = xp.where(upd, new, st.proc_q).astype(xp.float32)
-                st.proc_seen = st.proc_seen | upd
+                upd = np.arange(self.num_cameras) == int(cam)
+                self._set_latency_lanes(np.where(upd, new, q), seen | upd)
+        self._tally("session.latency_reports")
+
+    def _set_latency_lanes(self, proc_q: np.ndarray,
+                           proc_seen: np.ndarray) -> None:
+        """Take new host latency lanes (fresh arrays, never written in
+        place): under ``serve="host"`` they are the state's own lanes,
+        on a device they wait for ``_put_latency_lanes``."""
+        self._proc_q, self._proc_seen = proc_q, proc_seen
+        if self.serve == "host":
+            self.state.proc_q, self.state.proc_seen = proc_q, proc_seen
+        else:
+            self._proc_dirty = True
+
+    def _put_latency_lanes(self) -> None:
+        """Upload the host latency lanes if a report or a lane reset
+        moved them since the last upload: one ``device_put`` of both, on
+        their own shardings (the compiled programs' signatures stay as
+        they are). Every path that hands the state to a program reading
+        the lanes calls this first."""
+        if not self._proc_dirty:
+            return
+        self._proc_dirty = False
+        sh = self._shardings
+        st = self.state
+        st.proc_q, st.proc_seen = jax.device_put(
+            (self._proc_q, self._proc_seen),
+            None if sh is None else (sh["proc_q"], sh["proc_seen"]))
+        self._tally("session.latency_flushes")
+
+    def _tally(self, name: str) -> None:
+        """Add one to the running total ``name``, a gauge of the session
+        registry (its counters are the per-step set that
+        ``bench/session_split.py`` reports)."""
+        if self.metrics is not None:
+            g = self.metrics.gauge(name)
+            g.set(g.value + 1)
 
     def report_ingress_fps(self, fps: float, cam: Optional[int] = None) -> None:
         """Observed ingress rate: per camera, or an aggregate rate split
@@ -1987,6 +2043,7 @@ class ShedSession:
         """Re-derive per-camera thresholds (Eq. 17–19) and queue sizes
         (Eq. 20) from the current metric lanes — one batched quantile +
         queue resize over all C camera lanes."""
+        self._put_latency_lanes()
         if self.cascade is not None:
             if self.serve == "device":
                 self.state, rates, resize_ev = _cascade_tick_dev(
@@ -2081,6 +2138,7 @@ class ShedSession:
         mesh-independent: ``restore`` re-shards onto the restoring
         session's mesh, whatever its device count."""
         from repro.train import checkpoint as ckpt
+        self._put_latency_lanes()
         meta = {
             "kind": "shed_session",
             "num_cameras": self.num_cameras,
@@ -2129,6 +2187,8 @@ class ShedSession:
             else:
                 leaf = np.array(out[k])
             setattr(self.state, k, leaf)
+        self._set_latency_lanes(np.array(out["proc_q"], np.float32),
+                                np.array(out["proc_seen"], bool))
         if meta.get("has_model"):
             self.model = UtilityModel(
                 self.query.colors, np.asarray(out["model_M_pos"]),

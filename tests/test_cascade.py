@@ -172,7 +172,8 @@ def test_degraded_floor_bounds_combined_rate(serve):
 
 def test_device_host_cascade_twins_agree():
     """The jitted cascade phases and their NumPy twins make identical
-    decisions and converge identical thresholds."""
+    decisions and converge identical thresholds, with latency reports
+    pending (on the device) at some of the steps."""
     C, T = 3, 8
     mk = lambda serve: _sess(
         C=C, serve=serve,
@@ -186,6 +187,10 @@ def test_device_host_cascade_twins_agree():
         u = rng.uniform(0, 1, (C, T)).astype(np.float32)
         s2 = rng.uniform(0, 1, (C, T)).astype(np.float32)
         tick = i % 2 == 1
+        if i % 3 == 0:
+            lat, cam = 0.1 + 0.01 * (i % 7), (None if i % 2 else i % C)
+            dev.report_backend_latency(lat, cam=cam)
+            host.report_backend_latency(lat, cam=cam)
         a = dev.step(utilities=u, s2_utilities=s2, tick=tick)
         b = host.step(utilities=u, s2_utilities=s2, tick=tick)
         np.testing.assert_array_equal(a.decisions, b.decisions)
@@ -193,6 +198,10 @@ def test_device_host_cascade_twins_agree():
     np.testing.assert_array_equal(
         np.asarray(dev.state.s2_threshold, np.float32),
         np.asarray(host.state.s2_threshold, np.float32))
+    np.testing.assert_array_equal(np.asarray(dev.state.threshold),
+                                  np.asarray(host.state.threshold))
+    np.testing.assert_array_equal(np.asarray(dev.state.proc_q),
+                                  host.state.proc_q)
     assert dev.stats.dropped_cascade == host.stats.dropped_cascade
 
 

@@ -141,21 +141,36 @@ def test_churned_session_matches_fresh_session_on_survivors():
 
 
 def test_detach_attach_same_camera_equals_fresh_lane():
-    rng = np.random.default_rng(3)
-    sess = _session(C=2)
-    _feed(sess, ["a", "b"], rng.random(30))
-    sess.report_backend_latency(0.05)
-    sess.report_ingress_fps(30.0)
-    sess.tick()
-    before = np.asarray(sess.state.threshold)[1]
-    assert np.isfinite(before)             # b had built real state
-    sess.detach_camera("b")
-    sess.attach_camera("b")                # same id, cycled
-    st = sess.state
-    assert np.asarray(st.threshold)[1] == -np.inf
-    assert int(np.asarray(st.cdf_len)[1]) == 0
-    assert int(np.asarray(st.q_next_seq)[1]) == 0
-    assert bool(np.asarray(st.active)[1])
+    for serve in ("host", "device"):
+        rng = np.random.default_rng(3)
+        sess = _session(C=2, serve=serve)
+        _feed(sess, ["a", "b"], rng.random(30))
+        sess.report_backend_latency(0.05)
+        sess.report_ingress_fps(30.0)
+        sess.tick()
+        before = np.asarray(sess.state.threshold)[1]
+        assert np.isfinite(before)             # b had built real state
+        # latency reports still pending (on a device) when b cycles:
+        # lane 0 keeps them, lane 1 starts unseen, as if each report
+        # had reached the state at once
+        sess.report_backend_latency(0.08, cam=1)
+        sess.report_backend_latency(0.07)
+        sess.detach_camera("b")
+        sess.attach_camera("b")                # same id, cycled
+        st = sess.state
+        assert np.asarray(st.threshold)[1] == -np.inf
+        assert int(np.asarray(st.cdf_len)[1]) == 0
+        assert int(np.asarray(st.q_next_seq)[1]) == 0
+        assert bool(np.asarray(st.active)[1])
+        p0 = np.float32(0.05)
+        p0 = p0 + np.float32(0.6) * (np.float32(0.07) - p0)
+        assert sess.expected_proc(cam=0) == float(p0), serve
+        assert sess.expected_proc(cam=1) == 0.0, serve
+        sess.tick()
+        np.testing.assert_array_equal(np.asarray(sess.state.proc_q),
+                                      [p0, 0.0], err_msg=serve)
+        np.testing.assert_array_equal(np.asarray(sess.state.proc_seen),
+                                      [True, False], err_msg=serve)
 
 
 # -- checkpoint / restore ----------------------------------------------------
@@ -183,7 +198,9 @@ def test_checkpoint_roundtrips_lane_map_and_active_mask(tmp_path):
 def test_midrun_checkpoint_restore_is_bit_identical(tmp_path):
     """Segment 1 -> checkpoint -> segment 2 must equal restoring the
     checkpoint into a fresh session and replaying segment 2: identical
-    admission codes, tick snapshots and state lanes."""
+    admission codes, tick snapshots and state lanes, on the host and on
+    a device. A latency report still pending at the checkpoint is in
+    it."""
     rng = np.random.default_rng(11)
     seg1, seg2 = rng.random(40), rng.random(50)
 
@@ -194,21 +211,25 @@ def test_midrun_checkpoint_restore_is_bit_identical(tmp_path):
         snap = _snap(sess)
         return codes, snap
 
-    live = _session(C=2)
-    _feed(live, ["a", "b"], seg1)
-    live.report_backend_latency(0.06)
-    live.report_ingress_fps(30.0)
-    live.tick()
-    live.checkpoint(tmp_path / "mid", step=1)
-    out_live = segment2(live)
+    for serve in ("host", "device"):
+        live = _session(C=2, serve=serve)
+        _feed(live, ["a", "b"], seg1)
+        live.report_backend_latency(0.06)
+        live.report_ingress_fps(30.0)
+        live.tick()
+        live.report_backend_latency(0.09, cam=1)
+        live.checkpoint(tmp_path / serve, step=1)
 
-    resumed = _session(C=2)
-    resumed.restore(tmp_path / "mid")
-    out_resumed = segment2(resumed)
+        resumed = _session(C=2, serve=serve)
+        resumed.restore(tmp_path / serve)
+        assert resumed.expected_proc(cam=1) == live.expected_proc(cam=1)
+        out_live = segment2(live)
+        out_resumed = segment2(resumed)
 
-    assert out_live == out_resumed
-    for leaf in ("threshold", "q_util", "q_seq", "queue_cap", "cdf_len",
-                 "cdf_pos", "proc_q", "fps_obs", "active", "rate_floor"):
-        a = np.asarray(getattr(live.state, leaf))
-        b = np.asarray(getattr(resumed.state, leaf))
-        assert np.array_equal(a, b), leaf
+        assert out_live == out_resumed, serve
+        for leaf in ("threshold", "q_util", "q_seq", "queue_cap",
+                     "cdf_len", "cdf_pos", "proc_q", "fps_obs", "active",
+                     "rate_floor"):
+            a = np.asarray(getattr(live.state, leaf))
+            b = np.asarray(getattr(resumed.state, leaf))
+            assert np.array_equal(a, b), (serve, leaf)

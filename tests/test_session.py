@@ -221,6 +221,76 @@ def test_offer_lane_mapping_and_limit():
 
 
 # ---------------------------------------------------------------------------
+# The backend-latency feed: a float32 host fold, uploaded at dispatch
+# ---------------------------------------------------------------------------
+
+def _ref_tick(control, procs):
+    """The reference's tick with lane ``c`` on its own estimate
+    ``procs[c]`` (the reference keeps one shared estimate)."""
+    rates = np.empty(len(procs), np.float32)
+    for c, p in enumerate(procs):
+        control.proc = p
+        rates[c] = control.rates()[c]
+        control.threshold[c] = control._threshold(c, rates[c])
+        control.cap[c] = control.queue_cap()
+    for c in range(len(procs)):
+        control.queue[c] = control._keep(control.queue[c], control.cap[c])
+    return rates
+
+
+@pytest.mark.parametrize("mode", ["device", "host", "mesh"])
+def test_latency_feed_matches_reference_fold_bitwise(mode):
+    """200 reports, scalar and per camera mixed, with ticking steps
+    between them: every lane's estimate is the reference's float32 fold
+    bit for bit, both while reports are pending (``expected_proc``) and
+    as uploaded (the state), and so are every tick's decisions, rates,
+    thresholds and queue caps. ``mesh`` is a one-device camera mesh."""
+    from bench import reference as ref
+    from repro.core.fleet import fleet_mesh
+    C, T, W, K = 4, 6, 64, 8
+    rng = np.random.default_rng(21)
+    hist = rng.uniform(0, 1, W).astype(np.float32)
+    where = ({"mesh": fleet_mesh(1)} if mode == "mesh"
+             else {"serve": mode})
+    sess = open_session(Query.single("red", latency_bound=1.0, fps=10.0),
+                        num_cameras=C, train_utilities=hist,
+                        cdf_window=W, queue_size=3, queue_capacity=K,
+                        **where)
+    cfg = {"cdf_window": W, "queue_capacity": K, "quantile_bins": 256,
+           "quantile_range": [0.0, 1.0], "latency_bound_s": 1.0,
+           "queue_size": 3}
+    control = ref.Control(C, cfg, hist, fps=10.0)
+    folds = [ref.Control(1, cfg, hist, fps=10.0) for _ in range(C)]
+    items = [[(c, t) for t in range(T)] for c in range(C)]
+    for i in range(200):
+        # latencies around Eq. 20's budget, repeats (x == q keeps the
+        # down rate) and zeros (clamped to min_proc)
+        x = float(rng.choice([0.0, 0.05, rng.uniform(0.01, 0.4)]))
+        cam = None if rng.random() < 0.4 else int(rng.integers(C))
+        sess.report_backend_latency(x, cam=cam)
+        for c in (range(C) if cam is None else [cam]):
+            folds[c].report_latency(x)
+        procs = np.array([f.proc for f in folds], np.float32)
+        for c in range(C):
+            assert sess.expected_proc(c) == float(procs[c])
+        assert sess.expected_proc() == float(procs.max())
+        if i % 10 == 9:
+            u = rng.uniform(0, 1, (C, T)).astype(np.float32)
+            res = sess.step(utilities=u, items=items, tick=True)
+            want = control.step(u, items, tick=False)
+            rates = _ref_tick(control, procs)
+            st = sess.state
+            np.testing.assert_array_equal(
+                np.asarray(st.proc_q).view(np.uint32), procs.view(np.uint32))
+            np.testing.assert_array_equal(res.decisions, want)
+            np.testing.assert_array_equal(res.target_drop_rate, rates)
+            np.testing.assert_array_equal(np.asarray(st.threshold),
+                                          control.threshold)
+            np.testing.assert_array_equal(np.asarray(st.queue_cap),
+                                          control.cap)
+
+
+# ---------------------------------------------------------------------------
 # Checkpoint round-trip (serve-path state)
 # ---------------------------------------------------------------------------
 
@@ -246,12 +316,14 @@ def test_session_checkpoint_roundtrip(tmp_path, rng):
     sess.report_ingress_fps(24.0)
     sess.tick()
     sess.admit(res2.utility)
+    sess.report_backend_latency(0.3, cam=1)     # not yet read by a tick
     sess.checkpoint(tmp_path, step=3)
 
     fresh = open_session(q, num_cameras=2, frame_shape=(12, 20))
     step, meta = fresh.restore(tmp_path)
     assert step == 3
     assert meta["colors"] == ["red", "yellow"] and meta["num_cameras"] == 2
+    assert fresh.expected_proc(cam=1) == sess.expected_proc(cam=1)
     for k, v in sess.state.as_dict().items():
         np.testing.assert_array_equal(v, fresh.state.as_dict()[k],
                                       err_msg=k)

@@ -4,6 +4,7 @@ records, which must not change a single decision and reach the report
 of a ``ServeService`` over the session."""
 import contextlib
 
+import jax
 import numpy as np
 import pytest
 
@@ -70,15 +71,18 @@ def _session(metrics=None, **kw):
 
 def _drive(session):
     """Steps of uint8 windows with a tick, each followed by a pop of C
-    frames and one latency report per popped frame."""
+    frames and one latency report per popped frame. The reports run
+    under a transfer guard that refuses any implicit transfer to the
+    device: a report is a host fold that makes no device call."""
     rng = np.random.default_rng(1)
     out = []
     for k in range(STEPS):
         frames = rng.integers(0, 256, (C, T, H, W, 3), dtype=np.uint8)
         res = session.step(frames=frames, tick=True)
         popped = session.next_frames(C)
-        for i, _ in enumerate(popped):
-            session.report_backend_latency(0.05 + 0.01 * i)
+        with jax.transfer_guard("disallow"):
+            for i, _ in enumerate(popped):
+                session.report_backend_latency(0.05 + 0.01 * i)
         out.append((res.decisions.copy(), res.target_drop_rate.copy(),
                     popped))
     return out
@@ -112,6 +116,33 @@ def test_session_spans_and_counters_cover_every_call():
     report = reg.report()
     for name in counts:
         assert f"span.{name}" in report
+
+
+def test_latency_reports_are_counted_and_uploaded_at_most_once_a_step():
+    """The gauges ``session.latency_reports`` and
+    ``session.latency_flushes`` total the reports and the uploads: the
+    reports that come between two steps reach the device in one upload,
+    when the next step dispatches."""
+    reg = MetricsRegistry()
+    s = _session(reg)
+    runs = _drive(s)
+    totals = lambda: {k: g["value"]
+                      for k, g in reg.snapshot()["gauges"].items()}
+    reports = sum(len(p) for _, _, p in runs)
+    assert reports > STEPS
+    # the last step's reports are still pending: one upload for each
+    # step that followed reports
+    assert totals() == {"session.latency_reports": reports,
+                        "session.latency_flushes": STEPS - 1}
+    pending = s.expected_proc()
+    s.tick()
+    assert totals()["session.latency_flushes"] == STEPS
+    assert float(np.asarray(s.state.proc_q).max()) == pending
+    s.tick()                        # nothing pending: no upload
+    assert totals()["session.latency_flushes"] == STEPS
+    # the registry's counters stay the per-step set
+    assert set(reg.snapshot()["counters"]) == {
+        "session.steps", "session.frames", "session.staged_bytes"}
 
 
 def test_session_frames_count_every_step_and_offer_batch_has_no_phases():
