@@ -4,8 +4,8 @@ device-serve path, at C >= 1024 cameras.
 
 Three measurements on the same seeded trace:
 
-  * ``single_device_ms`` — the unsharded ``serve="device"`` step at C
-    cameras (the pre-fleet baseline);
+  * ``single_device_ms`` — the unsharded device step at C cameras
+    (the pre-fleet baseline);
   * ``fleet_wall_ms``   — the sharded step over all local devices;
   * ``shard_program_ms`` — the unsharded step at C/ndev cameras: the
     *exact* program each mesh device runs concurrently (the serve plane
@@ -49,10 +49,10 @@ def _sessions(C, W, ndev, rng):
     kw = dict(num_cameras=C, train_utilities=hist, queue_size=4,
               queue_capacity=16, cdf_window=W)
     q = Query.single("red", latency_bound=1.0, fps=FPS)
-    single = open_session(q, serve="device", **kw)
+    single = open_session(q, **kw)
     fleet = open_session(q, shard_cameras=True, **kw)
     kw["num_cameras"] = C // ndev
-    shard = open_session(q, serve="device", **kw)
+    shard = open_session(q, **kw)
     return single, fleet, shard
 
 

@@ -78,8 +78,8 @@ def _session(su: dict, **kw):
     # (restart bit-identity, outage/churn QoR curves) against the
     # exact-quantile reference trajectory. The closed control loop is
     # chaotic — one extra shed frame rewrites the latency feedback and
-    # with it the whole trajectory — so the bucket tick's (bounded,
-    # characterized in bench_transmit) threshold drift would land these
+    # with it the whole trajectory — so the bucket tick's (bounded, see
+    # tests/test_transmit.py) threshold drift would land these
     # scenarios on a different-but-equally-valid trajectory and make
     # the pinned curves meaningless as a regression signal.
     kw.setdefault("exact_tick", True)

@@ -23,8 +23,6 @@ BENCHES = [
     ("fig13a_control_loop", "benchmarks.bench_control_loop"),
     ("fig13b_14_multicam", "benchmarks.bench_multicam"),
     ("fig15_overhead", "benchmarks.bench_overhead"),
-    ("serve_step_fused", "benchmarks.bench_serve_step"),
-    ("transmit_control", "benchmarks.bench_transmit"),
     ("fleet_sharded", "benchmarks.bench_fleet"),
     ("service_streaming", "benchmarks.bench_service"),
     ("scenarios_resilience", "benchmarks.bench_scenarios"),

@@ -16,8 +16,8 @@ seeds by ``repro.data.synthetic``:
 
 ``--fleet`` (four chips) runs only the camera-sharded session: 32
 cameras at 1280x720, 8 per chip, its fused steps and ``next_frames``
-pops checked bit-identical against the unsharded ``serve="device"``
-session on one chip, and every state leaf spread over 4 devices.
+pops checked bit-identical against the unsharded session on one
+chip, and every state leaf spread over 4 devices.
 
 Earlier output lines are smoke facts (sizes, first-call seconds,
 frames served, parity); the last line is the JSON result. The script
@@ -213,10 +213,10 @@ def fleet(chips: int = FLEET_CHIPS, cams: int = FLEET_CAMS,
     # and report a backend latency at which Eq. 19 drops about half the
     # frames, so the compared decisions include sheds
     kw = dict(num_cameras=cams, frame_shape=(height, width), model=model)
-    hist = open_session(q, serve="device", **kw).ingest(window(0)).utility
+    hist = open_session(q, **kw).ingest(window(0)).utility
     kw["train_utilities"] = hist.reshape(-1)
     latency = 2.0 / (cams * q.fps)
-    ref = open_session(q, serve="device", **kw)
+    ref = open_session(q, **kw)
     fl = open_session(q, mesh=fleet_lib.fleet_mesh(chips), **kw)
     shed = 0
     for s in range(steps):

@@ -3,7 +3,8 @@
 # control loop, utility-ordered bounded queue, QoR metrics), unified
 # behind the multi-camera session API (repro.core.session). Fleet
 # scale-out (camera lanes sharded over a device mesh) lives in
-# repro.core.fleet and is reached via open_session(shard_cameras=True).
+# repro.core.fleet and is reached via open_session(shard_cameras=True);
+# both run the jitted programs of repro.core.control_plane.
 from repro.core.colors import BLUE, COLORS, GREEN, RED, YELLOW, Color
 from repro.core.control import ControlLoop, LatencyInputs
 from repro.core.qor import drop_rate, overall_qor, per_object_qor
@@ -21,10 +22,10 @@ from repro.core.utility import (
     train_utility_model,
 )
 
-# The session API is imported on first use: session.py pulls in the
-# ingest kernels, which themselves import the core math modules above,
-# so an eager import here would make ``import
-# repro.kernels.hsv_features.kernel`` circular.
+# The session API is imported on first use: session.py (through
+# control_plane.py) pulls in the ingest kernels, which themselves import
+# the core math modules above, so an eager import here would make
+# ``import repro.kernels.hsv_features.kernel`` circular.
 _SESSION_NAMES = ("IngestResult", "Query", "SessionState", "ShedSession",
                   "StepResult", "open_session")
 

@@ -48,6 +48,14 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core.control_plane import (
+    ADMIT,
+    SessionState,
+    control_core,
+    no_span,
+    tick_core,
+)
+from repro.kernels.hsv_features.ops import ingest_core
 from repro.sharding.api import auto_mesh, resolve_axis
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -149,7 +157,6 @@ def _local_aggregates(state, axis: AxisName, decisions=None):
                               .sum().astype(jnp.float32)),
     }
     if decisions is not None:
-        from repro.core.session import ADMIT
         agg["offered"] = psum((decisions >= 0).sum().astype(jnp.int32))
         agg["admitted"] = psum((decisions == ADMIT).sum().astype(jnp.int32))
         agg["shed"] = psum((decisions > ADMIT).sum().astype(jnp.int32))
@@ -186,8 +193,8 @@ def derive_fleet_stats(agg: Dict[str, Any],
 
 
 # ---------------------------------------------------------------------------
-# The sharded serve plane — shard_map'd twins of the session's device
-# programs. Row-local math only; num_total keeps Eq. 19 global.
+# The sharded serve plane — the control plane's cores shard_map'd over
+# the camera axis. Row-local math only; num_total keeps Eq. 19 global.
 # ---------------------------------------------------------------------------
 
 def _out_pspecs(axis: AxisName, with_decisions: bool):
@@ -210,12 +217,11 @@ def _fleet_control(state, util, present, *, mesh, axis, num_total, masked,
     """Sharded control step: CDF push -> admission -> queue selection ->
     (optional) tick, each camera shard running the identical row-local
     program; one optional psum aggregate tree rides along."""
-    from repro.core.session import SessionState, _control_core_dev
     st_spec = state_pspecs(SessionState, axis)
     ctrl_spec, agg_spec = _out_pspecs(axis, True)
 
     def local(st, u, pres):
-        st, out = _control_core_dev(
+        st, out = control_core(
             st, u, pres if masked else None, update_cdf=update_cdf,
             do_tick=do_tick, min_proc=min_proc, budget=budget,
             num_total=num_total, tick_cfg=tick_cfg)
@@ -247,8 +253,6 @@ def _fleet_serve_step(state, frames, M_pos, norm, *, mesh, axis, num_total,
     ``(C, T, H, W, 3)`` laid out as ``frame_sharding`` places them, or
     already flattened to ``(C, T, H*W, 3)``; any dtype (the ingest's
     first relayout converts to float32)."""
-    from repro.core.session import SessionState, _control_core_dev
-    from repro.kernels.hsv_features.ops import ingest_core
     st_spec = state_pspecs(SessionState, axis)
     ctrl_spec, agg_spec = _out_pspecs(axis, True)
 
@@ -266,7 +270,7 @@ def _fleet_serve_step(state, frames, M_pos, norm, *, mesh, axis, num_total,
         st = dataclasses.replace(st, bg=bg, gain=gain,
                                  bg_valid=jnp.asarray(True))
         with jax.named_scope("shed.control"):
-            st, out = _control_core_dev(
+            st, out = control_core(
                 st, util, None, update_cdf=update_cdf, do_tick=do_tick,
                 min_proc=min_proc, budget=budget, num_total=num_total,
                 tick_cfg=tick_cfg)
@@ -291,12 +295,11 @@ def _fleet_tick(state, *, mesh, axis, num_total, min_proc, budget,
     """Sharded Eq. 18–20 tick: per-shard batched quantile (O(bins) on
     the incremental bucket counts by default) + queue resize; rates use
     the GLOBAL camera count."""
-    from repro.core.session import SessionState, _tick_core_dev
     st_spec = state_pspecs(SessionState, axis)
 
     def local(st):
-        st, rates, resize_ev = _tick_core_dev(st, min_proc, budget,
-                                              num_total, tick_cfg=tick_cfg)
+        st, rates, resize_ev = tick_core(st, min_proc, budget, num_total,
+                                         tick_cfg=tick_cfg)
         return st, rates, resize_ev
 
     return jax.shard_map(
@@ -363,7 +366,6 @@ def _fleet_pop_clear(state, gcam, slot, *, mesh, axis):
     """Clear the popped (global camera, slot) entries shard-locally:
     the (gcam, slot) lists are replicated; each shard scatters only the
     rows it owns (out-of-range rows drop)."""
-    from repro.core.session import SessionState
     st_spec = state_pspecs(SessionState, axis)
 
     def local(st, gc, sl):
@@ -394,8 +396,7 @@ def pop_topk(state, *, mesh, axis, k, rows=None, metrics=None):
     their read-back) and ``session.pop_merge`` (the host merge and the
     clear's dispatch), and ``fleet.pop_candidates`` counts the
     ``S * kk`` candidates read back."""
-    from repro.core.session import _no_span
-    span = metrics.span if metrics is not None else _no_span
+    span = metrics.span if metrics is not None else no_span
     C, K = state.q_util.shape
     S = mesh_axis_size(mesh, axis)
     k = int(k)
@@ -430,7 +431,6 @@ def pop_topk(state, *, mesh, axis, k, rows=None, metrics=None):
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis"))
 def _fleet_aggregates(state, *, mesh, axis):
-    from repro.core.session import SessionState
     st_spec = state_pspecs(SessionState, axis)
     agg_spec = {k: P() for k in _empty_aggregates(False)}
     return jax.shard_map(

@@ -11,7 +11,7 @@ array*:
     latency budget, per-camera target FPS, feature-bin and
     background-model constants. One compiled shedder per query.
 
-``SessionState``
+``SessionState`` (``repro.core.control_plane``)
     An explicit JAX pytree of per-camera state lanes: ``(C, N)``
     background rows and ``(C,)`` illumination gains (the fused ingest
     kernel's carried state), per-camera utility-CDF ring buffers and
@@ -36,51 +36,56 @@ array*:
     simulator drives; ``checkpoint``/``restore`` persist the state
     pytree.
 
-Serve-control implementations (``serve=``), mirroring the ingest
-layer's backend-aware dispatch:
-
-``"device"``
-    SessionState lanes live as jnp device arrays and ``step`` is ONE
-    jitted, donated-buffer XLA program (ingest kernel + ring-buffer CDF
-    push + ``u < threshold`` admission + top-cap queue selection + one
-    batched (C, W) quantile sort). The TPU serving path.
-
-``"host"``
-    Lanes are NumPy arrays and the identical algorithms run as
-    vectorized NumPy — the compiled-CPU serving path (XLA's CPU sort
-    lowering is far slower than ``np.sort``, exactly why ingest also
-    dispatches per backend). Bit-identical float32 results; the two
-    impls are parity-tested against each other and against the scalar
-    heapq/`threshold_from_sorted` reference.
+The control plane is one implementation: the state lanes are jnp
+arrays and every control operation is a jitted XLA program with donated
+state buffers (``repro.core.control_plane``), on every backend — the
+TPU, and XLA CPU on a CPU. A camera-sharded session runs the same
+cores shard_map'd over a mesh (``repro.core.fleet``). The host keeps
+only bookkeeping: payloads, counters, queue depths and the
+backend-latency fold.
 
 ``open_session(query, num_cameras, ...)`` is the entry point.
 """
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import functools
 import heapq
 from dataclasses import dataclass
-from typing import (TYPE_CHECKING, Any, Dict, List, NamedTuple, Optional,
-                    Sequence, Tuple, Union)
+from typing import (TYPE_CHECKING, Any, Dict, List, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import fleet
 from repro.core import shed_queue as sq
 from repro.core.colors import COLORS, Color
 from repro.core.control import LatencyInputs
-from repro.core.shedder import ShedderStats
-from repro.core.threshold import (
-    bucket_index_dev,
-    bucket_index_host,
-    thresholds_from_counts_dev,
-    thresholds_from_counts_host,
-    thresholds_from_lanes_dev,
-    thresholds_from_lanes_host,
+from repro.core.control_plane import (
+    ADMIT,
+    DECISION_NAMES,
+    SHED_ADMISSION,
+    SHED_CASCADE,
+    SHED_QUEUE,
+    SessionState,
+    TickConfig,
+    _cascade_admit_dev,
+    _cascade_finish_dev,
+    _cascade_tick_dev,
+    _control_masked_dev,
+    _control_step_dev,
+    _flatten_frames,
+    _offer_dev,
+    _pop_any_dev,
+    _pop_cam_dev,
+    _pop_topk_dev,
+    _pop_topk_masked_dev,
+    _serve_step_dev,
+    _tick_dev,
+    no_span,
+    ring_push,
 )
+from repro.core.shedder import ShedderStats
 from repro.core.utility import (
     B_S,
     B_V,
@@ -91,59 +96,12 @@ from repro.core.utility import (
 from repro.kernels.hsv_features.ops import (
     IngestState,
     default_impl,
-    ingest_core,
     ingest_pipeline,
     query_constants,
 )
 
 if TYPE_CHECKING:
     from repro.serve.metrics import MetricsRegistry
-
-# admit() decision codes — (C, T) int8 arrays, vectorized per camera
-# (offer_batch marks padding slots that carried no frame with -1)
-ADMIT = 0
-SHED_ADMISSION = 1
-SHED_QUEUE = 2
-SHED_CASCADE = 3     # passed the color gate, shed by the stage-2 scorer
-
-_DECISION_NAMES = {ADMIT: "queued", SHED_ADMISSION: "shed_admission",
-                   SHED_QUEUE: "shed_queue", SHED_CASCADE: "shed_cascade"}
-
-# the span of a session opened without a metrics registry: one shared
-# context for every site, so an unmetered step allocates nothing and
-# reads no clock
-_NO_SPAN = contextlib.nullcontext()
-
-
-def _no_span(name: str):
-    return _NO_SPAN
-
-
-class TickConfig(NamedTuple):
-    """Static quantile-tick configuration, threaded as ONE hashable
-    static through the serve-step programs.
-
-    ``exact=True`` re-derives Eq. 17 thresholds with the full ``(C, W)``
-    sort (``thresholds_from_lanes_*``) — bit-identical to the pre-bucket
-    behavior, the escape hatch. ``exact=False`` (the default) uses the
-    O(bins) cumsum over the incrementally-maintained ``(C, bins)`` count
-    histograms, whose threshold is within one bucket width above the
-    exact one for in-range utilities. The bucket geometry
-    (``lo``/``width``/``inv_width`` for the stage-1 utility range,
-    ``s2_*`` for the cascade scorer's softsign range) is baked in here;
-    counts are maintained either way, so flipping ``exact`` never
-    desyncs checkpointed state.
-    """
-    exact: bool = False
-    lo: float = 0.0
-    width: float = 1.0 / 256.0
-    inv_width: float = 256.0
-    s2_lo: float = -1.0
-    s2_width: float = 2.0 / 256.0
-    s2_inv_width: float = 128.0
-
-
-DEFAULT_TICK_CONFIG = TickConfig()
 
 
 def _as_color(c: Union[str, Color]) -> Color:
@@ -204,108 +162,6 @@ class Query:
         return len(self.colors)
 
 
-@jax.tree_util.register_dataclass
-@dataclass
-class SessionState:
-    """Per-camera session state — a pytree whose every leaf is an array
-    with a leading camera lane, so C cameras are one device dispatch
-    and one checkpointable object.
-
-    Camera lanes (row c belongs to camera c):
-      * ``bg (C, N)`` / ``gain (C,)`` — the fused ingest kernel's
-        carried background state; ``bg_valid ()`` says whether the lanes
-        hold real history yet (frame 0 seeds them otherwise).
-      * ``cdf_buf (C, W)`` ring buffers of recent utilities with
-        ``cdf_len`` / ``cdf_pos`` — the sliding-window utility CDF
-        (Eq. 16) per camera; ``cdf_counts (C, B)`` is its bucket-count
-        histogram, maintained incrementally with push/evict deltas so a
-        control tick is O(B) instead of a (C, W) sort (``TickConfig``).
-      * ``threshold (C,)`` — current admission thresholds (Eq. 17).
-      * ``proc_q (C,)`` (+ ``proc_seen``) — asymmetric-EWMA backend
-        latency estimates, folded on the host and written here by the
-        session (no program writes them); ``fps_obs (C,)`` (+
-        ``fps_seen``) — observed per-camera ingress rates (Eq. 18–19
-        inputs).
-      * ``queue_cap (C,)`` — dynamic queue sizes (Eq. 20).
-      * ``q_util`` / ``q_seq (C, K)`` + ``q_next_seq (C,)`` — the
-        utility-ordered queues as array lanes (``repro.core.shed_queue``
-        ordering contract; empty slots are ``(-inf, -1)``). ``K`` is the
-        physical bound; the *effective* size is ``queue_cap`` clipped
-        to it.
-    """
-    bg: Any          # (C, N) float32
-    gain: Any        # (C,) float32
-    bg_valid: Any    # () bool
-    cdf_buf: Any     # (C, W) float32
-    cdf_len: Any     # (C,) int32
-    cdf_pos: Any     # (C,) int32
-    cdf_counts: Any  # (C, B) int32 — live-window bucket histogram
-    #                  (always equals a recount of cdf_buf[:, :cdf_len])
-    threshold: Any   # (C,) float32
-    proc_q: Any      # (C,) float32
-    proc_seen: Any   # (C,) bool
-    fps_obs: Any     # (C,) float32
-    fps_seen: Any    # (C,) bool
-    queue_cap: Any   # (C,) int32
-    q_util: Any      # (C, K) float32
-    q_seq: Any       # (C, K) int32
-    q_next_seq: Any  # (C,) int32
-    active: Any      # (C,) bool — detached lanes are masked out of
-    #                  control (threshold forced +inf so they admit
-    #                  nothing); all-True is bit-identical to pre-churn
-    rate_floor: Any  # (C,) float32 — degraded-mode floor under the
-    #                  Eq. 19 target drop rates; 0 = normal regime
-    # stage-2 (semantic cascade) lanes — inert unless the session was
-    # opened with cascade=; same ring/threshold machinery as the
-    # stage-1 CDF, but over the scorer outputs of frames that PASSED
-    # the color gate
-    s2_buf: Any        # (C, W2) float32 stage-2 score ring
-    s2_len: Any        # (C,) int32
-    s2_pos: Any        # (C,) int32
-    s2_threshold: Any  # (C,) float32 stage-2 shed thresholds
-    s2_counts: Any     # (C, B) int32 stage-2 bucket histogram
-
-    @property
-    def num_cameras(self) -> int:
-        return self.gain.shape[0]
-
-    def as_dict(self) -> Dict[str, np.ndarray]:
-        return {f.name: np.asarray(getattr(self, f.name))
-                for f in dataclasses.fields(self)}
-
-    @classmethod
-    def fresh(cls, num_cameras: int, npix: int = 0, *,
-              cdf_window: int = 4096, fps: float = 10.0,
-              queue_size: int = 8, queue_capacity: int = 64,
-              s2_window: int = 64, quantile_bins: int = 256,
-              xp=np) -> "SessionState":
-        C = int(num_cameras)
-        K = max(int(queue_capacity), int(queue_size), 1)
-        B = int(quantile_bins)
-        q_util, q_seq, q_next = sq.make_lanes(C, K, xp=xp)
-        return cls(
-            bg=xp.zeros((C, npix), xp.float32),
-            gain=xp.ones((C,), xp.float32),
-            bg_valid=xp.asarray(False),
-            cdf_buf=xp.zeros((C, cdf_window), xp.float32),
-            cdf_len=xp.zeros((C,), xp.int32),
-            cdf_pos=xp.zeros((C,), xp.int32),
-            cdf_counts=xp.zeros((C, B), xp.int32),
-            threshold=xp.full((C,), -xp.inf, xp.float32),
-            proc_q=xp.zeros((C,), xp.float32),
-            proc_seen=xp.zeros((C,), bool),
-            fps_obs=xp.full((C,), float(fps), xp.float32),
-            fps_seen=xp.zeros((C,), bool),
-            queue_cap=xp.full((C,), int(queue_size), xp.int32),
-            q_util=q_util, q_seq=q_seq, q_next_seq=q_next,
-            active=xp.ones((C,), bool),
-            rate_floor=xp.zeros((C,), xp.float32),
-            s2_buf=xp.zeros((C, int(s2_window)), xp.float32),
-            s2_len=xp.zeros((C,), xp.int32),
-            s2_pos=xp.zeros((C,), xp.int32),
-            s2_threshold=xp.full((C,), -xp.inf, xp.float32),
-            s2_counts=xp.zeros((C, B), xp.int32),
-        )
 
 
 @dataclass(frozen=True)
@@ -338,666 +194,6 @@ class StepResult:
     s2_scores: Optional[np.ndarray] = None
 
 
-# ---------------------------------------------------------------------------
-# Serve-step cores — device (traced jnp) and host (vectorized NumPy)
-# twins. Same float32 math, bit-identical outputs; see module docstring.
-# ---------------------------------------------------------------------------
-
-def _ring_push_dev(buf, pos, ln, counts, us, mask, lo: float,
-                   inv_width: float):
-    """Append a (C, T) utility batch into the per-camera ring buffers;
-    ``mask`` marks real entries (None = all). The (C, B) bucket
-    ``counts`` are maintained incrementally (ring-wrap aware: slot s is
-    pre-push live iff s < len, regardless of where ``pos`` wrapped), so
-    they always equal a recount of the live window. Utilities are stored
-    flushed of subnormals (``shed_queue.flush_subnormal``)."""
-    C, W = buf.shape
-    B = counts.shape[1]
-    us = sq.flush_subnormal(us, jnp)
-    rows = jnp.arange(C)[:, None]
-    if mask is None:
-        if us.shape[1] >= W:                   # only the tail can survive
-            us = us[:, -W:]
-        T = us.shape[1]
-        idx = (pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]) % W
-        old = jnp.take_along_axis(buf, idx, axis=1)
-        evict = idx < ln[:, None]
-        buf = buf.at[rows, idx].set(us)
-        counts = counts.at[rows, bucket_index_dev(us, lo, inv_width, B)].add(
-            jnp.int32(1))
-        cnt = jnp.full((C,), T, jnp.int32)
-    else:
-        kk = jnp.cumsum(mask.astype(jnp.int32), axis=1)
-        idx = jnp.where(mask, (pos[:, None] + kk - 1) % W, W)
-        old = jnp.take_along_axis(buf, jnp.minimum(idx, W - 1), axis=1)
-        evict = mask & (idx < ln[:, None])
-        buf = buf.at[rows, idx].set(us, mode="drop")
-        counts = counts.at[rows, bucket_index_dev(us, lo, inv_width, B)].add(
-            mask.astype(jnp.int32))
-        cnt = kk[:, -1]
-    counts = counts.at[rows, bucket_index_dev(old, lo, inv_width, B)].add(
-        -evict.astype(jnp.int32))
-    pos = ((pos + cnt) % W).astype(jnp.int32)
-    ln = jnp.minimum(ln + cnt, W).astype(jnp.int32)
-    return buf, pos, ln, counts
-
-
-def _ring_push_host(buf, pos, ln, counts, us, mask, lo: float,
-                    inv_width: float):
-    """NumPy twin of :func:`_ring_push_dev`; mutates ``buf`` and
-    ``counts`` in place, returns (pos', len')."""
-    C, W = buf.shape
-    B = counts.shape[1]
-    us = sq.flush_subnormal(us)
-    if mask is None:
-        if us.shape[1] >= W:
-            us = us[:, -W:]
-        T = us.shape[1]
-        idx = (pos[:, None] + np.arange(T, dtype=np.int32)[None, :]) % W
-        rows = np.arange(C)[:, None]
-        old = buf[rows, idx]                       # pre-write snapshot
-        evict = idx < ln[:, None]
-        rb = np.broadcast_to(rows, idx.shape)
-        np.add.at(counts, (rb[evict],
-                           bucket_index_host(old[evict], lo, inv_width, B)),
-                  -1)
-        np.add.at(counts, (rb.reshape(-1),
-                           bucket_index_host(us, lo, inv_width,
-                                             B).reshape(-1)), 1)
-        buf[rows, idx] = us
-        cnt = np.full((C,), T, np.int32)
-    else:
-        kk = np.cumsum(mask.astype(np.int32), axis=1)
-        idx = (pos[:, None] + kk - 1) % W
-        r, t = np.nonzero(mask)
-        ii = idx[r, t]
-        old = buf[r, ii]
-        ev = ii < ln[r]
-        np.add.at(counts, (r[ev],
-                           bucket_index_host(old[ev], lo, inv_width, B)), -1)
-        np.add.at(counts, (r, bucket_index_host(us[r, t], lo, inv_width, B)),
-                  1)
-        buf[r, ii] = us[r, t]
-        cnt = kk[:, -1].astype(np.int32)
-    pos = ((pos + cnt) % W).astype(np.int32)
-    ln = np.minimum(ln + cnt, W).astype(np.int32)
-    return pos, ln
-
-
-def _tick_core_dev(state: SessionState, min_proc: float, budget: float,
-                   num_total: Optional[int] = None,
-                   tick_cfg: Optional[TickConfig] = None):
-    """Eq. 18–20 re-derivation on device: target rates from the metric
-    lanes, thresholds via the O(bins) bucket cumsum (or ONE batched
-    (C, W) sort under ``tick_cfg.exact``), queue caps + resize.
-
-    ``num_total`` is the number of cameras sharing the backend — Eq. 19's
-    service-time multiplier. It defaults to the local lane count; a
-    camera-sharded fleet step (repro.core.fleet) passes the GLOBAL count
-    so every shard derives the same rates as the unsharded program.
-    """
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    C = num_total if num_total is not None else state.threshold.shape[0]
-    p = jnp.maximum(state.proc_q, min_proc)
-    # single-division form of Eq. 19's 1 - (ST/C)/fps: bit-stable under
-    # XLA (the two-division chain gets algebraically rewritten by the
-    # compiler, which would break device/host bit parity)
-    rates = jnp.clip(
-        1.0 - 1.0 / (p * C * jnp.maximum(state.fps_obs, 1e-9)),
-        0.0, 1.0).astype(jnp.float32)
-    # degraded-mode floor + churn mask: exact elementwise ops AFTER the
-    # Eq. 19 expression, so floor=0 / all-active stays bit-identical
-    rates = jnp.maximum(rates, state.rate_floor).astype(jnp.float32)
-    rates = jnp.where(state.active, rates, jnp.float32(0.0))
-    if tick_cfg.exact:
-        threshold = thresholds_from_lanes_dev(state.cdf_buf, state.cdf_len,
-                                              rates)
-    else:
-        threshold = thresholds_from_counts_dev(
-            state.cdf_counts, state.cdf_len, rates, tick_cfg.lo,
-            tick_cfg.width)
-    threshold = jnp.where(state.active, threshold, jnp.float32(jnp.inf))
-    cap = jnp.maximum((budget / p + 1e-9).astype(jnp.int32) - 1, 1)
-    q_util, q_seq, resize_ev = sq.resize_dev(state.q_util, state.q_seq, cap)
-    state = dataclasses.replace(
-        state, threshold=threshold, queue_cap=cap.astype(jnp.int32),
-        q_util=q_util, q_seq=q_seq)
-    return state, rates, resize_ev
-
-
-def _resize_host_guarded(state: SessionState, cap: np.ndarray, exact: bool,
-                         live: Optional[np.ndarray] = None) -> np.ndarray:
-    """Host-tick queue resize with a no-eviction fast path.
-
-    When no lane holds more live entries than its new (clipped) cap,
-    ``sq.resize_host`` would evict nothing and only renormalize the
-    physical lane layout — which nothing reads (entries are keyed by
-    seq; the next select renormalizes anyway) — so the (C, K) sort is
-    skipped and an all-(-1) event array returned. Gated off under
-    ``exact_tick`` so that escape hatch stays bit-identical to the
-    legacy tick, physical layout included.
-
-    ``live`` is an optional (C,) per-lane live-entry count (the
-    session passes its depth cache); recounted from ``q_seq`` when
-    absent.
-    """
-    K = state.q_seq.shape[1]
-    if not exact:
-        occ = live if live is not None else (state.q_seq >= 0).sum(axis=1)
-        if int((occ > np.clip(cap, 1, K)).sum()) == 0:
-            return np.full_like(state.q_seq, -1)
-    return sq.resize_host(state.q_util, state.q_seq, cap)
-
-
-def _tick_core_host(state: SessionState, min_proc: float, budget: float,
-                    num_total: Optional[int] = None,
-                    tick_cfg: Optional[TickConfig] = None,
-                    live: Optional[np.ndarray] = None):
-    """NumPy twin of :func:`_tick_core_dev`; mutates state in place.
-    ``live`` optionally feeds the session's (C,) depth cache to the
-    resize fast path (see :func:`_resize_host_guarded`)."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    C = num_total if num_total is not None else state.threshold.shape[0]
-    p = np.maximum(state.proc_q, min_proc)
-    rates = np.clip(
-        1.0 - np.float32(1.0) / (p * C * np.maximum(state.fps_obs, 1e-9)),
-        0.0, 1.0).astype(np.float32, copy=False)
-    rates = np.maximum(rates, state.rate_floor)
-    rates = np.where(state.active, rates, np.float32(0.0))
-    if tick_cfg.exact:
-        threshold = thresholds_from_lanes_host(
-            state.cdf_buf, state.cdf_len, rates)
-    else:
-        threshold = thresholds_from_counts_host(
-            state.cdf_counts, state.cdf_len, rates, tick_cfg.lo,
-            tick_cfg.width)
-    state.threshold = np.where(state.active, threshold,
-                               np.float32(np.inf)).astype(np.float32,
-                                                          copy=False)
-    cap = np.maximum((budget / p + 1e-9).astype(np.int32) - 1, 1)
-    state.queue_cap = cap.astype(np.int32)
-    resize_ev = _resize_host_guarded(state, cap, tick_cfg.exact, live)
-    return rates, resize_ev
-
-
-def _control_core_dev(state: SessionState, util, present, *,
-                      update_cdf: bool, do_tick: bool,
-                      min_proc: float, budget: float,
-                      num_total: Optional[int] = None,
-                      tick_cfg: Optional[TickConfig] = None):
-    """CDF push -> admission -> queue selection -> (optional) tick, all
-    traced. Returns (state', outputs-dict of compact arrays)."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    util = util.astype(jnp.float32)
-    C, T = util.shape
-    rows = jnp.arange(C)[:, None]
-    cdf_buf, cdf_pos, cdf_len = state.cdf_buf, state.cdf_pos, state.cdf_len
-    cdf_counts = state.cdf_counts
-    if update_cdf:
-        cdf_buf, cdf_pos, cdf_len, cdf_counts = _ring_push_dev(
-            cdf_buf, cdf_pos, cdf_len, cdf_counts, util, present,
-            tick_cfg.lo, tick_cfg.inv_width)
-    shed = util < state.threshold[:, None]
-    admit = ~shed if present is None else (present & ~shed)
-    decisions = jnp.where(admit, ADMIT, SHED_ADMISSION).astype(jnp.int8)
-    if present is not None:
-        decisions = jnp.where(present, decisions, jnp.int8(-1))
-    q_util, q_seq, q_next, pushed_seq, ev_s, ev_b = sq.push_batch_dev(
-        state.q_util, state.q_seq, state.q_next_seq, util, admit,
-        state.queue_cap)
-    # retroactive SHED_QUEUE flips for this batch's evicted frames: a
-    # scatter-max (codes are 0 <= 1 <= 2, dummy writes use -1 = no-op)
-    flip = ev_b >= 0
-    decisions = decisions.at[rows, jnp.where(flip, ev_b, 0)].max(
-        jnp.where(flip, jnp.int8(SHED_QUEUE), jnp.int8(-1)))
-    state = dataclasses.replace(
-        state, cdf_buf=cdf_buf, cdf_pos=cdf_pos, cdf_len=cdf_len,
-        cdf_counts=cdf_counts, q_util=q_util, q_seq=q_seq, q_next_seq=q_next)
-    out = {
-        "decisions": decisions,
-        "pushed_seq": pushed_seq,
-        "evicted_resident": jnp.where((ev_b < 0) & (ev_s >= 0), ev_s, -1),
-        "push_evictions": (ev_s >= 0).sum(axis=-1).astype(jnp.int32),
-        "rates": jnp.zeros((C,), jnp.float32),
-        "resize_evicted": jnp.full_like(state.q_seq, -1),
-    }
-    if do_tick:
-        state, rates, resize_ev = _tick_core_dev(state, min_proc, budget,
-                                                 num_total, tick_cfg)
-        out["rates"] = rates
-        out["resize_evicted"] = resize_ev
-    return state, out
-
-
-def _control_core_host(state: SessionState, util, present, *,
-                       update_cdf: bool, do_tick: bool,
-                       min_proc: float, budget: float,
-                       num_total: Optional[int] = None,
-                       tick_cfg: Optional[TickConfig] = None):
-    """NumPy twin of :func:`_control_core_dev`; mutates state in place."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    util = np.asarray(util, np.float32)
-    C, T = util.shape
-    if update_cdf:
-        state.cdf_pos, state.cdf_len = _ring_push_host(
-            state.cdf_buf, state.cdf_pos, state.cdf_len, state.cdf_counts,
-            util, present, tick_cfg.lo, tick_cfg.inv_width)
-    shed = util < state.threshold[:, None]
-    admit = ~shed if present is None else (present & ~shed)
-    decisions = np.where(admit, ADMIT, SHED_ADMISSION).astype(np.int8)
-    if present is not None:
-        decisions = np.where(present, decisions, np.int8(-1))
-    q_next, pushed_seq, ev_s, ev_b = sq.push_batch_host(
-        state.q_util, state.q_seq, state.q_next_seq, util, admit,
-        state.queue_cap)
-    state.q_next_seq = q_next
-    r, i = np.nonzero(ev_b >= 0)
-    decisions[r, ev_b[r, i]] = SHED_QUEUE
-    out = {
-        "decisions": decisions,
-        "pushed_seq": pushed_seq,
-        "evicted_resident": np.where((ev_b < 0) & (ev_s >= 0), ev_s, -1),
-        "push_evictions": (ev_s >= 0).sum(axis=-1).astype(np.int32),
-        "rates": np.zeros((C,), np.float32),
-        "resize_evicted": np.full_like(state.q_seq, -1),
-    }
-    if do_tick:
-        rates, resize_ev = _tick_core_host(state, min_proc, budget,
-                                           num_total, tick_cfg)
-        out["rates"] = rates
-        out["resize_evicted"] = resize_ev
-    return state, out
-
-
-# ---------------------------------------------------------------------------
-# Semantic-cascade cores. Same twin discipline as the single-stage
-# control cores, but split around the host scorer call: phase A (stage-1
-# CDF push + color gate) -> scorer on the survivors -> phase B (stage-2
-# ring push + gate + queue insertion + optional cascade tick). The
-# single-stage cores above are untouched, so cascade-off sessions stay
-# bit-identical to the pre-cascade pipeline.
-# ---------------------------------------------------------------------------
-
-def _cascade_rates(rates, gate_fraction, xp):
-    """Split the Eq. 19 combined target drop rate r into the stage-1
-    share r1 = g*r and the stage-2 CONDITIONAL share r2 = (r-r1)/(1-r1)
-    (of the survivors), so r1 + (1-r1)*r2 == r exactly — the combined
-    realized rate tracks r and the degraded floor (already folded into
-    ``rates``) bounds the combined rate."""
-    r1 = (rates * xp.float32(gate_fraction)).astype(xp.float32)
-    r2 = ((rates - r1)
-          / xp.maximum(1.0 - r1, xp.float32(1e-9))).astype(xp.float32)
-    return r1, r2
-
-
-def _cascade_tick_core_dev(state: SessionState, min_proc: float,
-                           budget: float, gate_fraction: float,
-                           num_total: Optional[int] = None,
-                           tick_cfg: Optional[TickConfig] = None):
-    """Two-threshold tick: the combined Eq. 18-20 rate (floor + churn
-    mask applied first, as in ``_tick_core_dev``) is split across the
-    stages; each stage's threshold comes from ITS ring at ITS share —
-    both through the same O(bins) bucket machinery (the s2 geometry
-    covers the scorer's softsign range)."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    C = num_total if num_total is not None else state.threshold.shape[0]
-    p = jnp.maximum(state.proc_q, min_proc)
-    rates = jnp.clip(
-        1.0 - 1.0 / (p * C * jnp.maximum(state.fps_obs, 1e-9)),
-        0.0, 1.0).astype(jnp.float32)
-    rates = jnp.maximum(rates, state.rate_floor).astype(jnp.float32)
-    rates = jnp.where(state.active, rates, jnp.float32(0.0))
-    r1, r2 = _cascade_rates(rates, gate_fraction, jnp)
-    if tick_cfg.exact:
-        threshold = thresholds_from_lanes_dev(state.cdf_buf, state.cdf_len,
-                                              r1)
-        s2_threshold = thresholds_from_lanes_dev(state.s2_buf, state.s2_len,
-                                                 r2)
-    else:
-        threshold = thresholds_from_counts_dev(
-            state.cdf_counts, state.cdf_len, r1, tick_cfg.lo, tick_cfg.width)
-        s2_threshold = thresholds_from_counts_dev(
-            state.s2_counts, state.s2_len, r2, tick_cfg.s2_lo,
-            tick_cfg.s2_width)
-    threshold = jnp.where(state.active, threshold, jnp.float32(jnp.inf))
-    s2_threshold = jnp.where(state.active, s2_threshold,
-                             jnp.float32(jnp.inf))
-    cap = jnp.maximum((budget / p + 1e-9).astype(jnp.int32) - 1, 1)
-    q_util, q_seq, resize_ev = sq.resize_dev(state.q_util, state.q_seq, cap)
-    state = dataclasses.replace(
-        state, threshold=threshold, s2_threshold=s2_threshold,
-        queue_cap=cap.astype(jnp.int32), q_util=q_util, q_seq=q_seq)
-    return state, rates, resize_ev
-
-
-def _cascade_tick_core_host(state: SessionState, min_proc: float,
-                            budget: float, gate_fraction: float,
-                            num_total: Optional[int] = None,
-                            tick_cfg: Optional[TickConfig] = None,
-                            live: Optional[np.ndarray] = None):
-    """NumPy twin of :func:`_cascade_tick_core_dev` (in-place)."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    C = num_total if num_total is not None else state.threshold.shape[0]
-    p = np.maximum(state.proc_q, min_proc)
-    rates = np.clip(
-        1.0 - np.float32(1.0) / (p * C * np.maximum(state.fps_obs, 1e-9)),
-        0.0, 1.0).astype(np.float32)
-    rates = np.maximum(rates, state.rate_floor).astype(np.float32)
-    rates = np.where(state.active, rates, np.float32(0.0))
-    r1, r2 = _cascade_rates(rates, gate_fraction, np)
-    if tick_cfg.exact:
-        threshold = thresholds_from_lanes_host(state.cdf_buf, state.cdf_len,
-                                               r1)
-        s2_th = thresholds_from_lanes_host(state.s2_buf, state.s2_len, r2)
-    else:
-        threshold = thresholds_from_counts_host(
-            state.cdf_counts, state.cdf_len, r1, tick_cfg.lo, tick_cfg.width)
-        s2_th = thresholds_from_counts_host(
-            state.s2_counts, state.s2_len, r2, tick_cfg.s2_lo,
-            tick_cfg.s2_width)
-    state.threshold = np.where(state.active, threshold,
-                               np.float32(np.inf)).astype(np.float32)
-    state.s2_threshold = np.where(state.active, s2_th,
-                                  np.float32(np.inf)).astype(np.float32)
-    cap = np.maximum((budget / p + 1e-9).astype(np.int32) - 1, 1)
-    state.queue_cap = cap.astype(np.int32)
-    resize_ev = _resize_host_guarded(state, cap, tick_cfg.exact, live)
-    return rates, resize_ev
-
-
-@functools.partial(jax.jit, static_argnames=("update_cdf", "tick_cfg"),
-                   donate_argnames=("state",))
-def _cascade_admit_dev(state, util, present, *, update_cdf,
-                       tick_cfg=DEFAULT_TICK_CONFIG):
-    """Cascade phase A on device: stage-1 CDF push + color gate.
-    Returns (state', pass1 (C, T) bool — the frames the scorer sees)."""
-    util = util.astype(jnp.float32)
-    cdf_buf, cdf_pos, cdf_len = state.cdf_buf, state.cdf_pos, state.cdf_len
-    cdf_counts = state.cdf_counts
-    if update_cdf:
-        cdf_buf, cdf_pos, cdf_len, cdf_counts = _ring_push_dev(
-            cdf_buf, cdf_pos, cdf_len, cdf_counts, util, present,
-            tick_cfg.lo, tick_cfg.inv_width)
-    pass1 = present & ~(util < state.threshold[:, None])
-    state = dataclasses.replace(state, cdf_buf=cdf_buf, cdf_pos=cdf_pos,
-                                cdf_len=cdf_len, cdf_counts=cdf_counts)
-    return state, pass1
-
-
-def _cascade_admit_host(state, util, present, *, update_cdf,
-                        tick_cfg=DEFAULT_TICK_CONFIG):
-    """NumPy twin of :func:`_cascade_admit_dev` (in-place)."""
-    util = np.asarray(util, np.float32)
-    if update_cdf:
-        state.cdf_pos, state.cdf_len = _ring_push_host(
-            state.cdf_buf, state.cdf_pos, state.cdf_len, state.cdf_counts,
-            util, present, tick_cfg.lo, tick_cfg.inv_width)
-    return present & ~(util < state.threshold[:, None])
-
-
-def _cascade_finish_core_dev(state: SessionState, s2, present, pass1, *,
-                             do_tick: bool, min_proc: float, budget: float,
-                             gate_fraction: float,
-                             num_total: Optional[int] = None,
-                             tick_cfg: Optional[TickConfig] = None):
-    """Cascade phase B on device: stage-2 ring push (survivors only) ->
-    stage-2 gate -> queue insertion keyed by the SEMANTIC score ->
-    (optional) two-threshold tick."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    s2 = s2.astype(jnp.float32)
-    C, T = s2.shape
-    rows = jnp.arange(C)[:, None]
-    s2_buf, s2_pos, s2_len, s2_counts = _ring_push_dev(
-        state.s2_buf, state.s2_pos, state.s2_len, state.s2_counts, s2, pass1,
-        tick_cfg.s2_lo, tick_cfg.s2_inv_width)
-    shed2 = pass1 & (s2 < state.s2_threshold[:, None])
-    admit = pass1 & ~shed2
-    decisions = jnp.where(
-        admit, ADMIT,
-        jnp.where(pass1, SHED_CASCADE, SHED_ADMISSION)).astype(jnp.int8)
-    decisions = jnp.where(present, decisions, jnp.int8(-1))
-    q_util, q_seq, q_next, pushed_seq, ev_s, ev_b = sq.push_batch_dev(
-        state.q_util, state.q_seq, state.q_next_seq, s2, admit,
-        state.queue_cap)
-    # retro SHED_QUEUE flips: evicted slots were ADMIT (0) and every
-    # code is <= 3, so a scatter-max with -1 dummies is exact
-    flip = ev_b >= 0
-    decisions = decisions.at[rows, jnp.where(flip, ev_b, 0)].max(
-        jnp.where(flip, jnp.int8(SHED_QUEUE), jnp.int8(-1)))
-    state = dataclasses.replace(
-        state, s2_buf=s2_buf, s2_pos=s2_pos, s2_len=s2_len,
-        s2_counts=s2_counts, q_util=q_util, q_seq=q_seq, q_next_seq=q_next)
-    out = {
-        "decisions": decisions,
-        "pushed_seq": pushed_seq,
-        "evicted_resident": jnp.where((ev_b < 0) & (ev_s >= 0), ev_s, -1),
-        "push_evictions": (ev_s >= 0).sum(axis=-1).astype(jnp.int32),
-        "rates": jnp.zeros((C,), jnp.float32),
-        "resize_evicted": jnp.full_like(state.q_seq, -1),
-    }
-    if do_tick:
-        state, rates, resize_ev = _cascade_tick_core_dev(
-            state, min_proc, budget, gate_fraction, num_total, tick_cfg)
-        out["rates"] = rates
-        out["resize_evicted"] = resize_ev
-    return state, out
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("do_tick", "min_proc", "budget", "gate_fraction",
-                     "num_total", "tick_cfg"),
-    donate_argnames=("state",))
-def _cascade_finish_dev(state, s2, present, pass1, *, do_tick, min_proc,
-                        budget, gate_fraction, num_total=None,
-                        tick_cfg=DEFAULT_TICK_CONFIG):
-    return _cascade_finish_core_dev(
-        state, s2, present, pass1, do_tick=do_tick, min_proc=min_proc,
-        budget=budget, gate_fraction=gate_fraction, num_total=num_total,
-        tick_cfg=tick_cfg)
-
-
-def _cascade_finish_core_host(state: SessionState, s2, present, pass1, *,
-                              do_tick: bool, min_proc: float, budget: float,
-                              gate_fraction: float,
-                              num_total: Optional[int] = None,
-                              tick_cfg: Optional[TickConfig] = None):
-    """NumPy twin of :func:`_cascade_finish_core_dev` (in-place)."""
-    if tick_cfg is None:
-        tick_cfg = DEFAULT_TICK_CONFIG
-    s2 = np.asarray(s2, np.float32)
-    C, T = s2.shape
-    state.s2_pos, state.s2_len = _ring_push_host(
-        state.s2_buf, state.s2_pos, state.s2_len, state.s2_counts, s2, pass1,
-        tick_cfg.s2_lo, tick_cfg.s2_inv_width)
-    shed2 = pass1 & (s2 < state.s2_threshold[:, None])
-    admit = pass1 & ~shed2
-    decisions = np.where(
-        admit, ADMIT,
-        np.where(pass1, SHED_CASCADE, SHED_ADMISSION)).astype(np.int8)
-    decisions = np.where(present, decisions, np.int8(-1))
-    q_next, pushed_seq, ev_s, ev_b = sq.push_batch_host(
-        state.q_util, state.q_seq, state.q_next_seq, s2, admit,
-        state.queue_cap)
-    state.q_next_seq = q_next
-    r, i = np.nonzero(ev_b >= 0)
-    decisions[r, ev_b[r, i]] = SHED_QUEUE
-    out = {
-        "decisions": decisions,
-        "pushed_seq": pushed_seq,
-        "evicted_resident": np.where((ev_b < 0) & (ev_s >= 0), ev_s, -1),
-        "push_evictions": (ev_s >= 0).sum(axis=-1).astype(np.int32),
-        "rates": np.zeros((C,), np.float32),
-        "resize_evicted": np.full_like(state.q_seq, -1),
-    }
-    if do_tick:
-        rates, resize_ev = _cascade_tick_core_host(
-            state, min_proc, budget, gate_fraction, num_total, tick_cfg)
-        out["rates"] = rates
-        out["resize_evicted"] = resize_ev
-    return state, out
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("min_proc", "budget", "gate_fraction", "num_total",
-                     "tick_cfg"),
-    donate_argnames=("state",))
-def _cascade_tick_dev(state, *, min_proc, budget, gate_fraction,
-                      num_total=None, tick_cfg=DEFAULT_TICK_CONFIG):
-    return _cascade_tick_core_dev(state, min_proc, budget, gate_fraction,
-                                  num_total, tick_cfg)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("update_cdf", "do_tick", "min_proc", "budget",
-                     "num_total", "tick_cfg"),
-    donate_argnames=("state",))
-def _control_step_dev(state, util, *, update_cdf, do_tick, min_proc, budget,
-                      num_total=None, tick_cfg=DEFAULT_TICK_CONFIG):
-    return _control_core_dev(state, util, None, update_cdf=update_cdf,
-                             do_tick=do_tick, min_proc=min_proc,
-                             budget=budget, num_total=num_total,
-                             tick_cfg=tick_cfg)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("update_cdf", "do_tick", "min_proc", "budget",
-                     "num_total", "tick_cfg"),
-    donate_argnames=("state",))
-def _control_masked_dev(state, util, present, *, update_cdf, do_tick,
-                        min_proc, budget, num_total=None,
-                        tick_cfg=DEFAULT_TICK_CONFIG):
-    return _control_core_dev(state, util, present, update_cdf=update_cdf,
-                             do_tick=do_tick, min_proc=min_proc,
-                             budget=budget, num_total=num_total,
-                             tick_cfg=tick_cfg)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("hue_ranges", "bs", "bv", "alpha", "fg_threshold",
-                     "use_fg", "bg_valid", "op", "impl", "interpret",
-                     "update_cdf", "do_tick", "min_proc", "budget",
-                     "num_total", "tick_cfg"),
-    donate_argnames=("state",))
-def _serve_step_dev(state, frames, M_pos, norm, *, hue_ranges, bs, bv,
-                    alpha, fg_threshold, use_fg, bg_valid, op, impl,
-                    interpret, update_cdf, do_tick, min_proc, budget,
-                    num_total=None, tick_cfg=DEFAULT_TICK_CONFIG):
-    """The tentpole device program: fused ingest -> CDF push ->
-    admission -> queue selection -> threshold/queue-size control, ONE
-    jitted dispatch with the state pytree's buffers donated. Utilities
-    are produced and consumed on device; only the compact decision /
-    eviction arrays and the (small) state leaves read by the host ever
-    transfer."""
-    bg0 = state.bg if bg_valid else jnp.zeros_like(state.bg)
-    gain0 = state.gain if bg_valid else jnp.ones_like(state.gain)
-    # the Pallas ingest names its own device work: shed.stage (the
-    # planar relayout of the frames) and shed.score (the kernel)
-    _, _, _, util, bg, gain = ingest_core(
-        frames, bg0, gain0, M_pos, norm, hue_ranges=hue_ranges, bs=bs,
-        bv=bv, alpha=alpha, threshold=fg_threshold, use_fg=use_fg,
-        bg_valid=bg_valid, op=op, impl=impl, interpret=interpret)
-    state = dataclasses.replace(state, bg=bg, gain=gain,
-                                bg_valid=jnp.asarray(True))
-    with jax.named_scope("shed.control"):
-        return _control_core_dev(state, util, None, update_cdf=update_cdf,
-                                 do_tick=do_tick, min_proc=min_proc,
-                                 budget=budget, num_total=num_total,
-                                 tick_cfg=tick_cfg)
-
-
-@jax.jit
-def _flatten_frames(frames):
-    """(C, T, H, W, 3) -> (C, T, H*W, 3) on the device: the frames'
-    first relayout, a program of its own under ``shed.stage``."""
-    C, T, H, W, _ = frames.shape
-    with jax.named_scope("shed.stage"):
-        return frames.reshape(C, T, H * W, 3)
-
-
-@functools.partial(jax.jit, static_argnames=("update_cdf", "tick_cfg"),
-                   donate_argnames=("state",))
-def _offer_dev(state, cam, u, *, update_cdf, tick_cfg=DEFAULT_TICK_CONFIG):
-    """Single-frame admission on device: scalar CDF push + threshold
-    compare + single queue push for one camera lane."""
-    C, W = state.cdf_buf.shape
-    B = state.cdf_counts.shape[1]
-    u = jnp.asarray(u, jnp.float32)
-    cdf_buf, cdf_pos, cdf_len = state.cdf_buf, state.cdf_pos, state.cdf_len
-    cdf_counts = state.cdf_counts
-    if update_cdf:
-        old = cdf_buf[cam, cdf_pos[cam]]
-        evict = cdf_pos[cam] < cdf_len[cam]
-        cdf_counts = cdf_counts.at[
-            cam, bucket_index_dev(old, tick_cfg.lo, tick_cfg.inv_width,
-                                  B)].add(-evict.astype(jnp.int32))
-        cdf_counts = cdf_counts.at[
-            cam, bucket_index_dev(u, tick_cfg.lo, tick_cfg.inv_width,
-                                  B)].add(1)
-        cdf_buf = cdf_buf.at[cam, cdf_pos[cam]].set(u)
-        cdf_pos = cdf_pos.at[cam].set((cdf_pos[cam] + 1) % W)
-        cdf_len = cdf_len.at[cam].set(jnp.minimum(cdf_len[cam] + 1, W))
-    shed = u < state.threshold[cam]
-    do_push = (jnp.arange(C) == cam) & ~shed
-    q_util, q_seq, q_next, pushed_seq, evicted_seq, inc_ev = sq.push_one_dev(
-        state.q_util, state.q_seq, state.q_next_seq,
-        jnp.full((C,), u, jnp.float32), do_push, state.queue_cap)
-    code = jnp.where(shed, jnp.int8(SHED_ADMISSION),
-                     jnp.where(inc_ev[cam], jnp.int8(SHED_QUEUE),
-                               jnp.int8(ADMIT)))
-    state = dataclasses.replace(
-        state, cdf_buf=cdf_buf, cdf_pos=cdf_pos, cdf_len=cdf_len,
-        cdf_counts=cdf_counts, q_util=q_util, q_seq=q_seq, q_next_seq=q_next)
-    return state, code, pushed_seq[cam], evicted_seq[cam]
-
-
-@functools.partial(jax.jit, donate_argnames=("state",))
-def _pop_any_dev(state):
-    q_util, q_seq, cam, seq = sq.pop_best_dev(state.q_util, state.q_seq)
-    return dataclasses.replace(state, q_util=q_util, q_seq=q_seq), cam, seq
-
-
-@functools.partial(jax.jit, donate_argnames=("state",))
-def _pop_cam_dev(state, cam):
-    q_util, q_seq, cam, seq = sq.pop_best_dev(state.q_util, state.q_seq, cam)
-    return dataclasses.replace(state, q_util=q_util, q_seq=q_seq), cam, seq
-
-
-@functools.partial(jax.jit, static_argnames=("k",),
-                   donate_argnames=("state",))
-def _pop_topk_dev(state, *, k):
-    q_util, q_seq, cams, seqs = sq.pop_topk_dev(state.q_util, state.q_seq, k)
-    return (dataclasses.replace(state, q_util=q_util, q_seq=q_seq),
-            cams, seqs)
-
-
-@functools.partial(jax.jit, static_argnames=("k",),
-                   donate_argnames=("state",))
-def _pop_topk_masked_dev(state, rows, *, k):
-    q_util, q_seq, cams, seqs = sq.pop_topk_dev(state.q_util, state.q_seq, k,
-                                                rows)
-    return (dataclasses.replace(state, q_util=q_util, q_seq=q_seq),
-            cams, seqs)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("min_proc", "budget", "num_total",
-                                    "tick_cfg"),
-                   donate_argnames=("state",))
-def _tick_dev(state, *, min_proc, budget, num_total=None,
-              tick_cfg=DEFAULT_TICK_CONFIG):
-    return _tick_core_dev(state, min_proc, budget, num_total, tick_cfg)
 
 
 class ShedSession:
@@ -1033,11 +229,18 @@ class ShedSession:
                  ) -> None:
         if num_cameras < 1:
             raise ValueError("num_cameras must be >= 1")
+        # serve= names the one control plane; the keyword stays for
+        # callers that still pass serve="device"
+        if serve not in (None, "device"):
+            raise ValueError(
+                f"serve={serve!r}: the NumPy serve path was removed; the "
+                f"session runs one jitted control plane on every backend "
+                f"(serve=None or 'device')")
         self.query = query
         # host spans and counters of the serve path (session.*); None
         # runs every span site through one shared no-op context
         self.metrics = metrics
-        self._span = metrics.span if metrics is not None else _no_span
+        self._span = metrics.span if metrics is not None else no_span
         self.num_cameras = int(num_cameras)
         self.model = model
         # semantic cascade (repro.cascade.Cascade, duck-typed: .scorer /
@@ -1072,22 +275,10 @@ class ShedSession:
         self.fleet_aggregate = bool(fleet_aggregate)
         self.last_fleet_stats: Optional[Dict[str, float]] = None
         if shard_cameras:
-            from repro.core import fleet as _fleet
-            if serve == "host":
-                raise ValueError(
-                    "shard_cameras requires serve='device' (the sharded "
-                    "serve plane is a shard_map'd device program)")
-            serve = "device"
-            self.mesh = mesh if mesh is not None else _fleet.fleet_mesh()
-            self._cam_axis = _fleet.camera_axis(self.mesh, self.num_cameras)
-            self._frame_sharding = _fleet.frame_sharding(self.mesh,
-                                                         self._cam_axis)
-        if serve is None:
-            serve = "device" if jax.default_backend() == "tpu" else "host"
-        if serve not in ("host", "device"):
-            raise ValueError(f"unknown serve impl {serve!r}")
-        self.serve = serve
-        self._xp = jnp if serve == "device" else np
+            self.mesh = mesh if mesh is not None else fleet.fleet_mesh()
+            self._cam_axis = fleet.camera_axis(self.mesh, self.num_cameras)
+            self._frame_sharding = fleet.frame_sharding(self.mesh,
+                                                        self._cam_axis)
         self._queue_size = int(queue_size)
         # quantile-tick mode: O(bins) incremental bucket counts by
         # default, exact (C, W) sort behind exact_tick=True. One
@@ -1112,13 +303,12 @@ class ShedSession:
         self.state = SessionState.fresh(
             num_cameras, npix, cdf_window=cdf_window, fps=query.fps,
             queue_size=queue_size, queue_capacity=queue_capacity,
-            s2_window=s2_window, quantile_bins=bins, xp=self._xp)
+            s2_window=s2_window, quantile_bins=bins)
         if self.mesh is not None:
-            from repro.core import fleet as _fleet
-            self._shardings = _fleet.state_shardings(
+            self._shardings = fleet.state_shardings(
                 self.mesh, self.state, self._cam_axis)
-            self.state = _fleet.shard_state(self.state, self.mesh,
-                                            self._cam_axis)
+            self.state = fleet.shard_state(self.state, self.mesh,
+                                           self._cam_axis)
         # host copy of the latency lanes, equal to the fresh state's
         # zeros (see report_backend_latency and _put_latency_lanes)
         self._proc_q = np.zeros((self.num_cameras,), np.float32)
@@ -1202,13 +392,9 @@ class ShedSession:
         return drained
 
     def _write_lane(self, name: str, lane: int, value: Any) -> None:
-        """Set one lane row of a state leaf (host in-place; device
-        functional update, re-placed on the fleet sharding when one
-        exists)."""
+        """Set one lane row of a state leaf (a functional update,
+        re-placed on the fleet sharding when one exists)."""
         st = self.state
-        if self.serve == "host":
-            getattr(st, name)[lane] = value
-            return
         arr = getattr(st, name).at[lane].set(value)
         if self._shardings is not None:
             arr = jax.device_put(arr, self._shardings[name])
@@ -1261,8 +447,7 @@ class ShedSession:
         if not 0.0 <= f <= 1.0:
             raise ValueError(f"rate floor {f} outside [0, 1]")
         self._rate_floor_host = f
-        xp = self._xp
-        val = xp.full((self.num_cameras,), f, xp.float32)
+        val = jnp.full((self.num_cameras,), f, jnp.float32)
         if self._shardings is not None:
             val = jax.device_put(val, self._shardings["rate_floor"])
         self.state.rate_floor = val
@@ -1300,16 +485,9 @@ class ShedSession:
         us = np.broadcast_to(us, (self.num_cameras, us.size))
         st = self.state
         cfg = self._tick_cfg
-        if self.serve == "device":
-            buf, pos, ln, counts = _ring_push_dev(
-                st.cdf_buf, st.cdf_pos, st.cdf_len, st.cdf_counts,
-                jnp.asarray(us), None, cfg.lo, cfg.inv_width)
-            st.cdf_buf, st.cdf_pos, st.cdf_len = buf, pos, ln
-            st.cdf_counts = counts
-        else:
-            st.cdf_pos, st.cdf_len = _ring_push_host(
-                st.cdf_buf, st.cdf_pos, st.cdf_len, st.cdf_counts, us, None,
-                cfg.lo, cfg.inv_width)
+        st.cdf_buf, st.cdf_pos, st.cdf_len, st.cdf_counts = ring_push(
+            st.cdf_buf, st.cdf_pos, st.cdf_len, st.cdf_counts,
+            jnp.asarray(us), None, cfg.lo, cfg.inv_width)
 
     # -- fused ingest --------------------------------------------------------
 
@@ -1334,7 +512,7 @@ class ShedSession:
                 raise ValueError(
                     f"frame size {n} px does not match carried background "
                     f"state {st.bg.shape}")
-            bg = self._xp.zeros((self.num_cameras, n), self._xp.float32)
+            bg = jnp.zeros((self.num_cameras, n), jnp.float32)
             if self._shardings is not None:
                 bg = jax.device_put(bg, self._shardings["bg"])
             st.bg = bg
@@ -1359,10 +537,7 @@ class ShedSession:
             op=q.op, bs=q.bs, bv=q.bv,
             impl=impl if impl is not None else self.impl,
             interpret=interpret if interpret is not None else self.interpret)
-        xp = self._xp
-        st.bg = xp.asarray(state_out.bg, xp.float32)
-        st.gain = xp.asarray(state_out.gain, xp.float32).reshape(-1)
-        st.bg_valid = xp.asarray(True)
+        self._set_ingest_lanes(state_out)
         return IngestResult(
             pf=np.asarray(pf), hue_fraction=np.asarray(hf),
             utility=None if util is None else np.asarray(util))
@@ -1373,20 +548,23 @@ class ShedSession:
         return IngestState(bg=self.state.bg, gain=self.state.gain)
 
     def set_ingest_state(self, state: Optional[IngestState]) -> None:
-        xp = self._xp
         if state is None:
-            self.state.bg_valid = xp.asarray(False)
+            self.state.bg_valid = jnp.asarray(False)
             return
-        bg = xp.asarray(state.bg, xp.float32)
+        bg = jnp.asarray(state.bg, jnp.float32)
         if bg.ndim == 1:
             bg = bg[None]
         if bg.shape[0] != self.num_cameras:
             raise ValueError(
                 f"state has {bg.shape[0]} camera lanes, session has "
                 f"{self.num_cameras}")
-        self.state.bg = bg
-        self.state.gain = xp.asarray(state.gain, xp.float32).reshape(-1)
-        self.state.bg_valid = xp.asarray(True)
+        self._set_ingest_lanes(IngestState(bg=bg, gain=state.gain))
+
+    def _set_ingest_lanes(self, state: IngestState) -> None:
+        st = self.state
+        st.bg = jnp.asarray(state.bg, jnp.float32)
+        st.gain = jnp.asarray(state.gain, jnp.float32).reshape(-1)
+        st.bg_valid = jnp.asarray(True)
 
     # -- the fused serve step (tentpole) -------------------------------------
 
@@ -1404,10 +582,8 @@ class ShedSession:
         Give either ``frames`` — a (C, T, H, W, 3) batch scored by the
         fused ingest kernel inside the same dispatch (requires a
         trained model) — or precomputed ``utilities`` (C, T) to run the
-        control plane alone. Under ``serve="device"`` the frames form
-        is ONE jitted XLA program with donated state buffers; under
-        ``serve="host"`` scoring is the jitted ingest oracle and the
-        control plane is its vectorized-NumPy twin.
+        control plane alone. The frames form is ONE jitted XLA program
+        with donated state buffers.
 
         With a session ``cascade``, a frames step additionally runs the
         stage-2 semantic scorer over the color-gate survivors (batched,
@@ -1425,11 +601,11 @@ class ShedSession:
 
         With a session ``metrics`` registry each call is a span
         ``session.step`` and counts ``session.steps`` and its offered
-        frames in ``session.frames``. On the ``serve="device"`` frames
-        path its phases are the spans ``session.stage`` (the frames'
-        checks on the host), ``session.put`` (the hand-off to the device
-        and, on one device, the first relayout), ``session.dispatch``,
-        ``session.readback`` and ``session.absorb`` (host bookkeeping).
+        frames in ``session.frames``. On the frames path its phases are
+        the spans ``session.stage`` (the frames' checks on the host),
+        ``session.put`` (the hand-off to the device and, on one device,
+        the first relayout), ``session.dispatch``, ``session.readback``
+        and ``session.absorb`` (host bookkeeping).
         That path hands the frames to the device in the camera's dtype
         (uint8 as it comes) and converts them to float32 there. On a
         camera mesh each camera's frames go straight to the device that
@@ -1467,44 +643,36 @@ class ShedSession:
             if self.model is None:
                 raise ValueError("step(frames=...) needs a trained model "
                                  "(call fit() or pass model=)")
-            if self.serve == "device":
-                return self._serve_frames(frames, items, tick, impl,
-                                          interpret, kw)
-            frames = self._check_frames(frames)
-            if frames.shape[1] == 0:
-                raise ValueError("empty frame batch")
-            util = self.ingest(frames, impl=impl,
-                               interpret=interpret).utility
-        else:
-            util = sq.flush_subnormal(utilities)
-            if util.ndim == 1:
-                util = util[None]
-            if util.shape[0] != self.num_cameras:
-                raise ValueError(
-                    f"expected ({self.num_cameras}, T) utilities, "
-                    f"got {util.shape}")
-            if util.shape[1] == 0:
-                raise ValueError("empty utility batch")
-        if self.serve == "device":
-            if self.mesh is not None:
-                from repro.core import fleet as _fleet
-                self.state, out, agg = _fleet.control_step(
-                    self.state, jnp.asarray(util, jnp.float32),
-                    mesh=self.mesh, axis=self._cam_axis,
-                    aggregate=self.fleet_aggregate, **kw)
-                return self._absorb_control(out, items, tick, agg=agg)
-            self.state, out = _control_step_dev(
-                self.state, jnp.asarray(util, jnp.float32), **kw)
-        else:
-            self.state, out = _control_core_host(
-                self.state, util, None, **kw)
+            return self._serve_frames(frames, items, tick, impl, interpret,
+                                      kw)
+        util = jnp.asarray(self._check_utilities(utilities))
+        if self.mesh is not None:
+            self.state, out, agg = fleet.control_step(
+                self.state, util, mesh=self.mesh, axis=self._cam_axis,
+                aggregate=self.fleet_aggregate, **kw)
+            return self._absorb_control(out, items, tick, agg=agg)
+        self.state, out = _control_step_dev(self.state, util, **kw)
         return self._absorb_control(out, items, tick)
+
+    def _check_utilities(self, utilities) -> np.ndarray:
+        """A (C, T) float32 utility batch, flushed of subnormals (a
+        (T,) vector is accepted for single-camera sessions)."""
+        util = sq.flush_subnormal(utilities)
+        if util.ndim == 1:
+            util = util[None]
+        if util.shape[0] != self.num_cameras:
+            raise ValueError(
+                f"expected ({self.num_cameras}, T) utilities, "
+                f"got {util.shape}")
+        if util.shape[1] == 0:
+            raise ValueError("empty utility batch")
+        return util
 
     def _serve_frames(self, frames, items, tick, impl, interpret,
                       kw) -> StepResult:
-        """The ``serve="device"`` frames step: the frames to the device
-        in the camera's dtype, converted to float32 there, then ONE
-        fused serve-step dispatch (see ``step`` for its spans)."""
+        """The frames step: the frames to the device in the camera's
+        dtype, converted to float32 there, then ONE fused serve-step
+        dispatch (see ``step`` for its spans)."""
         span = self._span
         with span("session.stage"):
             frames = self._validate_frames(np.asarray(frames))
@@ -1535,8 +703,7 @@ class ShedSession:
                            else self.interpret))
             agg = None
             if self.mesh is not None:
-                from repro.core import fleet as _fleet
-                self.state, out, agg = _fleet.serve_step(
+                self.state, out, agg = fleet.serve_step(
                     self.state, frames, M_pos, norm, mesh=self.mesh,
                     axis=self._cam_axis,
                     aggregate=self.fleet_aggregate, **ingest_kw, **kw)
@@ -1575,33 +742,17 @@ class ShedSession:
                 interpret=(interpret if interpret is not None
                            else self.interpret),
                 with_bbox=True)
-            xp = self._xp
-            st.bg = xp.asarray(state_out.bg, xp.float32)
-            st.gain = xp.asarray(state_out.gain, xp.float32).reshape(-1)
-            st.bg_valid = xp.asarray(True)
+            self._set_ingest_lanes(state_out)
             util = np.asarray(util, np.float32)
             bbox = np.asarray(bbox, np.int32)
         else:
-            util = sq.flush_subnormal(utilities)
-            if util.ndim == 1:
-                util = util[None]
-            if util.shape[0] != self.num_cameras:
-                raise ValueError(
-                    f"expected ({self.num_cameras}, T) utilities, "
-                    f"got {util.shape}")
-            if util.shape[1] == 0:
-                raise ValueError("empty utility batch")
+            util = self._check_utilities(utilities)
         present = np.ones(util.shape, bool)
         # phase A: stage-1 CDF push + color gate
-        if self.serve == "device":
-            self.state, pass1 = _cascade_admit_dev(
-                self.state, jnp.asarray(util), jnp.asarray(present),
-                update_cdf=self.update_cdf_online, tick_cfg=self._tick_cfg)
-            pass1 = np.asarray(pass1)
-        else:
-            pass1 = _cascade_admit_host(
-                self.state, util, present,
-                update_cdf=self.update_cdf_online, tick_cfg=self._tick_cfg)
+        self.state, pass1 = _cascade_admit_dev(
+            self.state, jnp.asarray(util), jnp.asarray(present),
+            update_cdf=self.update_cdf_online, tick_cfg=self._tick_cfg)
+        pass1 = np.asarray(pass1)
         # stage-2 scoring — ONE batched scorer call over the survivors
         if s2_utilities is not None:
             s2 = np.asarray(s2_utilities, np.float32).reshape(util.shape)
@@ -1615,13 +766,9 @@ class ShedSession:
                     np.float32)
         s2 = sq.flush_subnormal(s2)
         # phase B: stage-2 ring/gate + queue insertion + optional tick
-        if self.serve == "device":
-            self.state, out = _cascade_finish_dev(
-                self.state, jnp.asarray(s2), jnp.asarray(present),
-                jnp.asarray(pass1), **kwt)
-        else:
-            self.state, out = _cascade_finish_core_host(
-                self.state, s2, present, pass1, **kwt)
+        self.state, out = _cascade_finish_dev(
+            self.state, jnp.asarray(s2), jnp.asarray(present),
+            jnp.asarray(pass1), **kwt)
         return self._absorb_control(out, items, tick, s2_scores=s2)
 
     def _absorb_control(self, out: Dict[str, Any],
@@ -1629,7 +776,7 @@ class ShedSession:
                         ticked: bool,
                         s2_scores: Optional[np.ndarray] = None,
                         agg: Optional[Dict[str, Any]] = None,
-                        span=_no_span) -> StepResult:
+                        span=no_span) -> StepResult:
         """Fold a control step's compact outputs into host bookkeeping:
         stats, payload registry, per-camera counters. Every read of the
         outputs (the span ``session.readback`` where ``span`` times
@@ -1648,10 +795,10 @@ class ShedSession:
             if agg is not None:
                 self._absorb_fleet(agg)
         with span("session.absorb"):
-            return self._absorb_host(decisions, pushed_seq, ev_res, push_ev,
-                                     rates, rz, items, s2_scores)
+            return self._absorb(decisions, pushed_seq, ev_res, push_ev,
+                                rates, rz, items, s2_scores)
 
-    def _absorb_host(self, decisions, pushed_seq, ev_res, push_ev, rates,
+    def _absorb(self, decisions, pushed_seq, ev_res, push_ev, rates,
                      rz, items, s2_scores) -> StepResult:
         C = decisions.shape[0]
         offered = decisions >= 0
@@ -1697,8 +844,7 @@ class ShedSession:
         """Keep the latest psum aggregate tree (host view) when the
         sharded step computed one."""
         if self.fleet_aggregate:
-            from repro.core import fleet as _fleet
-            self.last_fleet_stats = _fleet.derive_fleet_stats(
+            self.last_fleet_stats = fleet.derive_fleet_stats(
                 agg, self.num_cameras)
 
     def fleet_stats(self) -> Dict[str, float]:
@@ -1709,11 +855,10 @@ class ShedSession:
             raise ValueError("fleet_stats() needs a camera-sharded "
                              "session (open_session(..., shard_cameras"
                              "=True))")
-        from repro.core import fleet as _fleet
         self._put_latency_lanes()
-        return _fleet.aggregates(self.state, mesh=self.mesh,
-                                 axis=self._cam_axis,
-                                 num_cameras=self.num_cameras)
+        return fleet.aggregates(self.state, mesh=self.mesh,
+                                axis=self._cam_axis,
+                                num_cameras=self.num_cameras)
 
     # -- admission + queues --------------------------------------------------
 
@@ -1751,36 +896,10 @@ class ShedSession:
         u = np.float32(sq.flush_subnormal(utility))
         self.stats.offered += 1
         self.per_camera_offered[c] += 1
-        st = self.state
-        if self.serve == "device":
-            self.state, code, pushed, evicted = _offer_dev(
-                st, c, u, update_cdf=self.update_cdf_online,
-                tick_cfg=self._tick_cfg)
-            code, pushed, evicted = int(code), int(pushed), int(evicted)
-        else:
-            if self.update_cdf_online:
-                cfg = self._tick_cfg
-                W = st.cdf_buf.shape[1]
-                B = st.cdf_counts.shape[1]
-                p = int(st.cdf_pos[c])
-                if p < int(st.cdf_len[c]):     # overwriting a live slot
-                    st.cdf_counts[c, int(bucket_index_host(
-                        st.cdf_buf[c, p], cfg.lo, cfg.inv_width, B))] -= 1
-                st.cdf_counts[c, int(bucket_index_host(
-                    u, cfg.lo, cfg.inv_width, B))] += 1
-                st.cdf_buf[c, p] = u
-                st.cdf_pos[c] = (p + 1) % W
-                st.cdf_len[c] = min(int(st.cdf_len[c]) + 1, W)
-            if u < st.threshold[c]:
-                code, pushed, evicted = SHED_ADMISSION, -1, -1
-            else:
-                do = np.arange(self.num_cameras) == c
-                st.q_next_seq, ps, es, ie = sq.push_one_host(
-                    st.q_util, st.q_seq, st.q_next_seq,
-                    np.full((self.num_cameras,), u, np.float32), do,
-                    st.queue_cap)
-                pushed, evicted = int(ps[c]), int(es[c])
-                code = SHED_QUEUE if ie[c] else ADMIT
+        self.state, code, pushed, evicted = _offer_dev(
+            self.state, c, u, update_cdf=self.update_cdf_online,
+            tick_cfg=self._tick_cfg)
+        code, pushed, evicted = int(code), int(pushed), int(evicted)
         if code == SHED_ADMISSION:
             self.stats.dropped_admission += 1
             self.per_camera_dropped[c] += 1
@@ -1835,37 +954,28 @@ class ShedSession:
                   min_proc=self.min_proc, budget=self._budget,
                   num_total=self._num_active, tick_cfg=self._tick_cfg)
         agg = None
-        if self.serve == "device":
-            if self.mesh is not None:
-                from repro.core import fleet as _fleet
-                self.state, out, agg = _fleet.control_step(
-                    self.state, jnp.asarray(util), jnp.asarray(present),
-                    mesh=self.mesh, axis=self._cam_axis,
-                    aggregate=self.fleet_aggregate, **kw)
-            else:
-                self.state, out = _control_masked_dev(
-                    self.state, jnp.asarray(util), jnp.asarray(present), **kw)
+        if self.mesh is not None:
+            self.state, out, agg = fleet.control_step(
+                self.state, jnp.asarray(util), jnp.asarray(present),
+                mesh=self.mesh, axis=self._cam_axis,
+                aggregate=self.fleet_aggregate, **kw)
         else:
-            self.state, out = _control_core_host(
-                self.state, util, present, **kw)
+            self.state, out = _control_masked_dev(
+                self.state, jnp.asarray(util), jnp.asarray(present), **kw)
         res = self._absorb_control(out, batch_items, ticked=False, agg=agg)
         codes = [""] * len(items)
         for (c, t), i in slot_of.items():
-            codes[i] = _DECISION_NAMES[int(res.decisions[c, t])]
+            codes[i] = DECISION_NAMES[int(res.decisions[c, t])]
         return codes
 
     def next_frame(self, cam: Optional[int] = None) -> Optional[Any]:
         """Transmission control: send the best queued frame — of one
         camera, or (default) the best across the whole array."""
-        st = self.state
-        if self.serve == "device":
-            if cam is None:
-                self.state, c, seqv = _pop_any_dev(st)
-            else:
-                self.state, c, seqv = _pop_cam_dev(st, int(cam))
-            c, seqv = int(c), int(seqv)
+        if cam is None:
+            self.state, c, seqv = _pop_any_dev(self.state)
         else:
-            c, seqv = sq.pop_best_host(st.q_util, st.q_seq, cam)
+            self.state, c, seqv = _pop_cam_dev(self.state, int(cam))
+        c, seqv = int(c), int(seqv)
         if seqv < 0:
             return None
         self._depths[c] -= 1
@@ -1893,26 +1003,21 @@ class ShedSession:
                 rows = np.zeros((self.num_cameras,), bool)
                 rows[[int(c) for c in cams]] = True
             st = self.state
-            if self.serve == "device":
-                if self.mesh is not None:
-                    from repro.core import fleet as _fleet
-                    # the registry goes along only when there is one: an
-                    # unmetered pop keeps pop_topk's plain keywords
-                    metered = ({} if self.metrics is None
-                               else {"metrics": self.metrics})
-                    self.state, pc, ps = _fleet.pop_topk(
-                        st, mesh=self.mesh, axis=self._cam_axis, k=int(k),
-                        rows=None if rows is None else jnp.asarray(rows),
-                        **metered)
-                elif rows is None:
-                    self.state, pc, ps = _pop_topk_dev(st, k=int(k))
-                else:
-                    self.state, pc, ps = _pop_topk_masked_dev(
-                        st, jnp.asarray(rows), k=int(k))
-                pc, ps = np.asarray(pc), np.asarray(ps)
+            if self.mesh is not None:
+                # the registry goes along only when there is one: an
+                # unmetered pop keeps pop_topk's plain keywords
+                metered = ({} if self.metrics is None
+                           else {"metrics": self.metrics})
+                self.state, pc, ps = fleet.pop_topk(
+                    st, mesh=self.mesh, axis=self._cam_axis, k=int(k),
+                    rows=None if rows is None else jnp.asarray(rows),
+                    **metered)
+            elif rows is None:
+                self.state, pc, ps = _pop_topk_dev(st, k=int(k))
             else:
-                pc, ps = sq.pop_topk_host(st.q_util, st.q_seq, int(k),
-                                          rows=rows)
+                self.state, pc, ps = _pop_topk_masked_dev(
+                    st, jnp.asarray(rows), k=int(k))
+            pc, ps = np.asarray(pc), np.asarray(ps)
             items: List[Any] = []
             for c, s in zip(pc.tolist(), ps.tolist()):
                 if s < 0:               # -1 padding: pool drained
@@ -1991,13 +1096,9 @@ class ShedSession:
     def _set_latency_lanes(self, proc_q: np.ndarray,
                            proc_seen: np.ndarray) -> None:
         """Take new host latency lanes (fresh arrays, never written in
-        place): under ``serve="host"`` they are the state's own lanes,
-        on a device they wait for ``_put_latency_lanes``."""
+        place); they wait for ``_put_latency_lanes``."""
         self._proc_q, self._proc_seen = proc_q, proc_seen
-        if self.serve == "host":
-            self.state.proc_q, self.state.proc_seen = proc_q, proc_seen
-        else:
-            self._proc_dirty = True
+        self._proc_dirty = True
 
     def _put_latency_lanes(self) -> None:
         """Upload the host latency lanes if a report or a lane reset
@@ -2026,17 +1127,17 @@ class ShedSession:
     def report_ingress_fps(self, fps: float, cam: Optional[int] = None) -> None:
         """Observed ingress rate: per camera, or an aggregate rate split
         evenly across the array's lanes."""
-        st, xp = self.state, self._xp
+        st = self.state
         if cam is None:
-            x = xp.full((self.num_cameras,), float(fps) / self.num_cameras)
-            upd = xp.ones((self.num_cameras,), bool)
+            x = jnp.full((self.num_cameras,), float(fps) / self.num_cameras)
+            upd = jnp.ones((self.num_cameras,), bool)
         else:
-            x = xp.where(xp.arange(self.num_cameras) == cam, float(fps),
-                         st.fps_obs)
-            upd = xp.arange(self.num_cameras) == cam
+            x = jnp.where(jnp.arange(self.num_cameras) == cam, float(fps),
+                          st.fps_obs)
+            upd = jnp.arange(self.num_cameras) == cam
         ew = st.fps_obs + self.ewma_alpha * (x - st.fps_obs)
-        st.fps_obs = xp.where(upd, xp.where(st.fps_seen, ew, x),
-                              st.fps_obs).astype(xp.float32)
+        st.fps_obs = jnp.where(upd, jnp.where(st.fps_seen, ew, x),
+                               st.fps_obs).astype(jnp.float32)
         st.fps_seen = st.fps_seen | upd
 
     def tick(self) -> Dict[str, Any]:
@@ -2044,37 +1145,17 @@ class ShedSession:
         (Eq. 20) from the current metric lanes — one batched quantile +
         queue resize over all C camera lanes."""
         self._put_latency_lanes()
+        kw = dict(min_proc=self.min_proc, budget=self._budget,
+                  num_total=self._num_active, tick_cfg=self._tick_cfg)
         if self.cascade is not None:
-            if self.serve == "device":
-                self.state, rates, resize_ev = _cascade_tick_dev(
-                    self.state, min_proc=self.min_proc,
-                    budget=self._budget,
-                    gate_fraction=self._gate_fraction,
-                    num_total=self._num_active,
-                    tick_cfg=self._tick_cfg)
-                rates, resize_ev = np.asarray(rates), np.asarray(resize_ev)
-            else:
-                rates, resize_ev = _cascade_tick_core_host(
-                    self.state, self.min_proc, self._budget,
-                    self._gate_fraction, num_total=self._num_active,
-                    tick_cfg=self._tick_cfg, live=self._depths)
-        elif self.serve == "device":
-            if self.mesh is not None:
-                from repro.core import fleet as _fleet
-                self.state, rates, resize_ev = _fleet.tick(
-                    self.state, mesh=self.mesh, axis=self._cam_axis,
-                    num_total=self._num_active, min_proc=self.min_proc,
-                    budget=self._budget, tick_cfg=self._tick_cfg)
-            else:
-                self.state, rates, resize_ev = _tick_dev(
-                    self.state, min_proc=self.min_proc, budget=self._budget,
-                    num_total=self._num_active, tick_cfg=self._tick_cfg)
-            rates, resize_ev = np.asarray(rates), np.asarray(resize_ev)
+            self.state, rates, resize_ev = _cascade_tick_dev(
+                self.state, gate_fraction=self._gate_fraction, **kw)
+        elif self.mesh is not None:
+            self.state, rates, resize_ev = fleet.tick(
+                self.state, mesh=self.mesh, axis=self._cam_axis, **kw)
         else:
-            rates, resize_ev = _tick_core_host(
-                self.state, self.min_proc, self._budget,
-                num_total=self._num_active, tick_cfg=self._tick_cfg,
-                live=self._depths)
+            self.state, rates, resize_ev = _tick_dev(self.state, **kw)
+        rates, resize_ev = np.asarray(rates), np.asarray(resize_ev)
         cnt = (resize_ev >= 0).sum(axis=1)
         self.stats.dropped_queue += int(cnt.sum())
         self.per_camera_dropped += cnt
@@ -2174,18 +1255,14 @@ class ShedSession:
         # numbers restart/collide across checkpoints)
         self._payloads = [{} for _ in range(self.num_cameras)]
         for k in self.state.as_dict():
-            # host lanes must be writable copies (restored buffers can be
-            # read-only views of device arrays)
             if self._shardings is not None:
                 # re-shard the global (C, ...) checkpoint arrays onto
                 # THIS session's mesh — which may hold a different
                 # device count than the mesh that saved them
                 leaf = jax.device_put(np.asarray(out[k]),
                                       self._shardings[k])
-            elif self.serve == "device":
-                leaf = jnp.asarray(out[k])
             else:
-                leaf = np.array(out[k])
+                leaf = jnp.asarray(out[k])
             setattr(self.state, k, leaf)
         self._set_latency_lanes(np.array(out["proc_q"], np.float32),
                                 np.array(out["proc_seen"], bool))
@@ -2221,9 +1298,10 @@ def open_session(query: Query, num_cameras: int = 1, **kw: Any) -> ShedSession:
     the admission CDFs), ``queue_size`` (initial per-camera queue cap),
     ``queue_capacity`` (the physical (C, K) lane bound the dynamic cap
     is clipped to), ``latency_inputs``, ``cdf_window``,
-    ``impl``/``interpret`` (ingest dispatch overrides), and ``serve``
-    ("device" = jitted XLA serve step with donated state buffers,
-    "host" = bit-identical vectorized NumPy; default backend-aware).
+    and ``impl``/``interpret`` (ingest dispatch overrides). The control
+    plane is one set of jitted XLA programs with donated state buffers
+    on every backend; ``serve`` is accepted as ``None`` or
+    ``"device"``.
 
     Fleet scale-out: ``shard_cameras=True`` (or ``mesh=some_mesh``)
     shards the camera lanes over a device mesh via ``repro.core.fleet``

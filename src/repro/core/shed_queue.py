@@ -5,7 +5,7 @@ lowest-utility frame is evicted (whether resident or incoming); the
 transmission layer always sends the current *best* frame. Queues never
 shrink below size 1 ("avoid starving the downstream operators").
 
-Two implementations of the same contract:
+Two forms of the same contract:
 
 ``UtilityQueue``
     The original scalar heapq queue — one Python object per camera.
@@ -13,15 +13,13 @@ Two implementations of the same contract:
     property-tested against it) and as the single-camera
     ``LoadShedder``'s queue.
 
-Array lanes (``lanes_*`` functions)
+Array lanes (the ``*_dev`` functions)
     The serve-path hot form: C cameras' queues as fixed-capacity
     ``(C, K)`` ``util``/``seq`` lanes (empty slots ``util=-inf``,
     ``seq=-1``) plus a ``(C,)`` ``next_seq`` push counter, so queue
     state joins the session's checkpointable pytree and admission is
-    pure array code. Each operation exists twice with bit-identical
-    float32 results: ``*_dev`` (pure jnp, traceable into one jitted
-    serve step) and ``*_host`` (vectorized NumPy, the compiled-CPU
-    serving path — mutates the lane arrays in place).
+    pure array code. Each operation is pure jnp, traced into the
+    control plane's jitted programs (``repro.core.control_plane``).
 
     Ordering contract (must match the heapq reference exactly):
       * eviction removes the minimum by ``(utility, seq)`` — lowest
@@ -144,21 +142,11 @@ class UtilityQueue:
 # Array-backed queue lanes — shared helpers
 # ---------------------------------------------------------------------------
 
-def make_lanes(num_cameras: int, capacity: int, xp=np):
+def make_lanes(num_cameras: int, capacity: int):
     """Fresh empty (C, K) lanes: (util, seq, next_seq)."""
-    return (xp.full((num_cameras, capacity), -xp.inf, xp.float32),
-            xp.full((num_cameras, capacity), -1, xp.int32),
-            xp.zeros((num_cameras,), xp.int32))
-
-
-def _order_key_host(util: np.ndarray, seq: np.ndarray) -> np.ndarray:
-    """Ascending uint64 key realizing the (utility, seq) lexicographic
-    order — the float32 bits are mapped order-preservingly into the
-    high word, the (signed) seq into the low word."""
-    ub = np.ascontiguousarray(util, np.float32).view(np.uint32)
-    fkey = np.where(ub >> 31 == 1, ~ub, ub | np.uint32(0x80000000))
-    skey = np.asarray(seq, np.int32).view(np.uint32) ^ np.uint32(0x80000000)
-    return (fkey.astype(np.uint64) << np.uint64(32)) | skey.astype(np.uint64)
+    return (jnp.full((num_cameras, capacity), -jnp.inf, jnp.float32),
+            jnp.full((num_cameras, capacity), -1, jnp.int32),
+            jnp.zeros((num_cameras,), jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -170,24 +158,7 @@ def _order_key_host(util: np.ndarray, seq: np.ndarray) -> np.ndarray:
 # counts (n_inval invalid, n_evict to drop), the evicted entries occupy
 # sorted positions [n_inval, n_inval + n_evict) and the survivors are
 # the final n_keep positions; gathering the last K positions re-packs
-# the lanes (sorted ascending — a canonical layout both impls share).
-
-def _select_core(u_sorted, s_sorted, b_sorted, total, keep_cap, K, xp):
-    C, M = u_sorted.shape
-    n_keep = xp.minimum(total, keep_cap)
-    n_evict = total - n_keep
-    n_inval = M - total
-    pos = xp.arange(M, dtype=xp.int32)
-    evict = ((pos[None, :] >= n_inval[:, None])
-             & (pos[None, :] < (n_inval + n_evict)[:, None]))
-    evicted_seq = xp.where(evict, s_sorted, -1).astype(xp.int32)
-    evicted_bidx = xp.where(evict, b_sorted, -1).astype(xp.int32)
-    alive = pos[None, M - K:] >= (M - n_keep)[:, None]
-    new_util = xp.where(alive, u_sorted[:, M - K:],
-                        xp.float32(-xp.inf)).astype(xp.float32)
-    new_seq = xp.where(alive, s_sorted[:, M - K:], -1).astype(xp.int32)
-    return new_util, new_seq, evicted_seq, evicted_bidx
-
+# the lanes (sorted ascending, a canonical layout).
 
 def select_dev(util, seq, bidx, keep_cap, K):
     """Device top-cap selection (see module docstring for the contract)."""
@@ -196,40 +167,25 @@ def select_dev(util, seq, bidx, keep_cap, K):
                                   bidx.astype(jnp.int32)),
                                  num_keys=2, dimension=-1)
     total = (seq >= 0).sum(axis=-1).astype(jnp.int32)
-    return _select_core(u_s, s_s, b_s, total, keep_cap, K, jnp)
-
-
-def select_host(util, seq, bidx, keep_cap, K):
-    """NumPy twin of :func:`select_dev` (bit-identical results)."""
-    order = np.argsort(_order_key_host(util, seq), axis=-1, kind="stable")
-    u_s = np.take_along_axis(np.asarray(util, np.float32), order, -1)
-    s_s = np.take_along_axis(np.asarray(seq, np.int32), order, -1)
-    b_s = np.take_along_axis(np.asarray(bidx, np.int32), order, -1)
-    total = (seq >= 0).sum(axis=-1).astype(np.int32)
-    return _select_core(u_s, s_s, b_s, total, keep_cap, K, np)
+    C, M = u_s.shape
+    n_keep = jnp.minimum(total, keep_cap)
+    n_evict = total - n_keep
+    n_inval = M - total
+    pos = jnp.arange(M, dtype=jnp.int32)
+    evict = ((pos[None, :] >= n_inval[:, None])
+             & (pos[None, :] < (n_inval + n_evict)[:, None]))
+    evicted_seq = jnp.where(evict, s_s, -1).astype(jnp.int32)
+    evicted_bidx = jnp.where(evict, b_s, -1).astype(jnp.int32)
+    alive = pos[None, M - K:] >= (M - n_keep)[:, None]
+    new_util = jnp.where(alive, u_s[:, M - K:],
+                         jnp.float32(-jnp.inf)).astype(jnp.float32)
+    new_seq = jnp.where(alive, s_s[:, M - K:], -1).astype(jnp.int32)
+    return new_util, new_seq, evicted_seq, evicted_bidx
 
 
 # ---------------------------------------------------------------------------
 # Batch push (vectorized admission)
 # ---------------------------------------------------------------------------
-
-def _push_batch_args(util, seq, next_seq, u, admit, cap, xp):
-    C, K = util.shape
-    T = u.shape[1]
-    npush = xp.cumsum(admit.astype(xp.int32), axis=1)
-    seq_in = next_seq[:, None] + npush - 1
-    cand_u = xp.concatenate(
-        [util, xp.where(admit, u, xp.float32(-xp.inf))], axis=1)
-    cand_s = xp.concatenate([seq, xp.where(admit, seq_in, -1)],
-                            axis=1).astype(xp.int32)
-    tcols = xp.broadcast_to(xp.arange(T, dtype=xp.int32)[None, :], (C, T))
-    cand_b = xp.concatenate(
-        [xp.full((C, K), -1, xp.int32), xp.where(admit, tcols, -1)], axis=1)
-    cap_eff = xp.clip(cap, 1, K).astype(xp.int32)
-    pushed_seq = xp.where(admit, seq_in, -1).astype(xp.int32)
-    new_next = (next_seq + npush[:, -1]).astype(xp.int32)
-    return cand_u, cand_s, cand_b, cap_eff, pushed_seq, new_next
-
 
 def push_batch_dev(util, seq, next_seq, u, admit, cap):
     """Push a (C, T) utility batch (``admit`` masks real pushes) into
@@ -241,22 +197,24 @@ def push_batch_dev(util, seq, next_seq, u, admit, cap):
     marks evictions of *this batch's* frames by batch column (-1 for
     evicted pre-batch residents, whose seqs are in ``evicted_seq``).
     """
-    K = util.shape[1]
-    cand_u, cand_s, cand_b, cap_eff, pushed_seq, new_next = _push_batch_args(
-        util, seq, next_seq, flush_subnormal(u, jnp), admit, cap, jnp)
+    C, K = util.shape
+    u = flush_subnormal(u, jnp)
+    T = u.shape[1]
+    npush = jnp.cumsum(admit.astype(jnp.int32), axis=1)
+    seq_in = next_seq[:, None] + npush - 1
+    cand_u = jnp.concatenate(
+        [util, jnp.where(admit, u, jnp.float32(-jnp.inf))], axis=1)
+    cand_s = jnp.concatenate([seq, jnp.where(admit, seq_in, -1)],
+                             axis=1).astype(jnp.int32)
+    tcols = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (C, T))
+    cand_b = jnp.concatenate(
+        [jnp.full((C, K), -1, jnp.int32), jnp.where(admit, tcols, -1)],
+        axis=1)
+    cap_eff = jnp.clip(cap, 1, K).astype(jnp.int32)
+    pushed_seq = jnp.where(admit, seq_in, -1).astype(jnp.int32)
+    new_next = (next_seq + npush[:, -1]).astype(jnp.int32)
     nu, ns, ev_s, ev_b = select_dev(cand_u, cand_s, cand_b, cap_eff, K)
     return nu, ns, new_next, pushed_seq, ev_s, ev_b
-
-
-def push_batch_host(util, seq, next_seq, u, admit, cap):
-    """NumPy twin of :func:`push_batch_dev`; mutates util/seq in place
-    and returns (next_seq', pushed_seq, evicted_seq, evicted_bidx)."""
-    K = util.shape[1]
-    cand_u, cand_s, cand_b, cap_eff, pushed_seq, new_next = _push_batch_args(
-        util, seq, next_seq, flush_subnormal(u), admit, cap, np)
-    nu, ns, ev_s, ev_b = select_host(cand_u, cand_s, cand_b, cap_eff, K)
-    util[...], seq[...] = nu, ns
-    return new_next, pushed_seq, ev_s, ev_b
 
 
 # ---------------------------------------------------------------------------
@@ -264,8 +222,8 @@ def push_batch_host(util, seq, next_seq, u, admit, cap):
 # ---------------------------------------------------------------------------
 #
 # No sort: find the first free slot (queue not full) or replace the
-# worst entry (full). Replacement keeps slot layout stable, so the two
-# impls stay bitwise identical through mixed push/pop sequences.
+# worst entry (full). Replacement keeps the slot layout stable through
+# mixed push/pop sequences.
 
 def push_one_dev(util, seq, next_seq, u, do_push, cap):
     """Push u[c] for cameras with do_push[c] (others untouched).
@@ -303,36 +261,8 @@ def push_one_dev(util, seq, next_seq, u, do_push, cap):
     return new_util, new_seq, nn, pushed_seq, evicted_seq, inc_evicted
 
 
-def push_one_host(util, seq, next_seq, u, do_push, cap):
-    """NumPy twin of :func:`push_one_dev`; mutates util/seq in place."""
-    C, K = util.shape
-    rows = np.arange(C)
-    u = flush_subnormal(u)
-    valid = seq >= 0
-    count = valid.sum(axis=-1)
-    cap_eff = np.clip(cap, 1, K)
-    uv = np.where(valid, util, np.inf)
-    w_util = uv.min(axis=-1)
-    w_cand = valid & (uv == w_util[:, None])
-    w_slot = np.where(w_cand, seq, INT32_MAX).argmin(axis=-1)
-    w_seq = seq[rows, w_slot]
-    free_slot = np.argmax(~valid, axis=-1)
-    full = count >= cap_eff
-    inc_evicted = do_push & full & (u < w_util)
-    place = do_push & ~inc_evicted
-    slot = np.where(full, w_slot, free_slot)
-    util[rows[place], slot[place]] = u[place]
-    seq[rows[place], slot[place]] = next_seq[place]
-    nn = (next_seq + do_push.astype(np.int32)).astype(np.int32)
-    pushed_seq = np.where(do_push, next_seq, -1).astype(np.int32)
-    evicted_seq = np.where(
-        inc_evicted, next_seq,
-        np.where(place & full, w_seq, -1)).astype(np.int32)
-    return nn, pushed_seq, evicted_seq, inc_evicted
-
-
 # ---------------------------------------------------------------------------
-# Resize (Eq. 20 dynamic sizing) and transmission (pop/peek best)
+# Resize (Eq. 20 dynamic sizing) and transmission (pop best)
 # ---------------------------------------------------------------------------
 
 def resize_dev(util, seq, cap):
@@ -345,26 +275,6 @@ def resize_dev(util, seq, cap):
     return nu, ns, ev_s
 
 
-def resize_host(util, seq, cap):
-    """NumPy twin of :func:`resize_dev`; mutates in place, returns
-    the (C, K) padded evicted-seq array."""
-    K = util.shape[1]
-    cap_eff = np.clip(cap, 1, K).astype(np.int32)
-    nu, ns, ev_s, _ = select_host(util, seq, np.full_like(seq, -1),
-                                  cap_eff, K)
-    util[...], seq[...] = nu, ns
-    return ev_s
-
-
-def _best_slot(util, seq, xp):
-    valid = seq >= 0
-    bu = xp.where(valid, util, xp.float32(-xp.inf)).max(axis=-1)
-    has = valid.any(axis=-1)
-    slot = xp.where(valid & (util == bu[:, None]), seq,
-                    INT32_MAX).argmin(axis=-1)
-    return bu, has, slot.astype(xp.int32)
-
-
 def pop_best_dev(util, seq, cam=None):
     """Pop the best (max utility, oldest seq) entry of camera ``cam``,
     or — cam=None — of the whole array (lowest camera index breaks
@@ -373,8 +283,11 @@ def pop_best_dev(util, seq, cam=None):
     Returns (util', seq', cam (int32), popped_seq (int32)); negative
     ``popped_seq`` means every candidate queue was empty.
     """
-    C = util.shape[0]
-    bu, has, slot = _best_slot(util, seq, jnp)
+    valid = seq >= 0
+    bu = jnp.where(valid, util, jnp.float32(-jnp.inf)).max(axis=-1)
+    has = valid.any(axis=-1)
+    slot = jnp.where(valid & (util == bu[:, None]), seq,
+                     INT32_MAX).argmin(axis=-1).astype(jnp.int32)
     if cam is None:
         c = jnp.argmax(bu).astype(jnp.int32)
         ok = has.any()
@@ -388,31 +301,6 @@ def pop_best_dev(util, seq, cam=None):
     return new_util, new_seq, jnp.where(ok, c, -1).astype(jnp.int32), popped_seq
 
 
-def pop_best_host(util, seq, cam=None):
-    """NumPy twin of :func:`pop_best_dev`; mutates in place, returns
-    (cam, popped_seq) as python ints (-1, -1 when empty)."""
-    bu, has, slot = _best_slot(util, seq, np)
-    if cam is None:
-        if not has.any():
-            return -1, -1
-        c = int(np.argmax(bu))
-    else:
-        c = int(cam)
-        if not has[c]:
-            return -1, -1
-    s = int(slot[c])
-    popped = int(seq[c, s])
-    util[c, s] = -np.inf
-    seq[c, s] = -1
-    return c, popped
-
-
-def peek_best_host(util, seq):
-    """(best_utility (C,) with -inf for empty, any_nonempty (C,) bool)."""
-    bu, has, _ = _best_slot(util, seq, np)
-    return bu, has
-
-
 # ---------------------------------------------------------------------------
 # Batched top-k pop (device-side transmission control)
 # ---------------------------------------------------------------------------
@@ -424,12 +312,10 @@ def peek_best_host(util, seq):
 # on device a single variadic ``lax.sort`` with keys (-util, cam, seq)
 # IS the top-k selection (``lax.top_k`` itself lowers to this sort, and
 # with x64 disabled no single 32-bit key can carry the two-level
-# tiebreak); on host an ``np.argpartition`` candidate pool + boundary
-# tie fix-up does the same in O(C*K + k log k). Utilities are
-# canonicalized with ``u + 0.0`` (folds -0.0 into +0.0, exact for every
-# other float) so ±0 ties break by (cam, seq) exactly like the scalar
-# pop's ``==`` mask; float negation is exact and order-reversing for
-# the remaining values, so dev and host agree bit-for-bit.
+# tiebreak). Utilities are canonicalized with ``u + 0.0`` (folds -0.0
+# into +0.0, exact for every other float) so ±0 ties break by (cam,
+# seq) exactly like the scalar pop's ``==`` mask; float negation is
+# exact and order-reversing for the remaining values.
 
 def pop_topk_dev(util, seq, k: int, rows=None):
     """Pop the ``min(k, C*K)`` best entries of the (C, K) lanes in ONE
@@ -463,64 +349,8 @@ def pop_topk_dev(util, seq, k: int, rows=None):
     return new_util, new_seq, pc, ps
 
 
-def _topk_key_host(util, valid):
-    """uint32 key ascending in (utility desc) — the order-preserving
-    float32 bit map of :func:`_order_key_host`, complemented. Invalid
-    entries map to the maximal key (sorts last, like +inf on device)."""
-    u0 = np.asarray(util, np.float32) + np.float32(0.0)   # -0.0 -> +0.0
-    ub = np.ascontiguousarray(u0).view(np.uint32)
-    fkey = np.where(ub >> 31 == 1, ~ub, ub | np.uint32(0x80000000))
-    return np.where(valid, ~fkey, np.uint32(0xFFFFFFFF))
-
-
-def pop_topk_host(util, seq, k: int, rows=None):
-    """NumPy twin of :func:`pop_topk_dev`; mutates the lanes in place,
-    returns (cams, seqs) int32 arrays of length ``min(k, C*K)`` padded
-    with -1 (popped identities in pop order, live entries first)."""
-    C, K = util.shape
-    kk = min(int(k), C * K)
-    valid = seq >= 0
-    if rows is not None:
-        valid = valid & np.asarray(rows, bool)[:, None]
-    cams_out = np.full((kk,), -1, np.int32)
-    seqs_out = np.full((kk,), -1, np.int32)
-    m = min(kk, int(valid.sum()))
-    if m == 0:
-        return cams_out, seqs_out
-    dk = _topk_key_host(util, valid).reshape(-1)
-    sflat = seq.reshape(-1)
-    if m < dk.size:
-        part = np.argpartition(dk, m - 1)
-        thresh = dk[part[m - 1]]               # the m-th smallest key
-        strict = np.flatnonzero(dk < thresh)   # at most m-1 entries
-        ties = np.flatnonzero(dk == thresh)
-        need = m - strict.size
-        if need < ties.size:                   # boundary tie fix-up:
-            tc = (ties // K).astype(np.int32)  # oldest (cam, seq) wins
-            sel = ties[np.lexsort((sflat[ties], tc))[:need]]
-        else:
-            sel = ties
-        idx = np.concatenate([strict, sel])
-    else:
-        idx = np.flatnonzero(valid.reshape(-1))
-    c_i = (idx // K).astype(np.int32)
-    s_i = sflat[idx]
-    order = np.lexsort((s_i, c_i, dk[idx]))    # final exact pop order
-    c_i, s_i, idx = c_i[order], s_i[order], idx[order]
-    sl = (idx % K).astype(np.int32)
-    util[c_i, sl] = -np.inf
-    seq[c_i, sl] = -1
-    cams_out[:m] = c_i
-    seqs_out[:m] = s_i
-    return cams_out, seqs_out
-
-
 __all__ = [
     "UtilityQueue", "make_lanes", "flush_subnormal",
-    "select_dev", "select_host",
-    "push_batch_dev", "push_batch_host",
-    "push_one_dev", "push_one_host",
-    "resize_dev", "resize_host",
-    "pop_best_dev", "pop_best_host", "peek_best_host",
-    "pop_topk_dev", "pop_topk_host",
+    "select_dev", "push_batch_dev", "push_one_dev", "resize_dev",
+    "pop_best_dev", "pop_topk_dev",
 ]

@@ -1,5 +1,5 @@
-"""Semantic cascade (ISSUE 9): stage-2 scoring between admission and
-queue insertion.
+"""Semantic cascade: stage-2 scoring between admission and queue
+insertion.
 
 Covers the four acceptance surfaces: (1) a session without ``cascade=``
 is bit-identical to the single-stage pipeline (including the algebraic
@@ -26,12 +26,18 @@ from repro.core.session import (
 from repro.kernels.hsv_features.kernel import ingest_batch
 from repro.kernels.hsv_features.ref import foreground_bbox, ingest_batch_ref
 
+from host_loop_reference import HostLoopCascade
+
 HR1 = (tuple(RED.hue_ranges),)
 
+# camera counts of the cascade sessions (the cascade runs on one device;
+# camera sharding does not support it)
+CAMERAS = {"device": 2, "device-3cams": 3}
 
-def _sess(C=2, serve="host", cascade=None, **kw):
+
+def _sess(C=2, cascade=None, **kw):
     return ShedSession(Query.single(RED, latency_bound=1.0, fps=10.0), C,
-                       serve=serve, cascade=cascade, **kw)
+                       cascade=cascade, **kw)
 
 
 def _warm(sess, p=0.2, fps=10.0):
@@ -53,13 +59,14 @@ def _gate_shed(decisions) -> int:
 # 1. no-cascade bit-parity
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("serve", ["host", "device"])
-def test_no_cascade_sessions_are_single_stage(serve, rng):
+@pytest.mark.parametrize("placement", ["device", "mesh"])
+def test_no_cascade_sessions_are_single_stage(placement, rng):
     """cascade=None leaves every decision identical run-to-run, the s2
-    lanes untouched, and the snapshot free of cascade keys."""
+    lanes untouched, and the snapshot free of cascade keys, on one
+    device and on a one-device camera mesh."""
     runs = []
     for _ in range(2):
-        sess = _sess(serve=serve)
+        sess = _sess(shard_cameras=placement == "mesh")
         _warm(sess)
         decs = []
         for i in range(12):
@@ -81,9 +88,8 @@ def test_gate_fraction_one_reduces_to_single_stage(rng):
     """r1 = r, r2 = 0: a cascade with the whole rate on stage 1 and the
     color utilities as stage-2 scores makes the SAME decisions as the
     plain single-stage session (stage 2 inert, same queue ordering)."""
-    plain = _sess(serve="host")
-    casc = _sess(serve="host",
-                 cascade=Cascade(CallableScorer(lambda f, b: None),
+    plain = _sess()
+    casc = _sess(cascade=Cascade(CallableScorer(lambda f, b: None),
                                  gate_fraction=1.0, window=64))
     _warm(plain)
     _warm(casc)
@@ -105,7 +111,7 @@ def test_cascade_rejects_sharding_and_bad_inputs():
     with pytest.raises(ValueError):
         _sess(cascade=Cascade(CallableScorer(lambda f, b: None)),
               shard_cameras=True)
-    sess = _sess(serve="host")
+    sess = _sess()
     with pytest.raises(ValueError):
         sess.step(utilities=np.zeros((2, 4), np.float32),
                   s2_utilities=np.zeros((2, 4), np.float32))
@@ -115,17 +121,18 @@ def test_cascade_rejects_sharding_and_bad_inputs():
 # 2. stage-2 threshold control convergence
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("serve", ["host", "device"])
-def test_stage2_threshold_converges_to_conditional_quantile(serve):
+@pytest.mark.parametrize("C", list(CAMERAS.values()),
+                         ids=list(CAMERAS))
+def test_stage2_threshold_converges_to_conditional_quantile(C):
     """Uniform [0,1] utilities and s2 scores, p*C*fps = 4 -> combined
     target r = 0.75. With gate_fraction g = 0.5: stage 1 thresholds at
     the 0.375-quantile, stage 2 at the conditional 0.6-quantile of the
     survivors, and the realized combined shed rate tracks 0.75."""
-    C, T = 2, 16
-    sess = _sess(C=C, serve=serve,
+    T = 16
+    sess = _sess(C=C,
                  cascade=Cascade(CallableScorer(lambda f, b: None),
                                  gate_fraction=0.5, window=2048))
-    _warm(sess, p=0.2)
+    _warm(sess, p=0.4 / C)
     rng = np.random.default_rng(7)
     shed = off = 0
     for i in range(60):
@@ -145,16 +152,18 @@ def test_stage2_threshold_converges_to_conditional_quantile(serve):
     assert sess.stats.dropped_cascade > 0
 
 
-@pytest.mark.parametrize("serve", ["host", "device"])
-def test_degraded_floor_bounds_combined_rate(serve):
+@pytest.mark.parametrize("C", list(CAMERAS.values()),
+                         ids=list(CAMERAS))
+def test_degraded_floor_bounds_combined_rate(C):
     """set_rate_floor applies to the COMBINED rate before the split, so
     both stages together shed at least the floor."""
-    C, T = 2, 16
-    sess = _sess(C=C, serve=serve,
+    T = 16
+    sess = _sess(C=C,
                  cascade=Cascade(CallableScorer(lambda f, b: None),
                                  gate_fraction=0.5, window=1024))
-    # a lightly loaded backend: target rate would be 0 without the floor
-    _warm(sess, p=0.04)
+    # a lightly loaded backend (p*C*fps = 0.8): target rate would be 0
+    # without the floor
+    _warm(sess, p=0.08 / C)
     sess.set_rate_floor(0.5)
     rng = np.random.default_rng(11)
     shed = off = 0
@@ -170,59 +179,64 @@ def test_degraded_floor_bounds_combined_rate(serve):
     assert sess.stats.dropped_cascade > 0
 
 
-def test_device_host_cascade_twins_agree():
-    """The jitted cascade phases and their NumPy twins make identical
-    decisions and converge identical thresholds, with latency reports
-    pending (on the device) at some of the steps."""
-    C, T = 3, 8
-    mk = lambda serve: _sess(
-        C=C, serve=serve,
-        cascade=Cascade(CallableScorer(lambda f, b: None),
-                        gate_fraction=0.4, window=256))
-    dev, host = mk("device"), mk("host")
-    _warm(dev, p=0.15)
-    _warm(host, p=0.15)
+def test_cascade_matches_host_loop_reference():
+    """The jitted cascade phases make the scalar two-stage loop's
+    decisions and converge its thresholds, bit for bit (exact sort
+    ticks), with latency reports pending at some of the steps."""
+    C, T, W2 = 3, 8, 256
+    sess = _sess(C=C, exact_tick=True,
+                 cascade=Cascade(CallableScorer(lambda f, b: None),
+                                 gate_fraction=0.4, window=W2))
+    ref = HostLoopCascade(C, gate_fraction=0.4, s2_window=W2)
+    _warm(sess, p=0.15)
+    ref.report_backend_latency(0.15)
+    ref.tick()
     rng = np.random.default_rng(3)
+    n_cascade = 0
     for i in range(40):
         u = rng.uniform(0, 1, (C, T)).astype(np.float32)
         s2 = rng.uniform(0, 1, (C, T)).astype(np.float32)
         tick = i % 2 == 1
         if i % 3 == 0:
             lat, cam = 0.1 + 0.01 * (i % 7), (None if i % 2 else i % C)
-            dev.report_backend_latency(lat, cam=cam)
-            host.report_backend_latency(lat, cam=cam)
-        a = dev.step(utilities=u, s2_utilities=s2, tick=tick)
-        b = host.step(utilities=u, s2_utilities=s2, tick=tick)
-        np.testing.assert_array_equal(a.decisions, b.decisions)
-        np.testing.assert_array_equal(a.pushed_seq, b.pushed_seq)
-    np.testing.assert_array_equal(
-        np.asarray(dev.state.s2_threshold, np.float32),
-        np.asarray(host.state.s2_threshold, np.float32))
-    np.testing.assert_array_equal(np.asarray(dev.state.threshold),
-                                  np.asarray(host.state.threshold))
-    np.testing.assert_array_equal(np.asarray(dev.state.proc_q),
-                                  host.state.proc_q)
-    assert dev.stats.dropped_cascade == host.stats.dropped_cascade
+            sess.report_backend_latency(lat, cam=cam)
+            ref.report_backend_latency(lat, cam=cam)
+        a = sess.step(utilities=u, s2_utilities=s2, tick=tick)
+        want = ref.admit(u, s2)
+        n_cascade += int((want == SHED_CASCADE).sum())
+        if tick:
+            ref.tick()
+        np.testing.assert_array_equal(a.decisions, want, err_msg=str(i))
+        np.testing.assert_array_equal(
+            np.asarray(sess.state.s2_threshold), ref.s2_threshold)
+        np.testing.assert_array_equal(np.asarray(sess.state.threshold),
+                                      ref.threshold)
+    np.testing.assert_array_equal(np.asarray(sess.state.proc_q),
+                                  ref.proc_q)
+    np.testing.assert_array_equal(np.asarray(sess.state.queue_cap),
+                                  ref.queue_cap)
+    assert sess.stats.dropped_cascade == n_cascade > 0
 
 
 # ---------------------------------------------------------------------------
 # 3. checkpoint / restore round-trip
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("serve", ["host", "device"])
-def test_cascade_checkpoint_restore_roundtrip(serve, tmp_path):
+@pytest.mark.parametrize("C", list(CAMERAS.values()),
+                         ids=list(CAMERAS))
+def test_cascade_checkpoint_restore_roundtrip(C, tmp_path):
     mk = lambda: _sess(
-        C=2, serve=serve,
+        C=C,
         cascade=Cascade(CallableScorer(lambda f, b: None),
                         gate_fraction=0.5, window=128))
     live = mk()
     _warm(live, p=0.2)
     rng = np.random.default_rng(5)
-    seg1 = [(rng.uniform(0, 1, (2, 8)).astype(np.float32),
-             rng.uniform(0, 1, (2, 8)).astype(np.float32))
+    seg1 = [(rng.uniform(0, 1, (C, 8)).astype(np.float32),
+             rng.uniform(0, 1, (C, 8)).astype(np.float32))
             for _ in range(10)]
-    seg2 = [(rng.uniform(0, 1, (2, 8)).astype(np.float32),
-             rng.uniform(0, 1, (2, 8)).astype(np.float32))
+    seg2 = [(rng.uniform(0, 1, (C, 8)).astype(np.float32),
+             rng.uniform(0, 1, (C, 8)).astype(np.float32))
             for _ in range(10)]
     for u, s2 in seg1:
         live.step(utilities=u, s2_utilities=s2, tick=True)

@@ -140,17 +140,21 @@ def test_churned_session_matches_fresh_session_on_survivors():
     assert outs[0] == outs[1]
 
 
+# where a session's lanes live: one device, or a one-device camera mesh
+PLACEMENTS = {"device": {}, "mesh": {"shard_cameras": True}}
+
+
 def test_detach_attach_same_camera_equals_fresh_lane():
-    for serve in ("host", "device"):
+    for where, placement in PLACEMENTS.items():
         rng = np.random.default_rng(3)
-        sess = _session(C=2, serve=serve)
+        sess = _session(C=2, **placement)
         _feed(sess, ["a", "b"], rng.random(30))
         sess.report_backend_latency(0.05)
         sess.report_ingress_fps(30.0)
         sess.tick()
         before = np.asarray(sess.state.threshold)[1]
         assert np.isfinite(before)             # b had built real state
-        # latency reports still pending (on a device) when b cycles:
+        # latency reports still pending when b cycles:
         # lane 0 keeps them, lane 1 starts unseen, as if each report
         # had reached the state at once
         sess.report_backend_latency(0.08, cam=1)
@@ -164,13 +168,13 @@ def test_detach_attach_same_camera_equals_fresh_lane():
         assert bool(np.asarray(st.active)[1])
         p0 = np.float32(0.05)
         p0 = p0 + np.float32(0.6) * (np.float32(0.07) - p0)
-        assert sess.expected_proc(cam=0) == float(p0), serve
-        assert sess.expected_proc(cam=1) == 0.0, serve
+        assert sess.expected_proc(cam=0) == float(p0), where
+        assert sess.expected_proc(cam=1) == 0.0, where
         sess.tick()
         np.testing.assert_array_equal(np.asarray(sess.state.proc_q),
-                                      [p0, 0.0], err_msg=serve)
+                                      [p0, 0.0], err_msg=where)
         np.testing.assert_array_equal(np.asarray(sess.state.proc_seen),
-                                      [True, False], err_msg=serve)
+                                      [True, False], err_msg=where)
 
 
 # -- checkpoint / restore ----------------------------------------------------
@@ -198,9 +202,9 @@ def test_checkpoint_roundtrips_lane_map_and_active_mask(tmp_path):
 def test_midrun_checkpoint_restore_is_bit_identical(tmp_path):
     """Segment 1 -> checkpoint -> segment 2 must equal restoring the
     checkpoint into a fresh session and replaying segment 2: identical
-    admission codes, tick snapshots and state lanes, on the host and on
-    a device. A latency report still pending at the checkpoint is in
-    it."""
+    admission codes, tick snapshots and state lanes, on one device and
+    on a one-device camera mesh. A latency report still pending at the
+    checkpoint is in it."""
     rng = np.random.default_rng(11)
     seg1, seg2 = rng.random(40), rng.random(50)
 
@@ -211,25 +215,25 @@ def test_midrun_checkpoint_restore_is_bit_identical(tmp_path):
         snap = _snap(sess)
         return codes, snap
 
-    for serve in ("host", "device"):
-        live = _session(C=2, serve=serve)
+    for where, placement in PLACEMENTS.items():
+        live = _session(C=2, **placement)
         _feed(live, ["a", "b"], seg1)
         live.report_backend_latency(0.06)
         live.report_ingress_fps(30.0)
         live.tick()
         live.report_backend_latency(0.09, cam=1)
-        live.checkpoint(tmp_path / serve, step=1)
+        live.checkpoint(tmp_path / where, step=1)
 
-        resumed = _session(C=2, serve=serve)
-        resumed.restore(tmp_path / serve)
+        resumed = _session(C=2, **placement)
+        resumed.restore(tmp_path / where)
         assert resumed.expected_proc(cam=1) == live.expected_proc(cam=1)
         out_live = segment2(live)
         out_resumed = segment2(resumed)
 
-        assert out_live == out_resumed, serve
+        assert out_live == out_resumed, where
         for leaf in ("threshold", "q_util", "q_seq", "queue_cap",
                      "cdf_len", "cdf_pos", "proc_q", "fps_obs", "active",
                      "rate_floor"):
             a = np.asarray(getattr(live.state, leaf))
             b = np.asarray(getattr(resumed.state, leaf))
-            assert np.array_equal(a, b), (serve, leaf)
+            assert np.array_equal(a, b), (where, leaf)

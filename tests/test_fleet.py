@@ -51,7 +51,7 @@ def _sessions(C=12, W=256, seed=0, **fleet_kw):
     kw = dict(num_cameras=C, train_utilities=hist, queue_size=4,
               queue_capacity=16, cdf_window=W)
     q = Query.single("red", latency_bound=1.0, fps=10.0)
-    ref = open_session(q, serve="device", **kw)
+    ref = open_session(q, **kw)
     fl = open_session(q, shard_cameras=True, **fleet_kw, **kw)
     return ref, fl, rng
 
@@ -91,11 +91,35 @@ def test_offer_batch_and_pop_parity_in_process():
         assert ref.next_frame() == fl.next_frame()
 
 
-def test_shard_cameras_rejects_host_serve():
+@pytest.mark.parametrize("sharded", [False, True])
+def test_host_serve_is_rejected(sharded):
+    """The NumPy serve path is gone: serve="host" (and any value but
+    None or "device") raises, with or without camera sharding."""
     from repro.core import Query, open_session
-    with pytest.raises(ValueError, match="serve='device'"):
-        open_session(Query.single("red"), num_cameras=4,
-                     shard_cameras=True, serve="host")
+    for serve in ("host", "numpy"):
+        with pytest.raises(ValueError, match="NumPy serve path was removed"):
+            open_session(Query.single("red"), num_cameras=4,
+                         shard_cameras=sharded, serve=serve)
+
+
+def test_serve_none_and_device_open_the_same_session():
+    """serve=None and serve="device" are the one control plane: the
+    same decisions, pops and state on the same trace."""
+    from repro.core import Query, open_session
+    rng = np.random.default_rng(4)
+    hist = rng.uniform(0, 1, 64).astype(np.float32)
+    a, b = (open_session(Query.single("red", fps=10.0), num_cameras=3,
+                         train_utilities=hist, cdf_window=64, serve=serve)
+            for serve in (None, "device"))
+    for i in range(4):
+        u = rng.uniform(0, 1, (3, 5)).astype(np.float32)
+        for s in (a, b):
+            s.report_backend_latency(0.03 * (i + 1))
+        np.testing.assert_array_equal(a.step(utilities=u).decisions,
+                                      b.step(utilities=u).decisions)
+        assert a.next_frames(2) == b.next_frames(2)
+    for k, v in a.state.as_dict().items():
+        np.testing.assert_array_equal(v, b.state.as_dict()[k], err_msg=k)
 
 
 def test_indivisible_camera_count_rejected():
@@ -114,24 +138,26 @@ def test_indivisible_camera_count_rejected():
 
 
 def test_report_backend_latency_per_camera_lanes():
-    """Satellite: scalar call broadcasts (legacy behavior); cam= call
-    updates one lane with the same asymmetric EWMA."""
+    """Scalar call broadcasts; cam= call updates one lane with the same
+    asymmetric EWMA. The lanes reach the state at the next program that
+    reads them (a tick here)."""
     from repro.core import Query, open_session
-    s = open_session(Query.single("red", fps=10.0), num_cameras=3,
-                     serve="host")
+    s = open_session(Query.single("red", fps=10.0), num_cameras=3)
     s.report_backend_latency(0.2)
+    s.tick()
     np.testing.assert_allclose(np.asarray(s.state.proc_q), 0.2)
     assert s.expected_proc() == pytest.approx(0.2)
     s.report_backend_latency(0.4, cam=1)     # up-move: alpha_up = 0.6
+    s.tick()
     p = np.asarray(s.state.proc_q)
     assert p[0] == pytest.approx(0.2) and p[2] == pytest.approx(0.2)
     assert p[1] == pytest.approx(0.2 + 0.6 * 0.2)
     assert s.expected_proc(cam=1) == pytest.approx(0.32)
     assert s.expected_proc() == pytest.approx(0.32)    # worst lane
     # first per-camera report lands raw (proc_seen gating)
-    s2 = open_session(Query.single("red", fps=10.0), num_cameras=2,
-                      serve="host")
+    s2 = open_session(Query.single("red", fps=10.0), num_cameras=2)
     s2.report_backend_latency(0.5, cam=0)
+    s2.tick()
     p = np.asarray(s2.state.proc_q)
     assert p[0] == pytest.approx(0.5) and p[1] == 0.0
     assert bool(np.asarray(s2.state.proc_seen)[0])
@@ -156,7 +182,7 @@ hist = rng.uniform(0, 1, 300).astype(np.float32)
 q = Query.single("red", latency_bound=1.0, fps=10.0)
 kw = dict(num_cameras=C, train_utilities=hist, queue_size=4,
           queue_capacity=16, cdf_window=W)
-ref = open_session(q, serve="device", **kw)
+ref = open_session(q, **kw)
 fl = open_session(q, shard_cameras=True, fleet_aggregate=True, **kw)
 assert fl.mesh.shape["camera"] == 8
 for s in range(6):
@@ -184,7 +210,7 @@ recs = [r for i, s in enumerate(scs)
         for r in scenario_records(s, i, [COLORS["red"]], fps=10.0)]
 pfs = np.stack([r.pf for r in recs])
 labels = np.array([r.label for r in recs])
-ref2 = open_session(q, num_cameras=8, serve="device", frame_shape=(24, 32))
+ref2 = open_session(q, num_cameras=8, frame_shape=(24, 32))
 model = ref2.fit(pfs, labels)
 fl2 = open_session(q, num_cameras=8, shard_cameras=True, model=model,
                    frame_shape=(24, 32))
@@ -230,7 +256,7 @@ fl8.checkpoint(d, step=7)
 fl2 = open_session(q, mesh=fleet.fleet_mesh(2), **kw)
 step, meta = fl2.restore(d)
 assert step == 7 and meta["num_cameras"] == C
-dev = open_session(q, serve="device", **kw)
+dev = open_session(q, **kw)
 dev.restore(d)
 for k, v in fl8.state.as_dict().items():
     assert np.array_equal(v, np.asarray(getattr(fl2.state, k))), k
@@ -350,7 +376,7 @@ def same_state(a, b, where):
 for dtype in ("uint8", "float32"):
     for bg in ("fresh", "carried"):
         mesh = fleet.fleet_mesh(4)
-        fl, one = session(bg, mesh=mesh), session(bg, serve="device")
+        fl, one = session(bg, mesh=mesh), session(bg)
         want = fleet.frame_sharding(mesh)
         staged.clear()
         shed = 0

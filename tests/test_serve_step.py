@@ -1,8 +1,10 @@
-"""Device-resident serve step (ISSUE 5): array-backed queue lanes vs
-the heapq ``UtilityQueue`` reference (push/evict/resize/pop_best with
-FIFO tiebreaks), device-vs-host threshold parity across cdf_len edge
-cases, fused ``step()`` parity against the seed-style host loop, the
-float32 admission-boundary regression, and simulator batched arrivals.
+"""Device-resident serve step: array-backed queue lanes vs the heapq
+``UtilityQueue`` reference (push/evict/resize/pop_best with FIFO
+tiebreaks), lane thresholds vs the scalar ``threshold_from_sorted``
+across cdf_len edge cases, fused ``step()`` parity against the scalar
+host loop (``host_loop_reference``) on one device and on a one-device
+camera mesh, the float32 admission-boundary regression, and simulator
+batched arrivals.
 """
 import numpy as np
 import pytest
@@ -17,8 +19,13 @@ from repro.core.shed_queue import UtilityQueue
 from repro.core.threshold import (
     threshold_from_sorted,
     thresholds_from_lanes_dev,
-    thresholds_from_lanes_host,
 )
+
+from host_loop_reference import HostLoopShedder
+
+# where a session's lanes live: one device, or a one-device camera mesh
+# (the sharded programs, one shard)
+PLACEMENTS = {"device": {}, "mesh": {"shard_cameras": True}}
 
 
 # ---------------------------------------------------------------------------
@@ -35,53 +42,67 @@ def _lane_multiset(util, seq, c):
 
 
 def _run_mixed_ops(rng, C=3, K=6, T=5, rounds=6, utilities=None):
-    """Drive heapq references, host lanes, and device lanes through the
-    same mixed op sequence; assert multiset parity vs heapq and bitwise
-    parity host-vs-device after every op."""
+    """Drive heapq references and device lanes through the same mixed
+    op sequence; assert multiset parity vs heapq after every op, and
+    that every eviction and pop is the one the reference makes."""
     cap = rng.integers(1, K + 1, C).astype(np.int32)
     refs = [UtilityQueue(int(cap[c])) for c in range(C)]
-    hu, hs, hn = sq.make_lanes(C, K)
-    du, ds, dn = jnp.asarray(hu), jnp.asarray(hs), jnp.asarray(hn)
+    du, ds, dn = sq.make_lanes(C, K)
     pool = utilities or [0.1, 0.2, 0.5, 0.5, 0.5, 0.9]
 
-    for _ in range(rounds):
+    for rnd in range(rounds):
         kind = int(rng.integers(0, 4))
         if kind == 0:       # batch push
             u = rng.choice(pool, (C, T)).astype(np.float32)
             admit = rng.random((C, T)) < 0.8
+            n0 = np.asarray(dn)
+            ref_ev = [[] for _ in range(C)]
             for c in range(C):
                 for t in range(T):
                     if admit[c, t]:
-                        refs[c].push(("f", c, t), float(u[c, t]))
+                        e = refs[c].push(("f", rnd, c, t), float(u[c, t]))
+                        if e is not None:
+                            ref_ev[c].append(e)
             du, ds, dn, dp, des, deb = sq.push_batch_dev(
                 du, ds, dn, jnp.asarray(u), jnp.asarray(admit),
                 jnp.asarray(cap))
-            hn, hp, hes, heb = sq.push_batch_host(hu, hs, hn, u, admit, cap)
-            np.testing.assert_array_equal(np.asarray(dp), hp)
-            np.testing.assert_array_equal(np.asarray(des), hes)
-            np.testing.assert_array_equal(np.asarray(deb), heb)
+            # seqs count up from next_seq over the admitted slots
+            want = np.where(admit, n0[:, None]
+                            + np.cumsum(admit, axis=1) - 1, -1)
+            np.testing.assert_array_equal(np.asarray(dp), want)
+            des, deb = np.asarray(des), np.asarray(deb)
+            for c in range(C):
+                assert int((des[c] >= 0).sum()) == len(ref_ev[c])
+                # this batch's own evicted frames, by batch column
+                assert sorted(deb[c][deb[c] >= 0].tolist()) == sorted(
+                    e[3] for e in ref_ev[c] if e[:3] == ("f", rnd, c))
         elif kind == 1:     # single push
             u = rng.choice(pool, C).astype(np.float32)
             do = rng.random(C) < 0.7
             ref_evicted = {}
             for c in range(C):
                 if do[c]:
-                    ref_evicted[c] = refs[c].push(("s", c), float(u[c]))
+                    ref_evicted[c] = refs[c].push(("s", rnd, c),
+                                                  float(u[c]))
+            n0 = np.asarray(dn)
             du, ds, dn, dp, des, die = sq.push_one_dev(
                 du, ds, dn, jnp.asarray(u), jnp.asarray(do),
                 jnp.asarray(cap))
-            hn, hp, hes, hie = sq.push_one_host(hu, hs, hn, u, do, cap)
-            np.testing.assert_array_equal(np.asarray(dp), hp)
-            np.testing.assert_array_equal(np.asarray(des), hes)
+            np.testing.assert_array_equal(np.asarray(dp),
+                                          np.where(do, n0, -1))
+            des, die = np.asarray(des), np.asarray(die)
             for c in range(C):      # eviction iff the reference evicted
-                assert (hes[c] >= 0) == (ref_evicted.get(c) is not None)
+                ev = ref_evicted.get(c)
+                assert (des[c] >= 0) == (ev is not None)
+                # the incoming frame itself lost iff the reference
+                # handed it straight back
+                assert bool(die[c]) == (ev == ("s", rnd, c))
         elif kind == 2:     # resize
             cap = rng.integers(1, K + 1, C).astype(np.int32)
-            for c in range(C):
-                refs[c].resize(int(cap[c]))
+            n_ev = [len(refs[c].resize(int(cap[c]))) for c in range(C)]
             du, ds, des = sq.resize_dev(du, ds, jnp.asarray(cap))
-            hes = sq.resize_host(hu, hs, cap)
-            np.testing.assert_array_equal(np.asarray(des), hes)
+            np.testing.assert_array_equal(
+                (np.asarray(des) >= 0).sum(axis=1), n_ev)
         else:               # pop best across the array
             bc, bu = -1, -np.inf
             for c, q in enumerate(refs):
@@ -90,17 +111,13 @@ def _run_mixed_ops(rng, C=3, K=6, T=5, rounds=6, utilities=None):
                     bc, bu = c, pu
             ref_item = refs[bc].pop_best() if bc >= 0 else None
             du, ds, dcam, dseq = sq.pop_best_dev(du, ds)
-            hcam, hseq = sq.pop_best_host(hu, hs)
-            assert (int(dcam), int(dseq)) == (hcam, hseq)
-            assert (ref_item is None) == (hseq < 0)
+            assert (ref_item is None) == (int(dseq) < 0)
             if ref_item is not None:
-                assert ref_item[1] == hcam      # same camera as reference
+                assert ref_item[2] == int(dcam)    # same camera
 
-        np.testing.assert_array_equal(np.asarray(du), hu)
-        np.testing.assert_array_equal(np.asarray(ds), hs)
-        np.testing.assert_array_equal(np.asarray(dn), hn)
         for c in range(C):
-            assert _lane_multiset(hu, hs, c) == _ref_multiset(refs[c]), c
+            assert _lane_multiset(du, ds, c) == _ref_multiset(refs[c]), c
+        assert not np.any(np.signbit(np.asarray(du)[np.asarray(ds) >= 0]))
 
 
 def test_queue_lanes_match_heapq_reference(rng):
@@ -131,24 +148,28 @@ def test_queue_lanes_subnormal_utilities(seed):
                    utilities=[np.float32(x) for x in SUBNORMAL_POOL])
 
 
-def test_subnormal_utilities_device_host_parity(rng):
-    """``step(utilities=...)`` with subnormals: both serve twins hold
-    the same CDF rings, thresholds and queues, bit for bit."""
+def test_subnormal_utilities_match_host_loop_reference(rng):
+    """``step(utilities=...)`` with subnormals: the session makes the
+    scalar host loop's decisions and thresholds, and its CDF rings and
+    queues hold the same values, with every subnormal stored as +0.0."""
     C, T, W = 3, 8, 16
-    sessions = [open_session(Query.single("red", fps=10.0), num_cameras=C,
-                             cdf_window=W, serve=serve, exact_tick=True)
-                for serve in ("host", "device")]
-    for s in sessions:
+    sess = open_session(Query.single("red", fps=10.0), num_cameras=C,
+                        cdf_window=W, exact_tick=True)
+    ref = HostLoopShedder(C, cdf_window=W)
+    for s in (sess, ref):
         s.report_backend_latency(0.2)
     for _ in range(5):
         u = rng.choice(np.array(SUBNORMAL_POOL, np.float32), (C, T))
-        res = [s.step(utilities=u, tick=True) for s in sessions]
-        np.testing.assert_array_equal(res[0].decisions, res[1].decisions)
-        for leaf in ("cdf_buf", "threshold", "q_util", "q_seq"):
-            np.testing.assert_array_equal(
-                np.asarray(getattr(sessions[0].state, leaf)),
-                np.asarray(getattr(sessions[1].state, leaf)), err_msg=leaf)
-    assert not np.any(np.signbit(np.asarray(sessions[1].state.cdf_buf)))
+        res = sess.step(utilities=u, tick=True)
+        np.testing.assert_array_equal(res.decisions, ref.step(u))
+        np.testing.assert_array_equal(np.asarray(sess.state.threshold),
+                                      ref.threshold)
+        np.testing.assert_array_equal(np.asarray(sess.state.cdf_buf),
+                                      ref.cdf.buf)
+        for c in range(C):
+            assert _lane_multiset(sess.state.q_util, sess.state.q_seq,
+                                  c) == _ref_multiset(ref.queues[c])
+    assert not np.any(np.signbit(np.asarray(sess.state.cdf_buf)))
 
 
 def test_queue_fifo_tiebreaks():
@@ -156,42 +177,56 @@ def test_queue_fifo_tiebreaks():
     returns the oldest of the best; any-camera pop prefers the lowest
     camera index on utility ties — all matching the heapq reference."""
     C, K = 2, 4
-    hu, hs, hn = sq.make_lanes(C, K)
-    cap = np.array([2, 2], np.int32)
+    du, ds, dn = sq.make_lanes(C, K)
+    cap = jnp.array([2, 2], jnp.int32)
     u = np.array([[0.5, 0.5, 0.5], [0.7, 0.9, 0.9]], np.float32)
     admit = np.ones((C, 3), bool)
-    hn, pushed, ev_s, ev_b = sq.push_batch_host(hu, hs, hn, u, admit, cap)
+    du, ds, dn, pushed, ev_s, ev_b = sq.push_batch_dev(
+        du, ds, dn, jnp.asarray(u), jnp.asarray(admit), cap)
+    ev_s = np.asarray(ev_s)
     # camera 0: three 0.5s into cap 2 -> seq 0 (oldest) evicted
     assert ev_s[0][ev_s[0] >= 0].tolist() == [0]
     # camera 1: 0.7 evicted (lowest utility), not an equal-utility entry
     assert ev_s[1][ev_s[1] >= 0].tolist() == [0]
-    # pop_best any-camera: best utility 0.9 on camera 1, oldest first
-    cam, seq = sq.pop_best_host(hu, hs)
-    assert (cam, seq) == (1, 1)
-    # tie between remaining 0.5 (cam 0) and 0.9 (cam 1)
-    cam, seq = sq.pop_best_host(hu, hs)
-    assert (cam, seq) == (1, 2)
-    # equal 0.5s on camera 0: oldest surviving seq pops first
-    cam, seq = sq.pop_best_host(hu, hs)
-    assert (cam, seq) == (0, 1)
+    refs = [UtilityQueue(2) for _ in range(C)]
+    for c in range(C):
+        for t in range(3):
+            refs[c].push((c, t), float(u[c, t]))
+    # pop_best any-camera: best utility 0.9 on camera 1, oldest first;
+    # then the tie between the remaining 0.9 (cam 1) and 0.5 (cam 0);
+    # then the equal 0.5s on camera 0, oldest surviving seq first
+    for want, ref_item in (((1, 1), refs[1].pop_best()),
+                           ((1, 2), refs[1].pop_best()),
+                           ((0, 1), refs[0].pop_best())):
+        du, ds, cam, seq = sq.pop_best_dev(du, ds)
+        assert (int(cam), int(seq)) == want
+        assert ref_item == want
 
 
 def test_batch_push_equals_sequential_single_pushes(rng):
     """One push_batch == T push_one calls (same final lanes multiset,
-    same eviction set) — the top-cap selection is order-free."""
+    same eviction set) — the top-cap selection is order-free — and both
+    hold what T heapq pushes leave."""
     C, K, T = 2, 5, 7
-    cap = np.array([3, 5], np.int32)
+    cap = jnp.array([3, 5], jnp.int32)
     u = rng.choice([0.1, 0.4, 0.4, 0.8], (C, T)).astype(np.float32)
     admit = rng.random((C, T)) < 0.85
 
     bu_, bs_, bn_ = sq.make_lanes(C, K)
-    sq.push_batch_host(bu_, bs_, bn_, u, admit, cap)
+    bu_, bs_, *_ = sq.push_batch_dev(bu_, bs_, bn_, jnp.asarray(u),
+                                     jnp.asarray(admit), cap)
 
     su_, ss_, sn_ = sq.make_lanes(C, K)
     for t in range(T):
-        sn_, *_ = sq.push_one_host(su_, ss_, sn_, u[:, t], admit[:, t], cap)
+        su_, ss_, sn_, *_ = sq.push_one_dev(
+            su_, ss_, sn_, jnp.asarray(u[:, t]), jnp.asarray(admit[:, t]),
+            cap)
     for c in range(C):
+        ref = UtilityQueue(int(cap[c]))
+        for t in np.flatnonzero(admit[c]):
+            ref.push(t, float(u[c, t]))
         assert _lane_multiset(bu_, bs_, c) == _lane_multiset(su_, ss_, c)
+        assert _lane_multiset(bu_, bs_, c) == _ref_multiset(ref)
 
 
 # ---------------------------------------------------------------------------
@@ -201,9 +236,9 @@ def test_batch_push_equals_sequential_single_pushes(rng):
 @pytest.mark.parametrize("lens", [(0, 0, 0), (0, 1, 17), (64, 64, 64),
                                   (3, 64, 40)])
 def test_threshold_lanes_parity_edge_cases(lens, rng):
-    """Empty, single-entry, partially filled and full windows: device
-    and host lanes agree bitwise, and each row equals the scalar
-    ``threshold_from_sorted`` reference."""
+    """Empty, single-entry, partially filled and full windows: each row
+    of the device lanes equals the scalar ``threshold_from_sorted``
+    reference."""
     C, W = len(lens), 64
     buf = np.zeros((C, W), np.float32)
     n = np.asarray(lens, np.int32)
@@ -212,35 +247,32 @@ def test_threshold_lanes_parity_edge_cases(lens, rng):
             [0.0, 0.25, 0.5, 0.5, 0.77, 1.0], n[c]).astype(np.float32)
     for r in (0.0, 1e-3, 0.33, 0.5, 0.999, 1.0):
         rates = np.full((C,), r, np.float32)
-        h = thresholds_from_lanes_host(buf, n, rates)
         d = np.asarray(thresholds_from_lanes_dev(
             jnp.asarray(buf), jnp.asarray(n), jnp.asarray(rates)))
-        np.testing.assert_array_equal(h, d)
         for c in range(C):
             ref = threshold_from_sorted(np.sort(buf[c, :n[c]]), float(r))
-            assert h[c] == np.float32(ref)
+            assert d[c] == np.float32(ref)
 
 
-def test_threshold_parity_through_wrapped_ring(rng):
-    """Session CDF rings that wrapped (len == W, pos mid-buffer) give
-    identical thresholds on both serve impls."""
+def test_threshold_through_wrapped_ring_matches_host_loop_reference(rng):
+    """Session CDF rings that wrapped (len == W, pos mid-buffer) give the
+    scalar host loop's ring positions and thresholds."""
     C, W = 2, 32
-    hs = open_session(Query.single("red", fps=10.0), num_cameras=C,
-                      cdf_window=W, serve="host")
-    ds = open_session(Query.single("red", fps=10.0), num_cameras=C,
-                      cdf_window=W, serve="device")
-    for s in (hs, ds):
+    sess = open_session(Query.single("red", fps=10.0), num_cameras=C,
+                        cdf_window=W, exact_tick=True)
+    ref = HostLoopShedder(C, cdf_window=W)
+    for s in (sess, ref):
         s.report_backend_latency(0.2)
     for k in range(7):                        # 7*10 > 2*W: wraps twice
         u = rng.uniform(0, 1, (C, 10)).astype(np.float32)
-        hs.step(utilities=u, tick=True)
-        ds.step(utilities=u, tick=True)
-        np.testing.assert_array_equal(np.asarray(hs.state.cdf_pos),
-                                      np.asarray(ds.state.cdf_pos))
-        np.testing.assert_array_equal(np.asarray(hs.state.threshold),
-                                      np.asarray(ds.state.threshold))
-    assert int(np.asarray(hs.state.cdf_len)[0]) == W     # wrapped
-    assert int(np.asarray(hs.state.cdf_pos)[0]) not in (0,)
+        sess.step(utilities=u, tick=True)
+        ref.step(u)
+        np.testing.assert_array_equal(np.asarray(sess.state.cdf_pos),
+                                      ref.cdf.pos)
+        np.testing.assert_array_equal(np.asarray(sess.state.threshold),
+                                      ref.threshold)
+    assert int(np.asarray(sess.state.cdf_len)[0]) == W     # wrapped
+    assert int(np.asarray(sess.state.cdf_pos)[0]) not in (0,)
 
 
 # ---------------------------------------------------------------------------
@@ -248,18 +280,16 @@ def test_threshold_parity_through_wrapped_ring(rng):
 # ---------------------------------------------------------------------------
 
 def test_step_matches_host_loop_reference():
-    from benchmarks.bench_serve_step import HostLoopShedder
-
     rng = np.random.default_rng(11)
     C, T, W = 4, 12, 128
     hist = rng.uniform(0, 1, W + 16).astype(np.float32)
     ref = HostLoopShedder(C, cdf_window=W)
     ref.seed_cdf(hist)
     sessions = {
-        serve: open_session(Query.single("red", latency_bound=1.0, fps=10.0),
+        where: open_session(Query.single("red", latency_bound=1.0, fps=10.0),
                             num_cameras=C, train_utilities=hist,
-                            cdf_window=W, serve=serve, exact_tick=True)
-        for serve in ("host", "device")}
+                            cdf_window=W, exact_tick=True, **kw)
+        for where, kw in PLACEMENTS.items()}
     for step in range(6):
         lat = float(rng.uniform(0.5, 2.0) / (C * 10.0))
         ref.report_backend_latency(lat)
@@ -267,13 +297,13 @@ def test_step_matches_host_loop_reference():
             s.report_backend_latency(lat)
         u = rng.uniform(0, 1, (C, T)).astype(np.float32)
         d_ref = ref.step(u)
-        for serve, s in sessions.items():
+        for where, s in sessions.items():
             res = s.step(utilities=u, tick=True)
             np.testing.assert_array_equal(res.decisions, d_ref,
-                                          err_msg=f"{serve} step {step}")
+                                          err_msg=f"{where} step {step}")
             np.testing.assert_array_equal(np.asarray(s.state.threshold),
                                           ref.threshold,
-                                          err_msg=f"{serve} step {step}")
+                                          err_msg=f"{where} step {step}")
             np.testing.assert_array_equal(np.asarray(s.state.queue_cap),
                                           ref.queue_cap)
 
@@ -287,25 +317,27 @@ def test_step_frames_fused_equals_split_pipeline(rng):
     model = train_utility_model(pfs, rng.random(40) < 0.5, [RED])
     hist = rng.uniform(0, 1, 64).astype(np.float32)
 
-    def mk(serve):
+    def mk(**kw):
         s = open_session(Query.single("red", latency_bound=1.0, fps=10.0),
                          num_cameras=C, model=model, train_utilities=hist,
-                         queue_size=3, cdf_window=64, serve=serve)
+                         queue_size=3, cdf_window=64, **kw)
         s.report_backend_latency(0.21)
         return s
 
-    fused_dev, fused_host, split = mk("device"), mk("host"), mk("host")
+    fused, fused_mesh, split = mk(), mk(shard_cameras=True), mk()
     for b in range(3):
-        rd = fused_dev.step(frames=frames[b])
-        rh = fused_host.step(frames=frames[b])
+        rd = fused.step(frames=frames[b])
+        rm = fused_mesh.step(frames=frames[b])
         dec = split.admit(split.ingest(frames[b]).utility)
         split.tick()
-        np.testing.assert_array_equal(rd.decisions, rh.decisions)
+        np.testing.assert_array_equal(rd.decisions, rm.decisions)
         np.testing.assert_array_equal(rd.decisions, dec)
         for k, v in split.state.as_dict().items():
-            np.testing.assert_array_equal(
-                np.asarray(fused_dev.state.as_dict()[k]), v, err_msg=k)
-    assert fused_dev.stats.__dict__ == split.stats.__dict__
+            np.testing.assert_array_equal(fused.state.as_dict()[k], v,
+                                          err_msg=k)
+            np.testing.assert_array_equal(fused_mesh.state.as_dict()[k], v,
+                                          err_msg=k)
+    assert fused.stats.__dict__ == split.stats.__dict__
 
 
 def test_step_requires_exactly_one_input(rng):
@@ -365,20 +397,20 @@ class _Frame:
         self.cam_id, self.i = cam_id, i
 
 
-@pytest.mark.parametrize("serve", ["host", "device"])
-def test_offer_batch_matches_sequential_offers(serve, rng):
+@pytest.mark.parametrize("placement", list(PLACEMENTS))
+def test_offer_batch_matches_sequential_offers(placement, rng):
     C = 3
     hist = rng.uniform(0, 1, 100).astype(np.float32)
 
-    def mk(s):
+    def mk(**kw):
         sess = open_session(Query.single("red", latency_bound=1.0, fps=10.0),
                             num_cameras=C, train_utilities=hist,
-                            queue_size=2, cdf_window=128, serve=s)
+                            queue_size=2, cdf_window=128, **kw)
         sess.report_backend_latency(0.15)
         sess.tick()
         return sess
 
-    seq_s, bat_s = mk("host"), mk(serve)
+    seq_s, bat_s = mk(), mk(**PLACEMENTS[placement])
     items = [_Frame(i % C, i) for i in range(11)]
     us = rng.uniform(0, 1, len(items))
     codes_seq = [seq_s.offer(f, float(u)) for f, u in zip(items, us)]

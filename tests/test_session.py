@@ -238,20 +238,22 @@ def _ref_tick(control, procs):
     return rates
 
 
-@pytest.mark.parametrize("mode", ["device", "host", "mesh"])
-def test_latency_feed_matches_reference_fold_bitwise(mode):
+@pytest.mark.parametrize("mode,C", [("device", 4), ("mesh", 4),
+                                    ("device", 1), ("mesh", 1)],
+                         ids=["device", "mesh", "device1", "mesh1"])
+def test_latency_feed_matches_reference_fold_bitwise(mode, C):
     """200 reports, scalar and per camera mixed, with ticking steps
     between them: every lane's estimate is the reference's float32 fold
     bit for bit, both while reports are pending (``expected_proc``) and
     as uploaded (the state), and so are every tick's decisions, rates,
-    thresholds and queue caps. ``mesh`` is a one-device camera mesh."""
+    thresholds and queue caps. ``mesh`` is a one-device camera mesh;
+    ``C`` cameras share the backend."""
     from bench import reference as ref
     from repro.core.fleet import fleet_mesh
-    C, T, W, K = 4, 6, 64, 8
+    T, W, K = 6, 64, 8
     rng = np.random.default_rng(21)
     hist = rng.uniform(0, 1, W).astype(np.float32)
-    where = ({"mesh": fleet_mesh(1)} if mode == "mesh"
-             else {"serve": mode})
+    where = {"mesh": fleet_mesh(1)} if mode == "mesh" else {}
     sess = open_session(Query.single("red", latency_bound=1.0, fps=10.0),
                         num_cameras=C, train_utilities=hist,
                         cdf_window=W, queue_size=3, queue_capacity=K,

@@ -65,8 +65,7 @@ def _session(metrics=None, **kw):
                         num_cameras=C, frame_shape=(H, W),
                         train_utilities=rng.uniform(0, 1, 64)
                         .astype(np.float32),
-                        queue_size=3, cdf_window=64, serve="device",
-                        metrics=metrics, **kw)
+                        queue_size=3, cdf_window=64, metrics=metrics, **kw)
 
 
 def _drive(session):

@@ -61,7 +61,7 @@ def _ingest_kw(query):
 
 
 def _control_kw(num_total):
-    from repro.core.session import DEFAULT_TICK_CONFIG
+    from repro.core.control_plane import DEFAULT_TICK_CONFIG
     return dict(update_cdf=True, do_tick=True, min_proc=1e-6, budget=0.4,
                 num_total=num_total, tick_cfg=DEFAULT_TICK_CONFIG)
 
@@ -69,11 +69,11 @@ def _control_kw(num_total):
 def _state_shapes(num_cameras, sharding_of, npix=NPIX):
     """SessionState of ShapeDtypeStructs; ``sharding_of(name)`` places
     each leaf."""
-    from repro.core.session import SessionState
-    st = SessionState.fresh(num_cameras, npix)
+    from repro.core.control_plane import SessionState
+    st = jax.eval_shape(lambda: SessionState.fresh(num_cameras, npix))
     return SessionState(**{
-        f.name: jax.ShapeDtypeStruct(np.shape(getattr(st, f.name)),
-                                     np.asarray(getattr(st, f.name)).dtype,
+        f.name: jax.ShapeDtypeStruct(getattr(st, f.name).shape,
+                                     getattr(st, f.name).dtype,
                                      sharding=sharding_of(f.name))
         for f in dataclasses.fields(st)})
 
@@ -96,7 +96,7 @@ def test_ingest_batch_compiles_at_720p(one_chip, query, with_bbox):
 
 
 def test_device_serve_step_compiles_at_720p(one_chip, query):
-    from repro.core.session import _serve_step_dev
+    from repro.core.control_plane import _serve_step_dev
     nc = query.num_colors
     S = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa: E731
                                            sharding=one_chip)
@@ -114,7 +114,7 @@ def test_device_serve_step_compiles_with_uint8_frames_at_540p(one_chip,
     """The camera's uint8 frames as the benchmark's 24 cameras x 8
     frames x 960x540 window hands them over: the first relayout, then
     the serve step that converts them to float32 on the chip."""
-    from repro.core.session import _flatten_frames, _serve_step_dev
+    from repro.core.control_plane import _flatten_frames, _serve_step_dev
     cams, frames, h, w = 24, 8, 540, 960
     nc = query.num_colors
     S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
@@ -148,7 +148,7 @@ FLEET_WINDOWS = {
 def test_fleet_serve_step_compiles_on_four_chips(topo, query, window):
     from jax.sharding import Mesh
     from repro.core import fleet
-    from repro.core.session import SessionState
+    from repro.core.control_plane import SessionState
     cams, frames_per_step, h, w, dtype, whole_frames = window
     mesh = Mesh(np.array(topo.devices[:4]), (fleet.CAMERA_AXIS,))
     axis = fleet.CAMERA_AXIS

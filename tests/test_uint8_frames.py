@@ -1,6 +1,6 @@
 """The camera's uint8 frames on the session's device frames path.
 
-A ``serve="device"`` session hands the frames to the device in the
+A session's frames step hands the frames to the device in the
 camera's dtype and converts them to float32 there, in the ingest's
 first relayout (the Pallas kernel's planar tiles, the oracle's HSV
 conversion). Every
@@ -8,8 +8,8 @@ uint8 value is exact in float32, so a session fed uint8 frames must
 score, decide, queue and pop bit for bit like one fed the same frames
 as float32: with and without a carried background, with a tick every
 step, for the jnp oracle, the Pallas kernel (interpret mode) and the
-camera-sharded fleet. The host-scored paths (``serve="host"``, the
-cascade, ``ingest``) keep converting on the host.
+camera-sharded fleet. The host-scored paths (the cascade and
+``ingest``) keep converting on the host.
 """
 import dataclasses
 import os
@@ -90,13 +90,13 @@ def _assert_same_run(make, windows):
 @pytest.mark.parametrize("frame_shape", [None, (H, W)],
                          ids=["sized-by-first-window", "preallocated"])
 def test_device_step_uint8_matches_float32_jnp(frame_shape):
-    _assert_same_run(lambda: _session(serve="device", impl="jnp",
+    _assert_same_run(lambda: _session(impl="jnp",
                                       frame_shape=frame_shape),
                      _windows())
 
 
 def test_device_step_uint8_matches_float32_pallas_interpret():
-    _assert_same_run(lambda: _session(C=2, serve="device", impl="pallas",
+    _assert_same_run(lambda: _session(C=2, impl="pallas",
                                       interpret=True),
                      _windows(steps=2, C=2, T=2, H=16, W=32))
 
@@ -118,7 +118,7 @@ import test_uint8_frames as t
 from repro.core.fleet import fleet_mesh
 wins = t._windows(C=8)
 fl = t._session(C=8, mesh=fleet_mesh(4), impl="jnp")
-ref = t._session(C=8, serve="device", impl="jnp")
+ref = t._session(C=8, impl="jnp")
 for s, win in enumerate(wins):
     for sess in (fl, ref):
         sess.report_backend_latency(2.0 / 80.0)
@@ -145,8 +145,7 @@ print("UINT8-FLEET-OK")
 def test_device_step_other_dtypes_match_float32_jnp(dtype):
     """Integer and narrow float frames cross as they come and score as
     their float32 values do."""
-    a, b = _session(serve="device", impl="jnp"), _session(
-        serve="device", impl="jnp")
+    a, b = _session(impl="jnp"), _session(impl="jnp")
     for s, win in enumerate(_windows(steps=2)):
         r1 = a.step(frames=win.astype(dtype), tick=True)
         r2 = b.step(frames=win.astype(np.float32), tick=True)
@@ -165,16 +164,17 @@ def test_device_step_hands_the_camera_dtype_to_the_device(monkeypatch):
         return flatten(frames)
 
     monkeypatch.setattr(session_mod, "_flatten_frames", spy)
-    s = _session(serve="device", impl="jnp")
+    s = _session(impl="jnp")
     win = _windows(steps=1)[0]
     for frames in (win, win.astype(np.float64), win.astype(np.float32)):
         s.step(frames=frames, tick=True)
     assert [str(d) for d in seen] == ["uint8", "float32", "float32"]
 
 
-def test_host_and_cascade_paths_still_convert_on_the_host(monkeypatch):
-    """``serve="host"`` and the cascade score float32 frames, as before:
-    uint8 and float32 inputs give the same steps, and the ingest and
+@pytest.mark.parametrize("path", ["cascade", "ingest"])
+def test_host_scored_paths_still_convert_on_the_host(monkeypatch, path):
+    """The cascade step and ``ingest()`` score float32 frames, as before:
+    uint8 and float32 inputs give the same results, and the ingest and
     the stage-2 scorer only ever see float32."""
     seen = []
     pipeline = session_mod.ingest_pipeline
@@ -189,15 +189,17 @@ def test_host_and_cascade_paths_still_convert_on_the_host(monkeypatch):
         seen.append(("scorer", frames.dtype))
         return frames.reshape(len(frames), -1).mean(axis=1) / 255.0
 
-    makers = {
-        "host": lambda: _session(serve="host"),
-        "cascade": lambda: _session(
-            serve="device", impl="jnp",
+    if path == "cascade":
+        _assert_same_run(lambda: _session(
+            impl="jnp",
             cascade=Cascade(CallableScorer(score), gate_fraction=0.5)),
-    }
-    for name, make in makers.items():
-        seen.clear()
-        _assert_same_run(make, _windows(steps=2))
-        assert seen and all(str(d) == "float32" for _, d in seen), name
-        if name == "cascade":
-            assert ("scorer", np.dtype(np.float32)) in seen
+            _windows(steps=2))
+        assert ("scorer", np.dtype(np.float32)) in seen
+    else:
+        a, b = _session(impl="jnp"), _session(impl="jnp")
+        for s, win in enumerate(_windows(steps=2)):
+            r1, r2 = a.ingest(win), b.ingest(win.astype(np.float32))
+            np.testing.assert_array_equal(r1.pf, r2.pf)
+            np.testing.assert_array_equal(r1.utility, r2.utility)
+            _assert_same_state(a, b, f"ingest {s}")
+    assert seen and all(str(d) == "float32" for _, d in seen), path
